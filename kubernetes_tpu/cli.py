@@ -218,7 +218,15 @@ def cmd_serve(args) -> int:
 
 def cmd_perf(args) -> int:
     from .perf.runner import PerfRunner
+    from .utils.device import init_backend
 
+    # backend up before any workload runs; every result line names it
+    device = init_backend()
+    print(
+        f"jax backend: platform={device['platform']} "
+        f"device_kind={device['kind']} devices={device['count']}",
+        file=sys.stderr,
+    )
     cfg = _load_config(args.config)
     sched_cfg = config_types.scheduler_config(cfg)
     sched_cfg.feature_gates = _feature_gates(args)
@@ -236,6 +244,7 @@ def cmd_perf(args) -> int:
                     "throughput": r.throughput_summary(),
                     "podLatency": r.latency_summary(),
                     "deviceSolveSeconds": round(r.solve_seconds, 3),
+                    "device": device,
                     **(
                         {"threshold": r.threshold, "passed": r.passed}
                         if r.threshold

@@ -212,7 +212,7 @@ class TpuSolverSection:
     backlog_chunk_pods: int = 0
     # Pallas-kernel tier (ExactSolverConfig.pallas): route the
     # InterPodAffinity domain aggregation through the MXU kernel.
-    # Default off — see ops/pallas_kernels.py's measured decision.
+    # Default off — see ops/pallas_kernels.py for why.
     pallas: bool = False
     single_shot: SingleShotSection = field(default_factory=SingleShotSection)
 

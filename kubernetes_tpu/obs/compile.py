@@ -4,11 +4,14 @@ stream / mega-drain scale, where one retracing shape turns a ~2 ms
 dispatch into a multi-second compile stall — shows up in metrics and on
 the dispatch span instead of only in a wall-clock mystery.
 
-Mechanism: one process-wide listener on ``jax.monitoring``'s duration
-events. ``/jax/core/compile/backend_compile_duration`` fires per actual
-XLA backend compile and ``/jax/core/compile/jaxpr_trace_duration`` per
-retrace (a persistent-disk-cache hit still pays the retrace, which is
-why retraces are the better "known shape came back cold" signal).
+Mechanism: one process-wide listener on ``jax.monitoring``'s events.
+``/jax/core/compile/backend_compile_duration`` fires per executable
+BUILT — an XLA backend compile, or a retrieval from the persistent disk
+cache (JAX times both under the one event; the second is much shorter)
+— and ``/jax/compilation_cache/cache_hits`` fires for the retrievals,
+so compiles minus hits is what XLA actually compiled.
+``/jax/core/compile/jaxpr_trace_duration`` fires per retrace (a
+persistent-cache hit still pays the retrace).
 Attribution: the scheduler brackets each solver dispatch with
 ``CompileWatcher.scope(key)`` — ``key`` is the dispatch's shape/static
 fingerprint — and any compile event firing inside the bracket counts
@@ -38,6 +41,7 @@ from .. import metrics
 
 _COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 _TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
+_CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
 
 OTHER_SCOPE = "other"
 
@@ -54,6 +58,7 @@ class CompileWatcher:
         self.compiles = 0
         self.retraces = 0
         self.compile_seconds = 0.0
+        self.cache_hits = 0  # compiles served by the persistent cache
         self._installed = False
 
     # -- scope bracketing --
@@ -93,6 +98,12 @@ class CompileWatcher:
                     self._current(), [0, 0, 0.0]
                 )[1] += 1
 
+    def _on_cache_hit(self, event: str, **kw) -> None:
+        if event == _CACHE_HIT_EVENT:
+            with self._lock:
+                self.cache_hits += 1
+            metrics.xla_persistent_cache_hits_total.inc()
+
     def _export(self) -> None:
         with self._lock:
             keys = len(self.by_scope)
@@ -118,6 +129,7 @@ class CompileWatcher:
             monitoring.register_event_duration_secs_listener(
                 self._on_event
             )
+            monitoring.register_event_listener(self._on_cache_hit)
         except Exception:  # pragma: no cover - jax surface drift
             pass
 

@@ -39,7 +39,7 @@ def domain_counts(
     the MXU one-hot-contraction kernel
     (ops/pallas_kernels.domain_counts_padded) and gathers back per node.
     Bit-identical to the segment_sum (integer adds in both); off by
-    default per the measured negative results in pallas_kernels.py."""
+    default (pallas_kernels.py says why)."""
     t, n = dom.shape
     hk = dom >= 0
     if ident:

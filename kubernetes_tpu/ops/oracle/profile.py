@@ -373,6 +373,7 @@ class FullOracle:
     def validate_feasible(
         self, pods: Sequence[Pod], assignments: Sequence[int],
         names: Sequence[str] | None = None,
+        sample: "set[int] | None" = None,
     ) -> list[str]:
         """Feasibility-only replay for GLOBAL planners (the convex-
         relaxation mega-planner, ISSUE 19): every placed pick must be
@@ -383,20 +384,36 @@ class FullOracle:
         tie-set parity (``validate_assignments``) is the sequential
         solvers' contract, not the planner's. Unplaced pods are not
         flagged — under-placement is an objective-quality question the
-        bench/sim ratio floors own, not a validity violation."""
+        bench/sim ratio floors own, not a validity violation.
+        ``sample``: step indices to verify, as in
+        ``validate_assignments`` — every step is still REPLAYED so the
+        state stays exact; only the per-step filter run is skipped
+        elsewhere (the PreFilter states scan every placed pod, which
+        at 10k pods x 5k nodes is the whole cost)."""
         index_of = {on.node.name: i for i, on in enumerate(self.nodes)}
         errors: list[str] = []
         for step, (pod, pick) in enumerate(zip(pods, assignments)):
             if pick < 0:
                 continue
-            feasible = self.feasible_set(pod)
             oi = index_of[names[step]] if names is not None else pick
-            if oi not in feasible:
-                errors.append(
-                    f"step {step} pod {pod.key}: pick {oi} not in "
-                    f"feasible set {feasible[:10]}"
-                    f"{'...' if len(feasible) > 10 else ''}"
+            if sample is None or step in sample:
+                # pick in feasible_set(pod) <=> the picked node passes
+                # every filter against the same PreFilter states; the
+                # full set is only built to word a failure
+                all_nodes = self._all_nodes_with_pods()
+                ok = self.filter_one(
+                    pod,
+                    self.nodes[oi],
+                    osp.build_filter_state(pod, all_nodes),
+                    oip.build_interpod_state(pod, all_nodes),
                 )
+                if not ok:
+                    feasible = self.feasible_set(pod)
+                    errors.append(
+                        f"step {step} pod {pod.key}: pick {oi} not in "
+                        f"feasible set {feasible[:10]}"
+                        f"{'...' if len(feasible) > 10 else ''}"
+                    )
             # follow the plan anyway to localize subsequent divergence
             self.nodes[oi].add_pod(pod)
         return errors

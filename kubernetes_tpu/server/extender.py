@@ -58,6 +58,12 @@ from ..state.cluster import ApiError, ClusterState
 from .. import metrics
 
 MAX_EXTENDER_PRIORITY = 10
+# aiohttp's default body limit is 1 MiB: an ExtenderArgs carrying the
+# full node list of a 5,000-node cluster is already past it (1.6 MB
+# with wrapper-built nodes, HTTP 413; real Node objects run to tens of
+# KiB each), and kube-scheduler's extender client sends whatever the
+# list weighs
+MAX_REQUEST_BYTES = 1 << 30
 
 
 class DecodeError(Exception):
@@ -714,7 +720,7 @@ def make_app(
             {"items": [le.to_dict() for le in core.cluster.list_leases()]}
         )
 
-    app = web.Application()
+    app = web.Application(client_max_size=MAX_REQUEST_BYTES)
     app.router.add_post("/filter", filter_)
     app.router.add_post("/prioritize", prioritize)
     app.router.add_post("/preempt", preempt)
@@ -812,7 +818,13 @@ def run_server(
 
     from aiohttp import web
 
+    from ..utils.device import init_backend
+
     log = logging.getLogger("kubernetes_tpu.serve")
+    # backend up BEFORE /healthz can answer: a dead or missing device
+    # fails the process here, not inside the first solve where the
+    # degraded-mode ladder would bind the batch on the host instead
+    init_backend()
     if state_file:
         _load_state_file(cluster, state_file)
     scheduler = None

@@ -23,8 +23,11 @@ import sys
 
 def _configure_jax(mesh_devices: int = 1) -> None:
     """Force CPU + 64-bit resource arithmetic BEFORE the solver imports
-    jax (tests get this from tests/conftest.py; the CLI must do it
-    itself — on this toolchain only jax.config.update is honored).
+    jax. The simulator runs on virtual time and its traces are compared
+    byte for byte across runs and machines, so it is CPU BY DESIGN: it
+    pins the platform in code, whatever JAX_PLATFORMS says (with
+    tests/conftest.py, the only place that does; every other entry
+    point takes its backend from JAX_PLATFORMS alone).
     ``mesh_devices > 1`` additionally forces that many virtual CPU
     devices (must land before the backend initializes) so the sim can
     drive the node-axis-sharded solve path."""

@@ -43,8 +43,8 @@ from ..tensorize.plugins import CLASS_PAD, PORT_PAD
 from ..tensorize.schema import LANE, bucket_pow2
 from ..tensorize.spread import DOM_PAD, INST_PAD as SPREAD_INST_PAD
 
-# Fallback per-device budget when the runtime reports no bytes_limit
-# (CPU backends, older PJRT): one conservative accelerator-die floor.
+# Per-device budget on the CPU backend, which reports no bytes_limit
+# (tests, the virtual-time sim). An accelerator is always asked.
 DEFAULT_DEVICE_BUDGET_BYTES = 8 << 30
 
 # Compiled-program workspace multiplier over the analytic resident set:
@@ -308,19 +308,22 @@ def relax_estimate(
 
 def device_budget_bytes(override: int = 0) -> int:
     """The per-device HBM budget: an explicit override, else the
-    runtime-reported ``bytes_limit`` (PJRT memory stats), else the
-    conservative DEFAULT_DEVICE_BUDGET_BYTES floor."""
+    runtime-reported ``bytes_limit`` (PJRT memory stats). Only the CPU
+    backend, which reports none, gets DEFAULT_DEVICE_BUDGET_BYTES; an
+    accelerator that reports no limit is an error, not an assumption."""
     if override > 0:
         return override
-    try:
-        import jax
+    import jax
 
-        stats = jax.devices()[0].memory_stats() or {}
-        limit = int(stats.get("bytes_limit", 0))
-        if limit > 0:
-            return limit
-    except Exception:
-        pass
+    dev = jax.devices()[0]
+    limit = int((dev.memory_stats() or {}).get("bytes_limit", 0))
+    if limit > 0:
+        return limit
+    if dev.platform != "cpu":
+        raise RuntimeError(
+            f"{dev.platform} device {dev.device_kind!r} reports no "
+            "bytes_limit; set hbm_budget_bytes explicitly"
+        )
     return DEFAULT_DEVICE_BUDGET_BYTES
 
 

@@ -349,6 +349,9 @@ class RelaxSolver:
         self.last = RelaxStats()
         if not jax.config.jax_enable_x64:
             jax.config.update("jax_enable_x64", True)
+        from ..utils.compile_cache import enable_persistent_cache
+
+        enable_persistent_cache()
 
     def solve(
         self,

@@ -356,6 +356,9 @@ class SingleShotSolver:
         self.config = config or SingleShotConfig()
         if not jax.config.jax_enable_x64:
             jax.config.update("jax_enable_x64", True)
+        from ..utils.compile_cache import enable_persistent_cache
+
+        enable_persistent_cache()
 
     def solve(
         self,

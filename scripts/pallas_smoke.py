@@ -1,5 +1,7 @@
-"""Compiled-path smoke test for the Pallas kernels on the real TPU (the
-CPU test suite runs them in interpret mode only). Run:
+"""Compiled-path smoke test and micro-timing for the Pallas kernels on a
+TPU, with x64 ON as the solver runs them (the CPU test suite runs them
+in interpret mode only; chip_smoke.py phase B checks the compiled kernel
+on every run). Run on the chip:
     python scripts/pallas_smoke.py
 """
 
@@ -13,6 +15,8 @@ import numpy as np
 
 def main() -> None:
     import jax
+
+    jax.config.update("jax_enable_x64", True)  # the solver's regime
 
     from kubernetes_tpu.ops.pallas_kernels import (
         N_TILE,

@@ -71,6 +71,8 @@ def test_dryrun_multichip_8_devices():
         text=True,
         timeout=600,
         cwd=_REPO_ROOT,
+        # the backend follows JAX_PLATFORMS alone: ask for the virtual mesh
+        env={**os.environ, "JAX_PLATFORMS": "cpu"},
     )
     assert proc.returncode == 0, (
         f"dryrun_multichip(8) failed (rc={proc.returncode})\n"

@@ -1,5 +1,6 @@
 """Pallas kernel parity vs the jax.lax reference (interpret mode on CPU;
-the compiled TPU path is exercised by scripts/pallas_smoke.py), plus the
+the compiled TPU path is exercised by chip_smoke.py phase B and
+scripts/pallas_smoke.py), its TPU lowering under x64, plus the
 PRODUCTION wiring behind ``tpuSolver.pallas`` (ISSUE 13 satellite): the
 per-pod scan's InterPodAffinity domain aggregation routed through the
 kernel must produce bit-identical assignments to the segment_sum path,
@@ -26,6 +27,31 @@ def test_domain_counts_parity(t, n_tiles, d_pad):
     got = np.asarray(domain_counts_pallas(dom, cnt, d_pad, interpret=True))
     want = np.asarray(domain_counts_reference(dom, cnt, d_pad))
     np.testing.assert_array_equal(got, want)
+
+
+def test_lowers_for_tpu_with_x64_on():
+    """The solver runs with jax_enable_x64 process-wide, and Mosaic has
+    no 64-bit integers: a bare Python literal in the kernel body or in a
+    BlockSpec index map becomes an i64 and the kernel stops lowering
+    (body: RecursionError in the 64->32 convert rule) or compiling
+    (index map: "failed to legalize operation 'func.return'" on the
+    chip). Cross-lowering needs no chip, so neither can come back."""
+    import jax
+
+    from kubernetes_tpu.ops import pallas_kernels as pk
+
+    assert jax.config.jax_enable_x64  # conftest: the solver's regime
+    spec = jax.ShapeDtypeStruct((16, 4 * N_TILE), np.int32)
+    lowered = domain_counts_pallas.trace(spec, spec, d_pad=8).lower(
+        lowering_platforms=("tpu",)
+    )
+    assert "tpu_custom_call" in lowered.as_text()
+    # the part of the program Mosaic compiles later, on the chip: every
+    # block index must already be 32-bit
+    i32 = jax.ShapeDtypeStruct((), np.int32)
+    for index_map in (pk._in_block, pk._out_block):
+        for out in jax.eval_shape(index_map, i32, i32):
+            assert out.dtype == np.int32, (index_map.__name__, out.dtype)
 
 
 def test_domain_counts_excludes_missing_key():
