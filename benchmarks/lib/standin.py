@@ -1,0 +1,141 @@
+"""The reference put in the program's place: an object with the served
+scheduler's client-side surface (``lib.serve.Serve``'s methods) whose
+placements come from ``reference.ReferenceScheduler``. It reads the pods
+from the POSTed manifests themselves, schedules them batch by batch on a
+worker thread and streams a decision journal in the program's format.
+
+Used by ``control.py`` (the control: a batch solved against the
+occupancy it started with) and by ``tests/test_faults.py`` (faults
+planted under the harness). Never by a measured run.
+"""
+
+from __future__ import annotations
+
+import json
+import os
+import queue
+import threading
+import time
+
+from . import gen, reference
+
+FAULTS = (None, "stale_state", "drop_half", "alter_answers")
+
+
+def spec_of(manifest: dict) -> gen.PodSpec:
+    """What a scheduler reads off a wire-shape pod."""
+    meta, spec = manifest["metadata"], manifest["spec"]
+    if spec.get("topologySpreadConstraints"):
+        kind = "spread"
+    elif (spec.get("affinity") or {}).get("podAntiAffinity"):
+        kind = "anti"
+    else:
+        kind = "plain"
+    (key, app), = meta["labels"].items()  # a pod carries one label
+    return gen.PodSpec(meta["name"], kind, app, key)
+
+
+class StandIn:
+    def __init__(
+        self, cfg: dict, workdir: str, fault: str | None = None,
+        batch: int = 1024, platform: str = "cpu",
+        pace_pods_per_s: float = 2000.0, fault_after: int = 0,
+    ) -> None:
+        if fault not in FAULTS:
+            raise ValueError(f"unknown fault {fault!r}")
+        self.fault = fault
+        self.batch = batch
+        # a closed loop offers as fast as it is served: without a pace the
+        # stand-in would fill the cluster before the window opened
+        self.pace = pace_pods_per_s
+        self.fault_after = fault_after  # pods decided soundly first
+        self.platform = platform
+        self.journal = os.path.join(workdir, "journal.jsonl")
+        self._sched = reference.ReferenceScheduler(cfg)
+        self._queue: queue.Queue = queue.Queue()
+        self._stop = threading.Event()
+        self._solves = 0
+        self._step = 0
+        self._file = open(self.journal, "w")
+        self._worker = threading.Thread(target=self._run, daemon=True)
+        self._worker.start()
+
+    # -- the scheduler ------------------------------------------------------
+
+    def _run(self) -> None:
+        while not self._stop.is_set():
+            try:
+                batch = [self._queue.get(timeout=0.01)]
+            except queue.Empty:
+                continue
+            while len(batch) < self.batch:
+                try:
+                    batch.append(self._queue.get_nowait())
+                except queue.Empty:
+                    break
+            t_batch = time.monotonic()
+            n_in = len(batch)
+            fault = self.fault if self._step >= self.fault_after else None
+            if fault == "drop_half":
+                batch = batch[::2]  # the other half is never looked at
+            self._sched.carry = fault != "stale_state"
+            placed = self._sched.schedule(batch)
+            if fault == "alter_answers" and placed:
+                first = placed[0][1]
+                placed = [(spec, first) for spec, _ in placed]
+            t_next = t_batch + max(n_in / self.pace, 0.1)
+            time.sleep(max(0.0, t_next - time.monotonic()))
+            self._solves += 1
+            for spec, node in placed:
+                self._step += 1
+                rec = {
+                    "k": "dec", "step": self._step, "pod": spec.key,
+                    "outcome": "bound" if node else "unschedulable",
+                    "t": time.monotonic(),
+                }
+                if node:
+                    rec["node"] = node
+                self._file.write(json.dumps(rec) + "\n")
+            self._file.flush()
+
+    # -- the client-side surface of lib.serve.Serve -------------------------
+
+    def wait_healthy(self, timeout: float = 0.0) -> float:
+        return 0.0
+
+    def alive(self) -> bool:
+        return self._worker.is_alive()
+
+    def require_alive(self) -> None:
+        if not self.alive() and not self._stop.is_set():
+            raise RuntimeError("the stand-in's worker died")
+
+    def post_pods(self, body: bytes) -> int:
+        items = json.loads(body)["items"]
+        for m in items:
+            self._queue.put(spec_of(m))
+        return len(items)
+
+    def scrape(self) -> dict:
+        return {
+            ("scheduler_tpu_device_info",
+             (("device_kind", "reference"), ("platform", self.platform))): 1.0,
+            ("scheduler_tpu_host_to_device_bytes_total", ()): float(self._solves),
+            ("scheduler_tpu_solve_batch_size_count", ()): float(self._solves),
+            ("scheduler_tpu_solve_batch_size_sum", ()): float(self._step),
+        }
+
+    def ask(self, *words, timeout: float = 0.0) -> dict:
+        """Nothing to trace here: the requests are answered, no trace is
+        written, and the device readers find nothing to read."""
+        now = time.monotonic()
+        return {"t_ask": now, "t_on": now, "t_off": now}
+
+    def memory_peak_bytes(self):
+        return None
+
+    def close(self) -> None:
+        if not self._stop.is_set():
+            self._stop.set()
+            self._worker.join(timeout=10.0)
+            self._file.close()

@@ -1,0 +1,437 @@
+#!/usr/bin/env python3
+"""One run of one cell, from the client's side of ``python -m
+kubernetes_tpu serve --mode scheduler`` on the chip.
+
+    python benchmarks/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
+
+The last stdout line is one JSON object: ``correct``, ``attempted``,
+``failed``, ``metrics``, ``device`` (+ ``breakdown`` with ``--trace 1``)
+and, last, ``compared``: each number the comparison held, beside its
+limit. See benchmarks/README.md.
+"""
+
+from __future__ import annotations
+
+import time
+
+T_PROCESS = time.monotonic()  # set-up is timed from here
+
+import argparse  # noqa: E402
+import json  # noqa: E402
+import os  # noqa: E402
+import shutil  # noqa: E402
+import sys  # noqa: E402
+import threading  # noqa: E402
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+from benchmarks.lib import (  # noqa: E402
+    files, gen, journal, loops, reference, serve as serve_mod, solve_work,
+    trace_reduce,
+)
+
+TRACE_START_SHARE = 0.3  # of the window, before the capture starts
+TRACE_SECONDS = 3.0  # length of the profiler capture (at most a third of the window)
+# a backlog cell's queue is first-in first-out: a pod offered more than
+# this share of the queue's depth ahead of the newest bound one and still
+# undecided at the close is lost
+LOST_MARGIN_SHARE = 0.5
+
+
+def say(**fields) -> None:
+    print(json.dumps(fields), flush=True)
+
+
+def log(msg: str) -> None:
+    print(f"[bench] {msg}", file=sys.stderr, flush=True)
+
+
+def apply_rehearsal(cell: dict, cfg: dict) -> None:
+    """--rehearse-cpu: the cell's own ``rehearse`` overrides (a tiny
+    cluster the CPU backend can serve), never used by the driver."""
+    r = cell["rehearse"]
+    scale = r["nodes"] / cfg["nodes"]["count"]
+    cfg["nodes"]["count"] = r["nodes"]
+    cfg["initPods"]["count"] = r["initPods"]
+    cfg["validWhile"]["maxPodsOffered"] = int(
+        cfg["validWhile"]["maxPodsOffered"] * scale
+    )
+    cfg["stream"]["deploymentReplicas"] = r.get(
+        "deploymentReplicas", cfg["stream"]["deploymentReplicas"]
+    )
+    cell["loop"].update(r.get("loop", {}))
+    cell["warmup"] = r.get("warmup", cell["warmup"])
+
+
+def sleep_until(t: float) -> None:
+    while True:
+        wait = t - time.monotonic()
+        if wait <= 0:
+            return
+        time.sleep(min(wait, 0.05))
+
+
+class Marks(threading.Thread):
+    """Scrapes and profiler requests at fixed times of the window, off
+    the posting thread (its own HTTP connection)."""
+
+    def __init__(self, system, t0: float, seconds: float, trace: bool, workdir: str):
+        super().__init__(daemon=True)
+        self.system, self.t0, self.t1 = system, t0, t0 + seconds
+        self.trace = trace
+        self.trace_dir = os.path.join(workdir, "trace")
+        self.trace_s = min(TRACE_SECONDS, seconds / 3.0)
+        self.t_trace = t0 + TRACE_START_SHARE * seconds
+        self.out: dict = {}
+        self.error: BaseException | None = None
+
+    def run(self) -> None:
+        try:
+            sleep_until(self.t0)
+            self.out["log0"] = _log_size(self.system)
+            self.out["m0"] = self.system.scrape()
+            if self.trace:
+                sleep_until(self.t_trace)
+                self.out["trace_on"] = self.system.ask("trace_start", self.trace_dir)
+                sleep_until(time.monotonic() + self.trace_s)
+                self.out["trace_off"] = self.system.ask("trace_stop", timeout=240.0)
+            sleep_until(self.t1)
+            self.out["m1"] = self.system.scrape()
+            self.out["log1"] = _log_size(self.system)
+        except BaseException as e:  # re-raised by the main thread at join
+            self.error = e
+
+    def finish(self) -> dict:
+        self.join(timeout=300.0)
+        if self.is_alive():
+            raise serve_mod.ServeError("window marks did not finish")
+        if self.error is not None:
+            raise self.error
+        return self.out
+
+
+def drive(args, cell: dict, cfg: dict, system, workdir: str) -> dict:
+    """Everything of a run but the look for a chip and the spawn:
+    ``system`` is the served scheduler (``lib.serve.Serve``, or a
+    stand-in in control.py and the tests). Returns the result line."""
+    rehearse = bool(args.rehearse_cpu)
+    seconds = float(args.seconds)
+    loop = cell["loop"]
+    ready_s = system.wait_healthy()
+    tail = journal.JournalTail(system.journal)
+    device = serve_mod.device_of(system.scrape())
+    log(f"serve up in {ready_s:.2f}s on {device}")
+    if not rehearse and device["platform"] != "tpu":
+        raise serve_mod.ServeError(f"the child runs on {device}, not on a TPU")
+    if device["count"] < int(cell["chips"]):
+        raise serve_mod.ServeError(
+            f"the cell asks for {cell['chips']} chips, the child has {device['count']}"
+        )
+    peaks = None if rehearse else solve_work.load_peaks(device["kind"])
+
+    offer = loops.Offer(cfg, system)
+    stream = gen.RolloutStream(cfg, args.seed)
+    max_offered = int(cfg["validWhile"]["maxPodsOffered"])
+
+    # -- set-up: initPods, then this cell's shapes -------------------------
+    # initPods, then the cell's warm-up bursts: each is posted whole and
+    # waited to bound, so the next dispatch finds the pipeline drained
+    # and syncs every node column the burst touched in one go
+    for specs in [gen.init_pods(cfg)] + [
+        stream.take(int(n)) for n in cell["warmup"].get("bursts", [])
+    ]:
+        for lo in range(0, len(specs), 1000):
+            offer.post(specs[lo : lo + 1000])
+        loops.wait_bound(system, tail, offer.n_posted, timeout=1500.0)
+        log(f"{offer.n_posted} pods bound at {time.monotonic() - T_PROCESS:.1f}s")
+    if loop["kind"] != "backlog":
+        raise ValueError(f"unknown loop kind {loop['kind']!r}")
+    depth, chunk = int(loop["depth"]), int(loop["chunk"])
+    loops.backlog(
+        offer, tail, stream, depth, chunk,
+        until_bound=offer.n_posted + int(cell["warmup"]["until_bound"]),
+        max_offered=max_offered,
+    )
+    t0 = time.monotonic() + 0.05
+    n_setup_posted = offer.n_posted
+    marks = Marks(system, t0, seconds, bool(args.trace), workdir)
+    marks.start()
+
+    # -- the window ---------------------------------------------------------
+    sleep_until(t0)
+    t1 = t0 + seconds
+    loops.backlog(
+        offer, tail, stream, depth, chunk, stop_at=t1, max_offered=max_offered,
+    )
+    setup_s = t0 - T_PROCESS
+    sleep_until(t1)
+
+    # -- after the close: late answers are late, not wrong -------------------
+    time.sleep(0.3)  # the records of the batch that straddles t1
+    tail.poll()
+    child_alive = system.alive()
+    seen = marks.finish() if child_alive else dict(marks.out)
+    m_end = system.scrape() if child_alive else seen.get("m1", {})
+    mem_peak = system.memory_peak_bytes() if child_alive else None
+    system.close()
+    tail.poll()
+
+    # -- what the window produced -------------------------------------------
+    bound_at = dict(zip(tail.keys, tail.times))
+    in_window = [t for t in tail.times if t0 <= t < t1]
+    numbers: dict = {}  # name -> (value, op, limit)
+    # decisions about offered pods (the telemetry sentinel journals its
+    # own anomaly records under the same kind)
+    other = [
+        r for r in tail.other
+        if t0 <= r["t"] < t1 and r.get("pod") in offer.specs
+    ]
+    newest = max(
+        (i for i, k in enumerate(offer.order) if k in bound_at), default=-1
+    )
+    lost = sum(
+        1
+        for k in offer.order[: max(0, newest - int(LOST_MARGIN_SHARE * depth))]
+        if k not in bound_at
+    )
+    attempted = len(in_window) + len(other)
+    not_bound = len(other) + lost
+    replay = reference.replay(cfg, offer.specs, list(zip(tail.keys, tail.nodes)))
+    fallbacks = _fallback_events(m_end)
+    numbers["child_exits"] = (0 if child_alive else 1, "<=", 0)
+    numbers["fallback_events"] = (fallbacks, "<=", 0)
+    numbers["pods_not_bound"] = (not_bound, "<=", 0)
+    for name in (
+        "unknown_bindings", "bound_twice", "infeasible_at_commit",
+        "nodes_over_capacity",
+    ):
+        numbers[name] = (replay[name], "<=", 0)
+    # a guarantee of a pod kind is compared where the configuration has it
+    kinds = cfg["stream"]["kinds"]
+    if "anti" in kinds:
+        numbers["anti_affinity_clashes"] = (replay["anti_affinity_clashes"], "<=", 0)
+    if "spread" in kinds:
+        numbers["max_zone_skew"] = (
+            replay["max_zone_skew"], "<=", int(kinds["spread"]["maxSkew"])
+        )
+    numbers["window_pods_bound"] = (len(in_window), ">=", 1)
+    numbers["device_h2d_bytes"] = (
+        serve_mod.metric_sum(m_end, "scheduler_tpu_host_to_device_bytes_total"),
+        ">=", 1,
+    )
+    correct = all(
+        (v <= lim) if op == "<=" else (v >= lim) for v, op, lim in numbers.values()
+    )
+    failed = (
+        not_bound + replay["unknown_bindings"] + replay["bound_twice"]
+        + replay["infeasible_at_commit"]
+    )
+    for note in replay["notes"]:
+        log(f"reference: {note}")
+
+    # -- metrics -------------------------------------------------------------
+    trace = None
+    if args.trace and "trace_off" in seen:
+        os.environ["JAX_PLATFORMS"] = "cpu"  # the child is gone; read, don't grab
+        path = trace_reduce.find_xplane(marks.trace_dir)
+        if path:
+            t_r = time.monotonic()
+            trace = trace_reduce.reduce(trace_reduce.load_xplane(path))
+            log(f"trace {os.path.getsize(path)} B reduced in {time.monotonic() - t_r:.1f}s")
+    m0, m1 = seen.get("m0"), seen.get("m1")
+
+    def delta(name: str, **labels):
+        if m0 is None or m1 is None:
+            return None
+        s = serve_mod.metric_sum
+        return s(m1, name, **labels) - s(m0, name, **labels)
+
+    traced = None
+    if trace is not None and trace["anchor_ns"] is not None:
+        # the span of the device's own events, placed on the host's
+        # monotonic clock by the wrapper's anchor event; the pods bound
+        # inside it, and the solves at the window's solves per pod
+        off = seen["trace_on"]["t_anchor"] - trace["anchor_ns"] / 1e9
+        lo, hi = trace["lo_ns"] / 1e9 + off, trace["hi_ns"] / 1e9 + off
+        pods = sum(1 for t in tail.times if lo <= t < hi)
+        solves = delta("scheduler_tpu_solve_batch_size_count")
+        traced = {
+            "pods": pods,
+            "solves": (
+                pods * solves / len(in_window) if solves and in_window else None
+            ),
+            "seconds": hi - lo,
+            "asked_seconds": seen["trace_off"]["t_ask"] - seen["trace_on"]["t_on"],
+            "from_t0": lo - t0,
+            "programs": trace["programs"],
+        }
+    ctx = {
+        "cell": cell, "config": cfg, "seconds": seconds, "t0": t0, "t1": t1,
+        "setup_s": setup_s, "serve_ready_s": ready_s,
+        "m0": m0, "m1": m1, "delta": delta,
+        "trace": trace if traced else None, "traced": traced, "peaks": peaks,
+        "bound_times": tail.times, "bound_at": bound_at,
+        "bound_in_window": len(in_window),
+        "posts": offer.posts, "metric_sum": serve_mod.metric_sum,
+        "solve_work": solve_work,
+    }
+    kind = "per_layer" if args.trace else "end_to_end"
+    metrics = {}
+    for name, m in sorted(files.metrics_of_cell(cell, kind).items()):
+        value = files.load_reader(m)(ctx, **m.get("args", {}))
+        if value is not None:
+            metrics[name] = {"value": value, "unit": m["unit"]}
+    if m0 and m1:
+        say(info="window", stage_seconds=_stage_seconds(m0, m1),
+            compile=_compile_counts(m0, m1), traced=traced,
+            posts=len(offer.posts), offered=offer.n_posted, bound=tail.n_bound,
+            bound_in_window=len(in_window),
+            setup_posted=n_setup_posted,
+            other_decisions=[
+                {k: r.get(k) for k in ("pod", "outcome", "reason", "t")}
+                for r in tail.other[:5]
+            ],
+            compiled_in_window=_compiled_in_window(system, seen),
+            discarded_solves_in_window=delta("scheduler_tpu_solves_discarded_total"),
+            bound_per_s=_timeline(tail.times, t0, -5, int(seconds) + 10),
+            offered_per_s=_timeline(
+                [p[0] for p in offer.posts for _ in range(p[2])], t0, -5,
+                int(seconds) + 10,
+            ),
+            largest_commit_gap_s=max(
+                (b - a for a, b in zip(in_window, in_window[1:])), default=None
+            ))
+
+    dev = dict(device)
+    if not rehearse:
+        dev["memory_peak_bytes"] = mem_peak
+        if trace is not None:
+            dev["busy_s"], dev["window_s"] = trace["busy_s"], trace["window_s"]
+    line: dict = {
+        "correct": bool(correct), "attempted": attempted, "failed": failed,
+        "metrics": metrics, "device": dev,
+    }
+    if rehearse:
+        line["rehearsal"] = "cpu: no number here is a device number"
+    if trace is not None:
+        line["breakdown"] = {
+            "device_ops": trace["device_ops"], "idle_gaps": trace["idle_gaps"],
+        }
+    line["compared"] = {
+        n: {"value": v, "limit": f"{op} {lim}"} for n, (v, op, lim) in numbers.items()
+    }
+    for n, (v, op, lim) in numbers.items():
+        print(f"compared {n}: {v} (limit {op} {lim})", file=sys.stderr)
+    sys.stderr.flush()
+    return line
+
+
+def _log_size(system) -> int:
+    path = getattr(system, "log_path", None)
+    return os.path.getsize(path) if path and os.path.exists(path) else 0
+
+
+def _compiled_in_window(system, seen: dict) -> list:
+    """Names of the programs JAX logged as compiling (or fetching)
+    between the window's two marks (JAX_LOG_COMPILES)."""
+    path = getattr(system, "log_path", None)
+    if not path or "log1" not in seen:
+        return []
+    with open(path, "rb") as f:
+        f.seek(seen["log0"])
+        text = f.read(seen["log1"] - seen["log0"]).decode(errors="replace")
+    return [
+        row.split("Compiling ", 1)[1].split(" with ", 1)[0]
+        for row in text.splitlines()
+        if "Compiling " in row
+    ][:20]
+
+
+def _timeline(times: list, t0: float, lo: int, hi: int) -> list:
+    """Events per second of the run, from ``lo`` to ``hi`` seconds
+    after the window's start."""
+    out = [0] * (hi - lo)
+    for t in times:
+        k = int((t - t0) // 1) - lo
+        if 0 <= k < len(out):
+            out[k] += 1
+    return out
+
+
+def _fallback_events(m: dict) -> float:
+    s = serve_mod.metric_sum
+    return (
+        s(m, "scheduler_tpu_fallback_solves_total")
+        + s(m, "scheduler_tpu_breaker_transitions_total", transition="trip")
+        + s(m, "scheduler_tpu_breaker_transitions_total", transition="rebuild")
+        + s(m, "scheduler_tpu_breaker_state")
+        + s(m, "scheduler_tpu_quarantined_pods_total")
+        + s(m, "scheduler_pipeline_mode_total", mode="sync")
+    )
+
+
+def _stage_seconds(m0: dict, m1: dict) -> dict:
+    out = {}
+    for (name, labels), v in m1.items():
+        if name == "scheduler_profile_stage_seconds_total":
+            stage = dict(labels).get("stage")
+            out[stage] = v - m0.get((name, labels), 0.0)
+    return out
+
+
+def _compile_counts(m0: dict, m1: dict) -> dict:
+    s = serve_mod.metric_sum
+
+    def d(name: str) -> float:
+        return s(m1, name) - s(m0, name)
+
+    return {
+        "executables_before_window": s(m0, "scheduler_xla_compilations_total"),
+        "cache_hits_before_window": s(m0, "scheduler_xla_persistent_cache_hits_total"),
+        "executables_in_window": d("scheduler_xla_compilations_total"),
+        "cache_hits_in_window": d("scheduler_xla_persistent_cache_hits_total"),
+    }
+
+
+def main(argv=None) -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--workload", required=True)
+    ap.add_argument("--seed", type=int, required=True)
+    ap.add_argument("--seconds", type=float, required=True)
+    ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
+    ap.add_argument(
+        "--rehearse-cpu", action="store_true",
+        help="plumbing rehearsal on the CPU backend at the cell's tiny "
+        "'rehearse' size; the result carries no device metric",
+    )
+    args = ap.parse_args(argv)
+    cell = files.load_workload(args.workload)
+    cfg = files.load_config(cell["config"])
+    if args.rehearse_cpu:
+        apply_rehearsal(cell, cfg)
+    if not os.path.isfile(os.path.join(files.ROOT, "kubernetes_tpu", "cli.py")):
+        log(f"no program in {files.ROOT}: kubernetes_tpu/cli.py is missing")
+        return 2
+    workdir = os.path.join(files.ROOT, ".bench_work", cell["name"])
+    shutil.rmtree(workdir, ignore_errors=True)
+    os.makedirs(workdir)
+    state_path = os.path.join(workdir, "state.json")
+    gen.write_state_file(cfg, state_path)
+    system = serve_mod.Serve(
+        files.ROOT, workdir, state_path,
+        platforms="cpu" if args.rehearse_cpu else "tpu",
+        telemetry=bool(args.trace),
+    )
+    try:
+        line = drive(args, cell, cfg, system, workdir)
+    except (serve_mod.ServeError, TimeoutError, RuntimeError) as e:
+        log(f"no result: {type(e).__name__}: {e}")
+        return 1
+    finally:
+        system.close()
+    print(json.dumps(line), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    raise SystemExit(main())
