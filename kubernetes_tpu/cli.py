@@ -128,6 +128,12 @@ def cmd_serve(args) -> int:
     sched_cfg = config_types.scheduler_config(cfg)
     sched_cfg.feature_gates = _feature_gates(args)
     telemetry_on = bool(args.telemetry or args.bundle_dir)
+    if telemetry_on:
+        # a device trace of this process is read by scope name: do not
+        # take executables built under other names from the cache
+        from .utils.compile_cache import key_on_op_names
+
+        key_on_op_names()
     if (
         args.obs or args.obs_journal or args.obs_dump or args.slo
         or telemetry_on
@@ -277,11 +283,6 @@ def main(argv: list[str] | None = None) -> int:
         '"SchedulerQueueingHints=false,PodSchedulingReadiness=true"',
     )
     parser.add_argument(
-        "--trace-dir",
-        help="write jax.profiler TensorBoard traces of device solves here "
-        "(SURVEY §6.1; the --profiling analog)",
-    )
-    parser.add_argument(
         "--leader-elect",
         action="store_true",
         help="Lease-based active/passive leader election over the state "
@@ -360,7 +361,10 @@ def main(argv: list[str] | None = None) -> int:
         help="enable always-on flight telemetry (kubernetes_tpu/obs): "
         "continuous per-stage profiler + anomaly sentinel (implies the "
         "SLO engine for the p99 signal), served at GET /debug/profile "
-        "and exported as scheduler_profile_* / scheduler_anomaly_*",
+        "and exported as scheduler_profile_* / scheduler_anomaly_*. "
+        "Each stage is also a jax.profiler TraceAnnotation "
+        "('stage:<name>', and 'stage:ingest' around POST /api/pods), "
+        "so any profiler session shows the stages beside the device",
     )
     p_serve.add_argument(
         "--bundle-dir",
@@ -380,13 +384,6 @@ def main(argv: list[str] | None = None) -> int:
     p_cfg.set_defaults(fn=cmd_config)
 
     args = parser.parse_args(argv)
-    if args.trace_dir:
-        import atexit
-
-        from .utils import tracing
-
-        tracing.enable(args.trace_dir)
-        atexit.register(tracing.stop)
     return args.fn(args)
 
 

@@ -633,11 +633,35 @@ journal_records_total = Counter(
     ["outcome"],
     registry=REGISTRY,
 )
+journal_seconds_total = Counter(
+    "scheduler_tpu_trace_journal_seconds_total",
+    "Wall seconds spent inside PodDecisionJournal.record (flight "
+    "recorder and sink write included). Flushed together with "
+    "scheduler_tpu_trace_journal_records_total, so the ratio of the "
+    "two deltas over any interval is the journal's seconds per record.",
+    registry=REGISTRY,
+)
 flight_recorder_dumps_total = Counter(
     "scheduler_tpu_flight_recorder_dumps_total",
     "Flight-recorder ring dumps, by trigger "
     "(crash|invariant|manual|breaker).",
     ["trigger"],
+    registry=REGISTRY,
+)
+
+# -- ingest (server/extender.py POST /api/pods) --
+
+ingest_seconds_total = Counter(
+    "scheduler_ingest_seconds_total",
+    "Wall seconds the POST /api/pods handler spent parsing a body and "
+    "applying its pods to the state service (the body's network read "
+    "is not in it). Time waiting for the interpreter lock while the "
+    "handler runs is.",
+    registry=REGISTRY,
+)
+ingest_pods_total = Counter(
+    "scheduler_ingest_pods_total",
+    "Pods applied through POST /api/pods.",
     registry=REGISTRY,
 )
 

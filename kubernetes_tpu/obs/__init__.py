@@ -1,6 +1,11 @@
 """kubernetes_tpu.obs — the end-to-end scheduling trace layer.
 
-Three cooperating pieces, all zero-dep and virtual-time-clean:
+Three cooperating pieces, all zero-dep and virtual-time-clean (the
+flight telemetry further down adds the one piece that reaches a
+profiler: ``Telemetry.stage``, the seam that times the seven batch
+stages for ``scheduler_profile_stage_seconds`` AND writes each as a
+``jax.profiler.TraceAnnotation`` — there is no other device-trace
+hook in the package):
 
 - **spans** (``span.py``): OTel-shaped host-side spans threaded through
   both scheduler loops (enqueue → snapshot → tensorize → fold/extender
@@ -188,6 +193,28 @@ def build_obs(
     return tracer, journal, recorder
 
 
+class _Stage:
+    """An open stage interval of ``Telemetry.stage``."""
+
+    __slots__ = ("_tel", "_name", "_attrs", "_ann", "_t0")
+
+    def __init__(self, tel: "Telemetry", name: str, attrs: dict) -> None:
+        self._tel, self._name, self._attrs = tel, name, attrs
+
+    def __enter__(self) -> "_Stage":
+        # the annotation starts when it is built, not when it is entered
+        self._ann = self._tel.annotation("stage:" + self._name, **self._attrs)
+        self._t0 = self._tel.clock.perf()
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        seconds = self._tel.clock.perf() - self._t0
+        self._ann.__exit__(exc_type, exc, tb)
+        if exc_type is None:
+            self._tel.add_stage(self._name, seconds)
+        return False
+
+
 class Telemetry:
     """The flight-telemetry coordinator: one object on the scheduler
     holding the profiler, the sentinel (+ its health ring), and the
@@ -195,7 +222,8 @@ class Telemetry:
 
     The scheduler's hot path pays one ``is not None`` check when
     telemetry is off; when on, every write here is host-side arithmetic
-    over numbers the loops already computed (TPU001-clean — the whole
+    over numbers the loops already computed, or a profiler annotation
+    that is free outside a profiler session (TPU001-clean — the whole
     layer rides inside bench ladder #13's <= 5% obs budget)."""
 
     def __init__(
@@ -214,6 +242,11 @@ class Telemetry:
         self.bundles = bundles
         self.journal = journal
         self.recorder = recorder
+        # jax.profiler.TraceAnnotation, resolved once, here: a stage
+        # (or the ingest handler) never imports or looks it up
+        from jax.profiler import TraceAnnotation
+
+        self.annotation = TraceAnnotation
         self.anomalies: list = []  # every Anomaly fired, for surfaces
         # window accumulation state (driver thread only)
         self._win_batches = 0
@@ -227,11 +260,25 @@ class Telemetry:
             "trips": 0.0,
         }
 
-    # -- stage attribution passthrough (scheduler seams) --
+    # -- the stage seam (scheduler call sites, dispatch-loop thread) --
 
     def add_stage(self, stage: str, seconds: float) -> None:
+        """Attribute a duration measured elsewhere. Only ``fence_wait``
+        still comes this way: it re-books a discarded flight's past
+        dispatch + read seconds, so it has no interval of its own."""
         if self.profiler is not None:
             self.profiler.add(stage, seconds)
+
+    def stage(self, name: str, **attrs) -> "_Stage":
+        """One stage's interval, as a context manager: a
+        ``jax.profiler.TraceAnnotation("stage:<name>", **attrs)`` around
+        the block (free unless a profiler session is on, and then on the
+        same clock as the device's events), and the block's
+        ``clock.perf()`` difference to the profiler, as ``add_stage``
+        hands it. A block that raises books nothing, as before. Where
+        the two reads live in two functions (tensorize, apply) the site
+        calls ``__enter__`` / ``__exit__`` itself."""
+        return _Stage(self, name, attrs)
 
     # -- the per-batch tick (commit seam, next to the SLO engine) --
 

@@ -293,6 +293,9 @@ class PodDecisionJournal:
         # ``lines`` read / pending flush
         self._outcome_counters: dict = {}
         self._outcome_pending: dict[str, int] = {}
+        # seconds spent in record() since the last flush
+        # (scheduler_tpu_trace_journal_seconds_total)
+        self._seconds_pending = 0.0
 
     def record(
         self,
@@ -308,6 +311,7 @@ class PodDecisionJournal:
         attempts: int = 0,
         nominated: str = "",
     ) -> dict:
+        t_rec = self.clock.perf()
         rec: dict = {
             "k": "dec",
             "v": SCHEMA_VERSION,
@@ -355,14 +359,18 @@ class PodDecisionJournal:
         self._outcome_pending[outcome] = (
             self._outcome_pending.get(outcome, 0) + 1
         )
-        if len(self._pending) >= 4096:
-            # amortized flush bound: a serve process that is never
-            # read must not grow the pending list without limit
-            self._flush_pending()
         if self.recorder is not None:
             self.recorder.record_decision(rec)
         if self.sink is not None:
             self.sink(rec)
+        # the journal's own cost: a plain float, handed to the registry
+        # with the record counts (_flush_pending, which adds its own
+        # seconds), so the two counters move at the same moments
+        self._seconds_pending += self.clock.perf() - t_rec
+        if len(self._pending) >= 4096:
+            # amortized flush bound: a serve process that is never
+            # read must not grow the pending list without limit
+            self._flush_pending()
         return rec
 
     def unschedulable(
@@ -381,6 +389,7 @@ class PodDecisionJournal:
         )
 
     def _flush_pending(self) -> None:
+        t_flush = self.clock.perf()
         pending, self._pending = self._pending, []
         self._lines.extend(canonical(r) for r in pending)
         counts, self._outcome_pending = self._outcome_pending, {}
@@ -391,6 +400,10 @@ class PodDecisionJournal:
                     metrics.journal_records_total.labels(outcome)
                 )
             counter.inc(n)
+        seconds, self._seconds_pending = self._seconds_pending, 0.0
+        metrics.journal_seconds_total.inc(
+            seconds + self.clock.perf() - t_flush
+        )
 
     @property
     def lines(self):

@@ -1,12 +1,16 @@
 """Continuous per-stage profiler: where did each batch's wall time go?
 
-A bounded per-batch **stage ledger** assembled host-side from numbers
-the dispatch loops already compute — ``prep.tensorize_seconds``, the
-dispatch span, the deferred-read wait, the locked validate/apply
-region, the per-entry bind wall — plus within-batch deltas of the
-transfer/decision counters (h2d/d2h bytes, sub-batch splits, stream
-chains, discards). Zero new device syncs (TPU001-clean): every number
-is either a ``clock.perf()`` difference the loop already took or a
+A bounded per-batch **stage ledger** assembled host-side at ONE seam,
+``Telemetry.stage(name)`` (obs/__init__.py): a context manager around
+each stage's work in the dispatch loops — tensorize to the first
+dispatch, the dispatch, the deferred-read wait, the locked validate and
+apply regions, the batch's bind commits — that hands the block's
+``clock.perf()`` difference to :meth:`StageProfiler.add` and, for the
+same interval, writes a ``jax.profiler.TraceAnnotation("stage:<name>")``
+that any profiler session shows on the device trace's clock. Plus
+within-batch deltas of the transfer/decision counters (h2d/d2h bytes,
+sub-batch splits, stream chains, discards). Zero new device syncs
+(TPU001-clean): every number is a ``clock.perf()`` difference or a
 host-side prometheus cell read, the CounterWindow discipline from
 ``tuning/window.py``.
 
@@ -18,7 +22,8 @@ Stage taxonomy (one batch's life):
 
     tensorize     host: cluster state -> padded device arrays
     dispatch      host: solve dispatch (upload + jit call, async)
-    fence_wait    host: work discarded to fences (stale flights)
+    fence_wait    host: work discarded to fences (stale flights; booked
+                  after the fact, so it alone has no annotation)
     deferred_read device->host: blocking assignment read (the RTT)
     validate      host: assignment validation under the lock
     apply         host: assume/reserve under the lock
@@ -74,7 +79,7 @@ _DELTA_READERS = {
 class StageProfiler:
     """Always-on per-batch stage attribution.
 
-    The loops call :meth:`add` at the seams they already time and
+    The loops reach :meth:`add` through ``Telemetry.stage`` and call
     :meth:`observe_batch` once per applied batch (next to the SLO
     tick in ``_commit_all``); readers call :meth:`snapshot` from any
     thread. ``capacity`` bounds the ledger — a serving process retains

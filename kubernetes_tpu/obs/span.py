@@ -1,6 +1,7 @@
 """Zero-dep span tracing for the scheduling loops (the tentpole of the
-trace layer, SURVEY §6.1's *host-side* complement to ``utils/tracing``'s
-jax-profiler device traces).
+trace layer, SURVEY §6.1). These spans stay on the host's clock, in the
+flight recorder; what a jax-profiler session shows beside the device's
+events is the telemetry stage seam (``Telemetry.stage``), not these.
 
 Spans are OTel-shaped — name, span/trace/parent ids, attributes, start
 and end timestamps — but carry **two** time bases from the injectable

@@ -387,6 +387,9 @@ class _PreparedGroup:
     # tensorize/PreFilter histograms exactly when operators are
     # reading them to diagnose an outage)
     timing_observed: bool = False
+    # the open telemetry stage "tensorize" (None with telemetry off, and
+    # once the first dispatch has closed it)
+    tensorize_stage: object = None
 
 
 @dataclass
@@ -1396,19 +1399,13 @@ class Scheduler:
         counts them, and a mid-solve cache mutation lands in the NEXT
         cycle's snapshot (the same staleness window the reference's
         binding goroutines accept)."""
-        from .utils import tracing
-
         self._trace_step += 1
-        step = self._trace_step
-        if tracing.enabled():
-            with tracing.step("schedule_batch", step):
-                return self._cycle_observed(step)
-        return self._cycle_observed(step)
+        return self._cycle_observed(self._trace_step)
 
     def _cycle_observed(self, step: int) -> BatchResult:
         """One cycle under the obs root span, with the flight recorder
         dumped if the cycle dies (the crash trigger). The span and the
-        jax-profiler step annotation share the ``_trace_step`` id."""
+        telemetry stages inside it share the ``_trace_step`` id."""
         if not self.obs.enabled and self.flight is None:
             return self._schedule_cycle()
         try:
@@ -1610,52 +1607,59 @@ class Scheduler:
             # kills the process (sim/harness.py crash_restart)
             hook(hook_pending)
         first_err = None
-        bind_wall = 0.0
-        for entry in pending:
-            tb = self.clock.perf()
-            # bind spans are 1-in-N sampled (ObsConfig.bind_span_
-            # sample_n; deterministic counter, first bind always
-            # sampled): the journal below stays COMPLETE per pod — the
-            # span only adds the commit's wall duration, which
-            # sampling preserves statistically, and per-pod spans at
-            # sustained-stream volume are what the obs-overhead
-            # budget cannot afford
-            self._bind_commits += 1
-            bn = self._bind_sample_n
-            span_ctx = (
-                self.obs.span(
-                    "bind", trace_id=entry[6], pod=entry[2].key,
-                    node=entry[3],
-                    **({"sample_n": bn} if bn > 1 else {}),
-                )
-                if bn <= 1 or self._bind_commits % bn == 1
-                else _NOOP_SPAN
+        # the bind stage: every per-pod commit of this batch, journal
+        # record included (the gang commits below are not in it)
+        with (
+            self.telemetry.stage(
+                "bind", step=self._trace_step, pods=len(pending)
             )
-            with span_ctx as bsp:
-                try:
-                    ok = self._commit_binding(entry, res)
-                except Exception as e:  # a buggy PreBind/PostBind plugin
-                    # must not strand the REST of the approved batch:
-                    # roll this pod back, keep committing, re-raise last
-                    ok = False
-                    first_err = first_err or e
-                    state, info, pod, node_name, cycle, _ts, step = entry
-                    with self.cluster.lock:
-                        self._unreserve_all(state, pod, node_name)
-                        res.bind_failures.append((pod.key, repr(e)))
-                        self._requeue(info, cycle)
-                        if self.journal is not None:
-                            self.journal.record(
-                                step, cycle, pod, "bind_failure",
-                                node=node_name, reason=repr(e),
-                                attempts=info.attempts,
-                            )
-                bsp.set(ok=ok)
-            bind_dur = self.clock.perf() - tb
-            bind_wall += bind_dur
-            metrics.framework_extension_point_duration_seconds.labels(
-                "Bind", "Success" if ok else "Error", "all"
-            ).observe(bind_dur)
+            if self.telemetry is not None and pending
+            else _NOOP_SPAN
+        ):
+            for entry in pending:
+                tb = self.clock.perf()
+                # bind spans are 1-in-N sampled (ObsConfig.bind_span_
+                # sample_n; deterministic counter, first bind always
+                # sampled): the journal below stays COMPLETE per pod — the
+                # span only adds the commit's wall duration, which
+                # sampling preserves statistically, and per-pod spans at
+                # sustained-stream volume are what the obs-overhead
+                # budget cannot afford
+                self._bind_commits += 1
+                bn = self._bind_sample_n
+                span_ctx = (
+                    self.obs.span(
+                        "bind", trace_id=entry[6], pod=entry[2].key,
+                        node=entry[3],
+                        **({"sample_n": bn} if bn > 1 else {}),
+                    )
+                    if bn <= 1 or self._bind_commits % bn == 1
+                    else _NOOP_SPAN
+                )
+                with span_ctx as bsp:
+                    try:
+                        ok = self._commit_binding(entry, res)
+                    except Exception as e:  # a buggy PreBind/PostBind plugin
+                        # must not strand the REST of the approved batch:
+                        # roll this pod back, keep committing, re-raise last
+                        ok = False
+                        first_err = first_err or e
+                        state, info, pod, node_name, cycle, _ts, step = entry
+                        with self.cluster.lock:
+                            self._unreserve_all(state, pod, node_name)
+                            res.bind_failures.append((pod.key, repr(e)))
+                            self._requeue(info, cycle)
+                            if self.journal is not None:
+                                self.journal.record(
+                                    step, cycle, pod, "bind_failure",
+                                    node=node_name, reason=repr(e),
+                                    attempts=info.attempts,
+                                )
+                    bsp.set(ok=ok)
+                bind_dur = self.clock.perf() - tb
+                metrics.framework_extension_point_duration_seconds.labels(
+                    "Bind", "Success" if ok else "Error", "all"
+                ).observe(bind_dur)
         for gid, rd in gang_ready:
             # one atomic all-or-nothing commit per complete gang round
             try:
@@ -1693,11 +1697,10 @@ class Scheduler:
             self.slo.observe_batch(res)
         if self.telemetry is not None and (infos or pending):
             # flight-telemetry tick, same post-commit chokepoint as the
-            # SLO engine: close the batch's stage ledger (the bind wall
-            # just measured is the last stage) and, at window
-            # boundaries, run the sentinel's regression rules. All
-            # host arithmetic; anomalies journal + capture here.
-            self.telemetry.add_stage("bind", bind_wall)
+            # SLO engine: close the batch's stage ledger (the bind stage
+            # above was the last one) and, at window boundaries, run
+            # the sentinel's regression rules. All host arithmetic;
+            # anomalies journal + capture here.
             self.telemetry.observe_batch(
                 self, step=self._trace_step, pods=len(pending)
             )
@@ -2495,6 +2498,16 @@ class Scheduler:
         view of cache + cluster."""
         solver = self.solvers[profile]
         gs = self.clock.perf()
+        # the tensorize stage runs from here to the first dispatch's t1
+        # (_dispatch_group closes it): fold, a fence drain and the
+        # session bookkeeping are in it, as they are in t1 - gs
+        tensorize_stage = (
+            self.telemetry.stage(
+                "tensorize", step=self._trace_step, pods=len(infos)
+            ).__enter__()
+            if self.telemetry is not None
+            else None
+        )
         with self.cluster.lock, self.obs.span(
             # explicit trace id: the pipelined loop has no root span, so
             # parent inheritance alone would leave these spans on trace 0
@@ -2785,6 +2798,7 @@ class Scheduler:
                     or nom_pairs
                 ),
                 step=self._trace_step,
+                tensorize_stage=tensorize_stage,
             )
 
     def _fold_group(self, prep: _PreparedGroup) -> None:
@@ -2990,6 +3004,11 @@ class Scheduler:
             # not strand a consumed stale flag), before the solve
             hook(prep.pods, tier_name)
         mesh = self.mesh if tier_name == TIER_MESH else None
+        if prep.tensorize_stage is not None:
+            # opened by _tensorize_group where prep.gs was read; a
+            # re-dispatch of the same prep finds it closed
+            prep.tensorize_stage.__exit__(None, None, None)
+            prep.tensorize_stage = None
         t1 = self.clock.perf()
         # backlog drains thread the chunk id into the dispatch span so
         # `obs explain` can attribute a pod to its drain chunk
@@ -3021,7 +3040,13 @@ class Scheduler:
             # telemetry capture arm: the solver's capture_hook payload
             # that fires inside solve() below belongs to this batch step
             self.telemetry.bundles.arm(prep.step, prep.profile)
-        with self.obs.span(
+        with (
+            self.telemetry.stage(
+                "dispatch", step=prep.step, pods=len(prep.pods)
+            )
+            if self.telemetry is not None
+            else _NOOP_SPAN
+        ), self.obs.span(
             "dispatch", trace_id=prep.step, profile=prep.profile,
             defer=defer, healed=heal_stale, split=split,
             mesh_devices=self._mesh_devices, **span_extra,
@@ -3047,15 +3072,9 @@ class Scheduler:
                     xla_compile_s=round(compile_s, 6),
                 )
         dispatch_dt = self.clock.perf() - t1
-        if self.telemetry is not None:
-            self.telemetry.add_stage("dispatch", dispatch_dt)
         if not prep.timing_observed:
             prep.timing_observed = True
             prep.tensorize_seconds = max(t1 - prep.gs, 0.0)
-            if self.telemetry is not None:
-                self.telemetry.add_stage(
-                    "tensorize", prep.tensorize_seconds
-                )
             metrics.tensorize_seconds.observe(prep.tensorize_seconds)
             # extension-point durations with the reference's metric
             # names: host tensorization maps to PreFilter (documented,
@@ -3139,19 +3158,24 @@ class Scheduler:
         unsched_before = len(res.unschedulable)
         failures_before = len(res.bind_failures)
         tr = self.clock.perf()
-        try:
-            assignments = flight.assignments()
-        except Exception as e:
-            # the deferred device→host read itself died (session /
-            # transfer loss after dispatch): surface it as a solver
-            # fault so the resilience layer owns the retry instead of
-            # the loop crashing (kubernetes_tpu/resilience)
-            raise SolverReadError(
-                f"deferred assignment read failed: {e!r}"
-            ) from e
+        with (
+            self.telemetry.stage(
+                "deferred_read", step=prep.step, pods=len(infos)
+            )
+            if self.telemetry is not None
+            else _NOOP_SPAN
+        ):
+            try:
+                assignments = flight.assignments()
+            except Exception as e:
+                # the deferred device→host read itself died (session /
+                # transfer loss after dispatch): surface it as a solver
+                # fault so the resilience layer owns the retry instead
+                # of the loop crashing (kubernetes_tpu/resilience)
+                raise SolverReadError(
+                    f"deferred assignment read failed: {e!r}"
+                ) from e
         flight.read_seconds = self.clock.perf() - tr
-        if self.telemetry is not None:
-            self.telemetry.add_stage("deferred_read", flight.read_seconds)
         solve_dt = flight.dispatch_seconds + flight.read_seconds
         res.solve_seconds += solve_dt
         # the fused device program IS RunFilterPlugins+RunScorePlugins, so
@@ -3180,18 +3204,29 @@ class Scheduler:
                 # prep-time capacity can only have been FREED since the
                 # solve (capacity-consuming events discard first) — a
                 # flagged overcommit is always corruption, not churn.
-                tv = self.clock.perf()
-                why = validate_assignments(
-                    prep, flight.lo, assignments,
-                    disabled=frozenset(solver.config.disabled_filters),
-                )
-                if self.telemetry is not None:
-                    self.telemetry.add_stage(
-                        "validate", self.clock.perf() - tv
+                with (
+                    self.telemetry.stage(
+                        "validate", step=prep.step, pods=len(infos)
+                    )
+                    if self.telemetry is not None
+                    else _NOOP_SPAN
+                ):
+                    why = validate_assignments(
+                        prep, flight.lo, assignments,
+                        disabled=frozenset(solver.config.disabled_filters),
                     )
                 if why is not None:
                     raise SolveCorruptError(why)
-            t_apply = self.clock.perf()
+            # the apply stage: the locked assume/Reserve/Permit region
+            # after validation, to the end of this function (closed by
+            # hand there: the region outlives this with statement)
+            apply_stage = (
+                self.telemetry.stage(
+                    "apply", step=prep.step, pods=len(infos)
+                ).__enter__()
+                if self.telemetry is not None
+                else None
+            )
             if self.telemetry is not None and self.telemetry.bundles is not None:
                 # the flight applied (fence passed, output validated):
                 # its assignment slice is what a bundle replay of this
@@ -3639,9 +3674,8 @@ class Scheduler:
             )
         if n_fail:
             metrics.schedule_attempts_total.labels("error", profile).inc(n_fail)
-        if self.telemetry is not None:
-            # the locked assume/Reserve/Permit region after validation
-            self.telemetry.add_stage("apply", self.clock.perf() - t_apply)
+        if apply_stage is not None:
+            apply_stage.__exit__(None, None, None)
         return True
 
     def _fold_signature(self, static, slot_nodes) -> bytes:

@@ -46,6 +46,7 @@ accepts/returns bare node names resolved against that state.
 
 from __future__ import annotations
 
+import json
 import time
 from typing import Mapping, Sequence
 
@@ -214,7 +215,6 @@ class ExtenderCore:
 
     def _run_many(self, requests: list[tuple[str, Mapping]]) -> list:
         import hashlib
-        import json
 
         results: list = [None] * len(requests)
         # group key -> [(req_idx, verb, pod)]; key captures everything the
@@ -477,8 +477,6 @@ def _load_state_file(cluster: ClusterState, path: str) -> None:
     """Initial-state ingest: JSON/YAML with {"nodes": [...], "pods": [...],
     "services": [...], "pdbs": [...], "resourceSlices": [...],
     "deviceClasses": [...], "resourceClaims": [...]} of wire-shape dicts."""
-    import json
-
     with open(path) as f:
         text = f.read()
     try:
@@ -683,15 +681,31 @@ def make_app(
         return web.json_response({})
 
     async def post_pods(request):
-        doc = await _json(request)
+        body = await request.read()
+        # parse + apply, timed from the server's side (a client's round
+        # trip also holds HTTP and the wait for this thread); with
+        # telemetry on, the same interval is a profiler annotation
+        # beside the dispatch loop's stages
+        telemetry = getattr(scheduler, "telemetry", None)
+        ann = (
+            telemetry.annotation("stage:ingest")
+            if telemetry is not None
+            else None
+        )
+        t0 = time.perf_counter()
         created = 0
-        for pd in _items(doc):
+        for pd in _items(json.loads(body)):
             pod = Pod.from_dict(pd)
             try:
                 core.cluster.create_pod(pod)
             except ApiError:
                 core.cluster.update_pod(pod)
             created += 1
+        metrics.ingest_seconds_total.inc(time.perf_counter() - t0)
+        metrics.ingest_pods_total.inc(created)
+        if ann is not None:
+            ann.set_metadata(pods=created)
+            ann.__exit__(None, None, None)
         return web.json_response({"applied": created})
 
     async def delete_pod(request):

@@ -64,6 +64,26 @@ from ..tensorize.schema import MEM_IDX, NodeBatch, PodBatch
 TIE_RANDOM = "random"
 TIE_FIRST = "first"
 
+# jax.named_scope names on the served path's device programs, in one
+# place: the filter/score parts carry upstream's plugin names (they line
+# up with scheduler_plugin_execution_duration_seconds{plugin}), the rest
+# name the scan step's and the wire's own parts. A scope changes op_name
+# metadata only; the benchmark's scan_* metrics read it off the device
+# trace (benchmarks/lib/span_attrib.py).
+SCOPES = (
+    "NodeResourcesFit",
+    "NodePorts",
+    "PodTopologySpread",
+    "InterPodAffinity",
+    "Score",
+    "select",
+    "assume",
+    "grouped_fast",
+    "grouped_slow",
+    "unpack",
+    "pack",
+)
+
 
 @dataclass(frozen=True)
 class ExactSolverConfig:
@@ -276,55 +296,67 @@ def _mask_and_score(
             )
             port_used = port_used + extra_p
     if "NodeResourcesFit" not in disabled:
-        mask = mask & nr.fit_mask(
-            x["req"], x["req_mask"], alloc, used,
-            pod_count, tables["max_pods"],
-        )
+        with jax.named_scope("NodeResourcesFit"):
+            mask = mask & nr.fit_mask(
+                x["req"], x["req_mask"], alloc, used,
+                pod_count, tables["max_pods"],
+            )
     if "NodePorts" not in disabled:
-        mask = mask & ~pl.ports_conflict_mask(
-            x["pod_conflict"], port_used
-        )
+        with jax.named_scope("NodePorts"):
+            mask = mask & ~pl.ports_conflict_mask(
+                x["pod_conflict"], port_used
+            )
     if use_spread and "PodTopologySpread" not in disabled:
-        mask = mask & ~sp.hard_violations(spr, st["spr_cnt"], cls, d_pad)
+        with jax.named_scope("PodTopologySpread"):
+            mask = mask & ~sp.hard_violations(
+                spr, st["spr_cnt"], cls, d_pad
+            )
     if use_interpod:
-        ipa_allowed, ipa_raw = ip.filter_and_score(
-            ipa, st["ipa_in"], st["ipa_ex"], cls, x, ipa_d_pad,
-            tables["node_valid"],
-            ident=ipa_ident, score=ipa_score and w_interpod > 0,
-            pallas=pallas,
-        )
-        if "InterPodAffinity" not in disabled:
-            mask = mask & ipa_allowed
+        with jax.named_scope("InterPodAffinity"):
+            ipa_allowed, ipa_raw = ip.filter_and_score(
+                ipa, st["ipa_in"], st["ipa_ex"], cls, x, ipa_d_pad,
+                tables["node_valid"],
+                ident=ipa_ident, score=ipa_score and w_interpod > 0,
+                pallas=pallas,
+            )
+            if "InterPodAffinity" not in disabled:
+                mask = mask & ipa_allowed
 
-    requested = nr.scoring_requested(x["nonzero_req"], st["nonzero_used"])
-    score = w_fit * fit_scorer(requested, alloc2, weights2)
-    score = score + w_balanced * nr.balanced_allocation_score(
-        requested, alloc2, fdtype=fdtype
-    )
-    score = score.astype(jnp.int32)
-    if w_taint:
-        score = score + w_taint * pl.normalize_score(
-            tables["taint_cnt"][cls], mask, reverse=True
+    with jax.named_scope("Score"):
+        requested = nr.scoring_requested(
+            x["nonzero_req"], st["nonzero_used"]
         )
-    if w_nodeaff:
-        score = score + w_nodeaff * pl.normalize_score(
-            tables["nodeaff_pref"][cls], mask, reverse=False
+        score = w_fit * fit_scorer(requested, alloc2, weights2)
+        score = score + w_balanced * nr.balanced_allocation_score(
+            requested, alloc2, fdtype=fdtype
         )
-    if w_image:
-        score = score + w_image * tables["image_score"][cls]
-    if use_extra_score:
-        # out-of-tree ScorePlugins + the gang heterogeneity objective
-        # (gang/throughput.py's workload-class x accelerator-class
-        # effective-throughput term), folded per class with weights
-        # pre-applied — the kernel stays objective-agnostic
-        score = score + tables["extra_score"][cls]
+        score = score.astype(jnp.int32)
+        if w_taint:
+            score = score + w_taint * pl.normalize_score(
+                tables["taint_cnt"][cls], mask, reverse=True
+            )
+        if w_nodeaff:
+            score = score + w_nodeaff * pl.normalize_score(
+                tables["nodeaff_pref"][cls], mask, reverse=False
+            )
+        if w_image:
+            score = score + w_image * tables["image_score"][cls]
+        if use_extra_score:
+            # out-of-tree ScorePlugins + the gang heterogeneity objective
+            # (gang/throughput.py's workload-class x accelerator-class
+            # effective-throughput term), folded per class with weights
+            # pre-applied — the kernel stays objective-agnostic
+            score = score + tables["extra_score"][cls]
     if use_spread and w_spread and spread_soft:
-        score = score + w_spread * sp.soft_scores(
-            spr, st["spr_cnt"], cls, mask, d_pad, fdtype=fdtype
-        )
+        with jax.named_scope("PodTopologySpread"):
+            score = score + w_spread * sp.soft_scores(
+                spr, st["spr_cnt"], cls, mask, d_pad, fdtype=fdtype
+            )
     if use_interpod and w_interpod and ipa_score:
-        score = score + w_interpod * ip.normalize(ipa_raw, mask)
-    return jnp.where(mask, score, -1)
+        with jax.named_scope("InterPodAffinity"):
+            score = score + w_interpod * ip.normalize(ipa_raw, mask)
+    with jax.named_scope("Score"):
+        return jnp.where(mask, score, -1)
 
 
 def _make_step(
@@ -343,77 +375,79 @@ def _make_step(
     def step(carry, x):
         st, k = carry
         score = _mask_and_score(tables, st, x, **pipe_kw)
-        mask = score >= 0
+        with jax.named_scope("select"):
+            mask = score >= 0
 
-        best = jnp.max(score)
-        feasible = best >= 0
-        ties = (score == best) & mask
-        csum = jnp.cumsum(ties)
-        if tie_break == TIE_RANDOM:
-            k, sub = jax.random.split(k)
-            n_ties = csum[-1]
-            pick_rank = jax.random.randint(sub, (), 0, jnp.maximum(n_ties, 1))
-        else:
-            pick_rank = 0
-        pick = jnp.argmax(csum > pick_rank).astype(jnp.int32)
-        if pipe_kw.get("use_nominated"):
-            # schedule_one.go#evaluateNominatedNode: a pod carrying a
-            # nomination takes that node if it is feasible, before any
-            # scoring of alternatives
-            s = x["nominated_slot"]
-            nom_ok = (s >= 0) & mask[jnp.maximum(s, 0)]
-            pick = jnp.where(nom_ok, jnp.maximum(s, 0).astype(jnp.int32), pick)
+            best = jnp.max(score)
+            feasible = best >= 0
+            ties = (score == best) & mask
+            csum = jnp.cumsum(ties)
+            if tie_break == TIE_RANDOM:
+                k, sub = jax.random.split(k)
+                n_ties = csum[-1]
+                pick_rank = jax.random.randint(sub, (), 0, jnp.maximum(n_ties, 1))
+            else:
+                pick_rank = 0
+            pick = jnp.argmax(csum > pick_rank).astype(jnp.int32)
+            if pipe_kw.get("use_nominated"):
+                # schedule_one.go#evaluateNominatedNode: a pod carrying a
+                # nomination takes that node if it is feasible, before any
+                # scoring of alternatives
+                s = x["nominated_slot"]
+                nom_ok = (s >= 0) & mask[jnp.maximum(s, 0)]
+                pick = jnp.where(nom_ok, jnp.maximum(s, 0).astype(jnp.int32), pick)
 
-        found = feasible & x["pod_valid"]
-        d = found.astype(alloc.dtype)
-        di = found.astype(jnp.int32)
-        new_st = dict(
-            used=st["used"].at[:, pick].add(x["req"] * d),
-            nonzero_used=st["nonzero_used"].at[:, pick].add(x["nonzero_req"] * d),
-            pod_count=st["pod_count"].at[pick].add(di),
-            port_used=st["port_used"].at[:, pick].add(x["pod_takes"] * di),
-            spr_cnt=(
-                st["spr_cnt"].at[:, pick].add(x["spr_placed"].astype(jnp.int32) * di)
-                if use_spread
-                else st["spr_cnt"]
-            ),
-            ipa_in=(
-                st["ipa_in"].at[:, pick].add(x["ipa_in_match"] * di)
-                if use_interpod
-                else st["ipa_in"]
-            ),
-            ipa_ex=(
-                st["ipa_ex"].at[:, pick].add(x["ipa_ex_owned"] * di)
-                if use_interpod
-                else st["ipa_ex"]
-            ),
-        )
-        if pipe_kw.get("use_nominated"):
-            # a placed nominated pod leaves the nominator map: accumulate
-            # its load (at its NOMINATED slot, where nom_used counted it)
-            # into the correction rows its priority contributed to
-            s_nom = x["nominated_slot"]
-            placed_nom = found & (s_nom >= 0)
-            ssn = jnp.maximum(s_nom, 0)
-            rows = st["nom_corr_cnt"].shape[0]
-            lev_mask = (
-                jnp.arange(rows, dtype=jnp.int32) >= x["nom_level"]
-            ) & placed_nom
-            new_st["nom_corr_used"] = st["nom_corr_used"].at[:, :, ssn].add(
-                lev_mask[:, None].astype(alloc.dtype) * x["req"][None, :]
+        with jax.named_scope("assume"):
+            found = feasible & x["pod_valid"]
+            d = found.astype(alloc.dtype)
+            di = found.astype(jnp.int32)
+            new_st = dict(
+                used=st["used"].at[:, pick].add(x["req"] * d),
+                nonzero_used=st["nonzero_used"].at[:, pick].add(x["nonzero_req"] * d),
+                pod_count=st["pod_count"].at[pick].add(di),
+                port_used=st["port_used"].at[:, pick].add(x["pod_takes"] * di),
+                spr_cnt=(
+                    st["spr_cnt"].at[:, pick].add(x["spr_placed"].astype(jnp.int32) * di)
+                    if use_spread
+                    else st["spr_cnt"]
+                ),
+                ipa_in=(
+                    st["ipa_in"].at[:, pick].add(x["ipa_in_match"] * di)
+                    if use_interpod
+                    else st["ipa_in"]
+                ),
+                ipa_ex=(
+                    st["ipa_ex"].at[:, pick].add(x["ipa_ex_owned"] * di)
+                    if use_interpod
+                    else st["ipa_ex"]
+                ),
             )
-            new_st["nom_corr_cnt"] = st["nom_corr_cnt"].at[:, ssn].add(
-                lev_mask.astype(jnp.int32)
-            )
-            if pipe_kw.get("use_nominated_ports"):
-                new_st["nom_corr_ports"] = st["nom_corr_ports"].at[
-                    :, :, ssn
-                ].add(
-                    lev_mask[:, None].astype(jnp.int32)
-                    * x["pod_takes"][None, :]
+            if pipe_kw.get("use_nominated"):
+                # a placed nominated pod leaves the nominator map: accumulate
+                # its load (at its NOMINATED slot, where nom_used counted it)
+                # into the correction rows its priority contributed to
+                s_nom = x["nominated_slot"]
+                placed_nom = found & (s_nom >= 0)
+                ssn = jnp.maximum(s_nom, 0)
+                rows = st["nom_corr_cnt"].shape[0]
+                lev_mask = (
+                    jnp.arange(rows, dtype=jnp.int32) >= x["nom_level"]
+                ) & placed_nom
+                new_st["nom_corr_used"] = st["nom_corr_used"].at[:, :, ssn].add(
+                    lev_mask[:, None].astype(alloc.dtype) * x["req"][None, :]
                 )
-        st = new_st
-        assignment = jnp.where(found, pick, -1).astype(jnp.int32)
+                new_st["nom_corr_cnt"] = st["nom_corr_cnt"].at[:, ssn].add(
+                    lev_mask.astype(jnp.int32)
+                )
+                if pipe_kw.get("use_nominated_ports"):
+                    new_st["nom_corr_ports"] = st["nom_corr_ports"].at[
+                        :, :, ssn
+                    ].add(
+                        lev_mask[:, None].astype(jnp.int32)
+                        * x["pod_takes"][None, :]
+                    )
+            st = new_st
+            assignment = jnp.where(found, pick, -1).astype(jnp.int32)
         return (st, k), assignment
 
     return step
@@ -558,27 +592,29 @@ def _solve_grouped(
             # result is clamped to [0, group] right after: a true quotient
             # >= 2^23 has relative f32 error ~2^-23, so the estimate stays
             # >> group and clamps identically; below 2^23 it is exact.
-            free = alloc - st["used"]
-            cap_res = jnp.where(
-                req_mask[:, None],
-                fastmath.floor_div_exact(
-                    jnp.maximum(free, 0), jnp.maximum(req, 1)[:, None]
-                ),
-                group,
-            )
-            cap = jnp.min(cap_res, axis=0)
-            cap = jnp.minimum(
-                cap, (tables["max_pods"] - st["pod_count"]).astype(cap.dtype)
-            )
-            conflict_now = pl.ports_conflict_mask(
-                conflict_row, st["port_used"]
-            )
-            has_ports = jnp.any(takes > 0)
-            self_conf = jnp.any((takes > 0) & conflict_row)
-            cap = jnp.where(conflict_now & has_ports, 0, cap)
-            cap = jnp.where(
-                self_conf & ~conflict_now, jnp.minimum(cap, 1), cap
-            )
+            with jax.named_scope("NodeResourcesFit"):
+                free = alloc - st["used"]
+                cap_res = jnp.where(
+                    req_mask[:, None],
+                    fastmath.floor_div_exact(
+                        jnp.maximum(free, 0), jnp.maximum(req, 1)[:, None]
+                    ),
+                    group,
+                )
+                cap = jnp.min(cap_res, axis=0)
+                cap = jnp.minimum(
+                    cap, (tables["max_pods"] - st["pod_count"]).astype(cap.dtype)
+                )
+            with jax.named_scope("NodePorts"):
+                conflict_now = pl.ports_conflict_mask(
+                    conflict_row, st["port_used"]
+                )
+                has_ports = jnp.any(takes > 0)
+                self_conf = jnp.any((takes > 0) & conflict_row)
+                cap = jnp.where(conflict_now & has_ports, 0, cap)
+                cap = jnp.where(
+                    self_conf & ~conflict_now, jnp.minimum(cap, 1), cap
+                )
             base_mask = tables["static_mask"][cls] & tables["node_valid"]
             cap = jnp.clip(jnp.where(base_mask, cap, 0), 0, group).astype(
                 jnp.int32
@@ -599,6 +635,7 @@ def _solve_grouped(
                 # shape as ImageLocality: fold into the frontier rows
                 static_row = static_row + tables["extra_score"][cls]
 
+            @jax.named_scope("Score")
             def frontier_rows(m, rows):
                 """fit+balanced (+static rows) score of placing the
                 (m+1)-th .. (m+rows)-th identical pod per node:
@@ -628,38 +665,43 @@ def _solve_grouped(
             taint_row = tables["taint_cnt"][cls]
             nodeaff_row = tables["nodeaff_pref"][cls]
 
-            # -- domain model (mode-static) --
-            if mode == "spread":
-                spr = tables["spr"]
-                jj = jnp.maximum(spr["hard"][cls, 0], 0)
-                dom_row = spr["dom"][jj]  # [N] (-1 = key missing)
-                hk = dom_row >= 0
-                dd = jnp.where(hk, dom_row, 0)
-                counted = spr["elig"][jj] & hk
-                base_cnt = st["spr_cnt"][jj]
-                skew_lim = spr["max_skew"][jj]
-                dom_present = (
-                    jops.segment_sum(
-                        counted.astype(jnp.int32), dd, num_segments=d_pad
+            # -- domain model (mode-static), under its plugin's name --
+            domain_scope = {
+                "spread": "PodTopologySpread", "anti": "InterPodAffinity",
+            }.get(mode, "grouped_fast")
+            with jax.named_scope(domain_scope):
+                if mode == "spread":
+                    spr = tables["spr"]
+                    jj = jnp.maximum(spr["hard"][cls, 0], 0)
+                    dom_row = spr["dom"][jj]  # [N] (-1 = key missing)
+                    hk = dom_row >= 0
+                    dd = jnp.where(hk, dom_row, 0)
+                    counted = spr["elig"][jj] & hk
+                    base_cnt = st["spr_cnt"][jj]
+                    skew_lim = spr["max_skew"][jj]
+                    dom_present = (
+                        jops.segment_sum(
+                            counted.astype(jnp.int32), dd, num_segments=d_pad
+                        )
+                        > 0
                     )
-                    > 0
-                )
-                dpad_local = d_pad
-            elif mode == "anti":
-                ipa = tables["ipa"]
-                jj = jnp.maximum(ipa["cls_req_anti"][cls, 0], 0)
-                dom_row = ipa["in_dom"][jj]
-                hk = dom_row >= 0
-                dd = jnp.where(hk, dom_row, 0)
-                # own symmetric ex term (host precondition: exactly one,
-                # same topology/domain row): its counts also block
-                ex_owned_row = row(cxs["ipa_ex_owned"])  # [Te]
-                ee = jnp.argmax(ex_owned_row > 0).astype(jnp.int32)
-                v_in = row(cxs["ipa_in_match"])[jj]
-                v_ex = ex_owned_row[ee]
-                base_cnt = st["ipa_in"][jj] + st["ipa_ex"][ee]
-                dpad_local = ipa_d_pad
+                    dpad_local = d_pad
+                elif mode == "anti":
+                    ipa = tables["ipa"]
+                    jj = jnp.maximum(ipa["cls_req_anti"][cls, 0], 0)
+                    dom_row = ipa["in_dom"][jj]
+                    hk = dom_row >= 0
+                    dd = jnp.where(hk, dom_row, 0)
+                    # own symmetric ex term (host precondition: exactly one,
+                    # same topology/domain row): its counts also block
+                    ex_owned_row = row(cxs["ipa_ex_owned"])  # [Te]
+                    ee = jnp.argmax(ex_owned_row > 0).astype(jnp.int32)
+                    v_in = row(cxs["ipa_in_match"])[jj]
+                    v_ex = ex_owned_row[ee]
+                    base_cnt = st["ipa_in"][jj] + st["ipa_ex"][ee]
+                    dpad_local = ipa_d_pad
 
+            @jax.named_scope(domain_scope)
             def domain_eval(m):
                 """(extra feasibility mask [N], quota_d [D], charged [N],
                 dc [D] current domain counts). charged=False nodes
@@ -692,6 +734,7 @@ def _solve_grouped(
                     ones_d,
                 )
 
+            @jax.named_scope("Score")
             def scores_at(m, extra_ok, f):
                 """Total score at frontier row ``f``
                 (= frontier_rows(m, ...)[0])."""
@@ -726,6 +769,9 @@ def _solve_grouped(
                     m, asg, placed, k = state
                     return placed < vcnt
 
+                # what is not one of the named parts above is the pick
+                # among ties (keys, quotas, water-fill): select
+                @jax.named_scope("select")
                 def body(state):
                     m, asg, placed, k = state
                     extra_ok, quota_d, charged, dc_now = domain_eval(m)
@@ -964,35 +1010,36 @@ def _solve_grouped(
                         feasible, jnp.where(multi, q, 1), 0
                     ).astype(jnp.int32)
 
-                    if mode is None:
-                        chosen = jnp.where(
-                            multi,
-                            jnp.where(iota_g < q, order[:group], -1),
-                            jnp.where(iota_g < 1, pick, -1),
-                        )  # [G] node ids for this iteration's pods, -1 pad
-                        chosen = jnp.where(feasible, chosen, -1)
-                        pos = jnp.where(chosen >= 0, placed + iota_g, group)
-                        asg = asg.at[pos].set(chosen, mode="drop")
-                        m = m.at[jnp.where(chosen >= 0, chosen, n)].add(
-                            jnp.int32(1), mode="drop"
-                        )
-                    else:
-                        take = accept & (pos_iter < q) & multi & feasible
-                        idx_multi = jnp.where(
-                            take, placed + pos_iter, group
-                        )
-                        asg = asg.at[idx_multi].set(iota_n, mode="drop")
-                        single = (~multi) & feasible
-                        asg = asg.at[
-                            jnp.where(single, placed, group)
-                        ].set(pick, mode="drop")
-                        delta_m = take.astype(jnp.int32) + (
-                            jnp.zeros(n, dtype=jnp.int32)
-                            .at[pick]
-                            .set(jnp.int32(1))
-                            * single.astype(jnp.int32)
-                        )
-                        m = m + delta_m
+                    with jax.named_scope("assume"):
+                        if mode is None:
+                            chosen = jnp.where(
+                                multi,
+                                jnp.where(iota_g < q, order[:group], -1),
+                                jnp.where(iota_g < 1, pick, -1),
+                            )  # [G] node ids for this iteration's pods, -1 pad
+                            chosen = jnp.where(feasible, chosen, -1)
+                            pos = jnp.where(chosen >= 0, placed + iota_g, group)
+                            asg = asg.at[pos].set(chosen, mode="drop")
+                            m = m.at[jnp.where(chosen >= 0, chosen, n)].add(
+                                jnp.int32(1), mode="drop"
+                            )
+                        else:
+                            take = accept & (pos_iter < q) & multi & feasible
+                            idx_multi = jnp.where(
+                                take, placed + pos_iter, group
+                            )
+                            asg = asg.at[idx_multi].set(iota_n, mode="drop")
+                            single = (~multi) & feasible
+                            asg = asg.at[
+                                jnp.where(single, placed, group)
+                            ].set(pick, mode="drop")
+                            delta_m = take.astype(jnp.int32) + (
+                                jnp.zeros(n, dtype=jnp.int32)
+                                .at[pick]
+                                .set(jnp.int32(1))
+                                * single.astype(jnp.int32)
+                            )
+                            m = m + delta_m
                     placed = jnp.where(feasible, placed + n_placed, vcnt)
                     return m, asg, placed, k
 
@@ -1002,6 +1049,7 @@ def _solve_grouped(
             else:
                 # Deterministic lowest-index tie-break: one placement per
                 # iteration, exactly the per-pod pipeline's argmax.
+                @jax.named_scope("select")
                 def body(t, acc):
                     m, asg = acc
                     extra_ok, _, _, _ = domain_eval(m)
@@ -1017,34 +1065,36 @@ def _solve_grouped(
 
                 m, asg = jax.lax.fori_loop(0, group, body, (m0, asg0))
 
-            d = m.astype(alloc.dtype)
-            st = dict(
-                st,
-                used=st["used"] + req[:, None] * d[None, :],
-                nonzero_used=st["nonzero_used"] + nz[:, None] * d[None, :],
-                pod_count=st["pod_count"] + m,
-                port_used=st["port_used"] + takes[:, None] * m[None, :],
-            )
-            # family occupancy updates (rows are zero for neutral chunks,
-            # making these no-ops for kind-1 chunks in active batches)
-            if use_spread:
-                st["spr_cnt"] = st["spr_cnt"] + row(
-                    cxs["spr_placed"]
-                ).astype(jnp.int32)[:, None] * m[None, :]
-            if use_interpod:
-                st["ipa_in"] = st["ipa_in"] + row(cxs["ipa_in_match"])[
-                    :, None
-                ] * m[None, :]
-                st["ipa_ex"] = st["ipa_ex"] + row(cxs["ipa_ex_owned"])[
-                    :, None
-                ] * m[None, :]
+            with jax.named_scope("assume"):
+                d = m.astype(alloc.dtype)
+                st = dict(
+                    st,
+                    used=st["used"] + req[:, None] * d[None, :],
+                    nonzero_used=st["nonzero_used"] + nz[:, None] * d[None, :],
+                    pod_count=st["pod_count"] + m,
+                    port_used=st["port_used"] + takes[:, None] * m[None, :],
+                )
+                # family occupancy updates (rows are zero for neutral chunks,
+                # making these no-ops for kind-1 chunks in active batches)
+                if use_spread:
+                    st["spr_cnt"] = st["spr_cnt"] + row(
+                        cxs["spr_placed"]
+                    ).astype(jnp.int32)[:, None] * m[None, :]
+                if use_interpod:
+                    st["ipa_in"] = st["ipa_in"] + row(cxs["ipa_in_match"])[
+                        :, None
+                    ] * m[None, :]
+                    st["ipa_ex"] = st["ipa_ex"] + row(cxs["ipa_ex_owned"])[
+                        :, None
+                    ] * m[None, :]
             return st, k, asg
 
         return fast_chunk
 
-    branches = [slow_chunk, make_fast(None)]
-    branches.append(make_fast("spread") if use_spread else branches[1])
-    branches.append(make_fast("anti") if use_interpod else branches[1])
+    slow, fast = jax.named_scope("grouped_slow"), jax.named_scope("grouped_fast")
+    branches = [slow(slow_chunk), fast(make_fast(None))]
+    branches.append(fast(make_fast("spread")) if use_spread else branches[1])
+    branches.append(fast(make_fast("anti")) if use_interpod else branches[1])
 
     def chunk_step(carry, x):
         st, k = carry
@@ -1117,8 +1167,9 @@ def _run_packed(
     tables = {**nt, **ct}
     state0 = dict(persist)
     if not chain_in:
-        for name, s, w in bspec:
-            state0[name] = bstate[s : s + w]
+        with jax.named_scope("unpack"):
+            for name, s, w in bspec:
+                state0[name] = bstate[s : s + w]
     if kw.get("use_nominated"):
         tables["nom_used"] = nom_used
         tables["nom_cnt"] = state0.pop("nom_cnt")
@@ -1132,9 +1183,10 @@ def _run_packed(
             state0["nom_corr_ports"] = jnp.zeros_like(nom_ports)
     srcs = {"i64": xi64, "i32": xi32, "bool": xbool}
     xs = {}
-    for name, src, s, w, squeeze in xspec:
-        a = srcs[src][:, s : s + w]
-        xs[name] = a[:, 0] if squeeze else a
+    with jax.named_scope("unpack"):
+        for name, src, s, w, squeeze in xspec:
+            a = srcs[src][:, s : s + w]
+            xs[name] = a[:, 0] if squeeze else a
     if grouped:
         assignments, state = _solve_grouped(
             tables, state0, xs, kinds, key, group=group, vcnt=vcnt,
@@ -1155,14 +1207,15 @@ def _run_packed(
         # result arrays are flattened into ONE int64 buffer so the host
         # blocks on a single device->host read. Session mode keeps the
         # dict (state stays device-resident; only assignments download).
-        return jnp.concatenate(
-            [
-                out_state["used"].reshape(-1),
-                out_state["nonzero_used"].reshape(-1),
-                out_state["pod_count"].astype(jnp.int64),
-                assignments.astype(jnp.int64),
-            ]
-        )
+        with jax.named_scope("pack"):
+            return jnp.concatenate(
+                [
+                    out_state["used"].reshape(-1),
+                    out_state["nonzero_used"].reshape(-1),
+                    out_state["pod_count"].astype(jnp.int64),
+                    assignments.astype(jnp.int64),
+                ]
+            )
     return assignments, out_state
 
 

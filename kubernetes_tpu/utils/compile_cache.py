@@ -57,3 +57,16 @@ def enable_persistent_cache() -> str:
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
     _enabled = True
     return cache_dir
+
+
+def key_on_op_names() -> None:
+    """Make the persistent cache's key cover op metadata, so that an
+    executable built from the same program under OTHER ``op_name``s
+    (before a ``jax.named_scope`` was added or renamed; the key leaves
+    debug information out by default) is not handed back with its old
+    names. ``serve --telemetry`` calls this and nothing else does: the
+    names matter only to who reads a device trace, and every other
+    process keeps today's key and its warm entries."""
+    import jax
+
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)

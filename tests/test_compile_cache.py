@@ -80,3 +80,60 @@ def test_no_private_knob_left():
         compile_cache.enable_persistent_cache
     ).parameters
     assert "KUBERNETES_TPU" not in inspect.getsource(compile_cache)
+
+
+_NAMES = """
+import re, sys, jax, jax.numpy as jnp
+from kubernetes_tpu.utils import compile_cache
+compile_cache.enable_persistent_cache()
+if sys.argv[2] == "keyed":
+    compile_cache.key_on_op_names()
+
+@jax.jit
+def f(x):
+    with jax.named_scope(sys.argv[1]):
+        return jnp.cumsum(x * 3 + 1)
+
+text = f.lower(jnp.arange(64.0)).compile().as_text()
+print(sorted({s for s in ("Score", "select") if s + "/" in text}))
+"""
+
+
+def _names(cache_dir: str, scope: str, mode: str) -> str:
+    env = {**os.environ, "JAX_PLATFORMS": "cpu", "JAX_COMPILATION_CACHE_DIR": cache_dir}
+    proc = subprocess.run(
+        [sys.executable, "-c", _NAMES, scope, mode], cwd=_REPO_ROOT, env=env,
+        capture_output=True, text=True, timeout=300,
+    )
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    return proc.stdout.strip().splitlines()[-1]
+
+
+def test_a_warm_cache_serves_stale_scope_names_unless_keyed_on_them(tmp_path):
+    """The hazard behind key_on_op_names: the cache key leaves op
+    metadata out, so the same program under a renamed jax.named_scope
+    comes back with the names it was first built with. serve --telemetry
+    (whose device trace is read by name) keys on them; nothing else does."""
+    cache = str(tmp_path / "cache")
+    assert _names(cache, "Score", "plain") == "['Score']"  # fills the cache
+    assert _names(cache, "select", "plain") == "['Score']"  # stale
+    assert _names(cache, "select", "keyed") == "['select']"
+    assert _names(cache, "Score", "keyed") == "['Score']"
+
+
+def test_only_serve_telemetry_keys_on_names():
+    import inspect
+
+    from kubernetes_tpu import cli
+
+    src = inspect.getsource(cli.cmd_serve)
+    gate = src.index("if telemetry_on:")
+    assert src.index("key_on_op_names()") > gate
+    assert src.count("key_on_op_names()") == 1
+    hits = subprocess.run(
+        ["grep", "-rl", "key_on_op_names", os.path.join(_REPO_ROOT, "kubernetes_tpu")],
+        capture_output=True, text=True,
+    ).stdout.split()
+    assert sorted(os.path.basename(h) for h in hits if h.endswith(".py")) == [
+        "cli.py", "compile_cache.py",
+    ]

@@ -19,7 +19,6 @@ from kubernetes_tpu.scheduler import Scheduler, SchedulerConfig
 from kubernetes_tpu.solver.exact import ExactSolverConfig
 from kubernetes_tpu.state.cluster import ClusterState
 from kubernetes_tpu.utils.clock import FakeClock
-from kubernetes_tpu.utils import tracing
 
 
 def _cfg(**kw):
@@ -375,21 +374,31 @@ def test_recovery_metric_and_span_observed():
     assert s2.journal.lines  # recovered record written under the span
 
 
-def test_tracing_wraps_schedule_batch(tmp_path):
-    """--trace-dir plumbing: enabling tracing must not change behavior and
-    must produce a trace directory when solves run."""
-    tracing.enable(str(tmp_path))
+def test_profiler_session_shows_the_synchronous_cycle_as_its_stages(tmp_path):
+    """What --trace-dir's step annotation was for: a jax-profiler
+    session around schedule_batch. With telemetry on, the cycle shows as
+    the stages inside it, and behaves as it does with no session."""
+    import jax
+
+    from benchmarks.lib import span_attrib, trace_reduce
+    from kubernetes_tpu.obs import ObsConfig
+
+    cs = ClusterState()
+    cs.create_node(
+        MakeNode().name("n").capacity({"cpu": "4", "memory": "8Gi", "pods": "10"}).obj()
+    )
+    cfg = _cfg()
+    cfg.obs = ObsConfig(profile=True)
+    sched = Scheduler(cs, cfg)
+    cs.create_pod(MakePod().name("p").req({"cpu": "1"}).obj())
+    jax.profiler.start_trace(str(tmp_path))
     try:
-        clock = FakeClock()
-        cs = ClusterState()
-        cs.create_node(
-            MakeNode().name("n").capacity({"cpu": "4", "memory": "8Gi", "pods": "10"}).obj()
-        )
-        sched = Scheduler(cs, _cfg(), clock=clock)
-        cs.create_pod(MakePod().name("p").req({"cpu": "1"}).obj())
         r = sched.schedule_batch()
-        assert dict(r.scheduled).get("default/p") == "n"
     finally:
-        tracing.stop()
-        tracing._trace_dir = None
-    assert any(tmp_path.iterdir())  # the profiler wrote a session dir
+        jax.profiler.stop_trace()
+    assert dict(r.scheduled).get("default/p") == "n"
+    capture = span_attrib.load(trace_reduce.find_xplane(str(tmp_path)))
+    assert {e[0] for th in capture["threads"] for e in th} >= {
+        "stage:tensorize", "stage:dispatch", "stage:deferred_read",
+        "stage:apply", "stage:bind",
+    }
