@@ -1581,7 +1581,7 @@ class FleetRuntime:
             # off the hub, which is conservative for peers
             self._needs_resync = True
 
-    # called from _commit_binding's locked confirmation phase: ktpu: holds(cluster.lock)
+    # called from _confirm_binding's locked region: ktpu: holds(cluster.lock)
     def commit(self, pod_key: str) -> None:
         try:
             self.exchange.commit(self.replica, pod_key)

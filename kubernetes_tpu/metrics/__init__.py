@@ -449,6 +449,17 @@ commit_fenced_total = Counter(
     "time.",
     registry=REGISTRY,
 )
+bind_commits_total = Counter(
+    "scheduler_bind_commits_total",
+    "Pods taken through the commit pass, by path: held = the pod's "
+    "binding cycle cannot leave the process (no PreBind/PostBind "
+    "plugin, volume, resource claim or binder extender), so it "
+    "committed inside a run of such pods under one hold of the cluster "
+    "lock; wire = it went through the three-phase cycle, unlocked "
+    "across its wire call. Counts attempts, failed binds included.",
+    ["path"],
+    registry=REGISTRY,
+)
 watch_delivery_error_total = Counter(
     "scheduler_watch_delivery_error_total",
     "Exceptions raised by ClusterState watch subscribers during event "

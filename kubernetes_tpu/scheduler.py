@@ -19,6 +19,9 @@ from the state service on restart.
 
 from __future__ import annotations
 
+import contextlib
+import functools
+import itertools
 from dataclasses import dataclass, field
 
 import numpy as np
@@ -533,6 +536,18 @@ class Scheduler:
             if self.config.obs is not None
             else 1
         )
+        # the commit pass (_commit_all): True inside a held run, where
+        # the only watch events are the pass's own binds and the
+        # pending gauge is refreshed once, at the run's end
+        self._in_held_run = False  # ktpu: guarded-by(cluster.lock)
+        # labels() is a lock and a dict walk: the histogram children
+        # the pass observes per pod are looked up once each, at first
+        # use (_pass_child)
+        self._pass_children: dict = {}
+        self._commit_path_children = {
+            held: metrics.bind_commits_total.labels(path)
+            for held, path in ((True, "held"), (False, "wire"))
+        }
         # fleet runtime (kubernetes_tpu/fleet): partition view, shard
         # watch filter, occupancy exchange client. Built before the
         # initial informer sync so the sync itself is shard-scoped.
@@ -1156,8 +1171,10 @@ class Scheduler:
             self._ingest_event(ev)
         # any non-Event kind can have moved pods between queues: keep
         # the pending_pods gauge current (it used to refresh only in
-        # the solve-recording path and went stale between solves)
-        self._refresh_pending_gauge()
+        # the solve-recording path and went stale between solves).
+        # A held commit run refreshes it itself, once
+        if not self._in_held_run:
+            self._refresh_pending_gauge()
 
     # ktpu: holds(cluster.lock)
     def _ingest_event(self, ev: Event) -> None:
@@ -1608,7 +1625,17 @@ class Scheduler:
             hook(hook_pending)
         first_err = None
         # the bind stage: every per-pod commit of this batch, journal
-        # record included (the gang commits below are not in it)
+        # record included (the gang commits below are not in it).
+        # Each maximal run of consecutive wire-free entries commits
+        # under ONE hold of cluster.lock: taken and dropped per pod (as
+        # _commit_binding's phases do on their own), every release
+        # hands the lock to the ingest thread and the loop waits out a
+        # create_pod before its next acquire — a convoy that cost more
+        # than the commits. A hold lasts at most this flight's pods.
+        # An entry with a wire call in its cycle commits alone,
+        # unlocked across that call, exactly as before.
+        binders = [cl for cl in self.extender_clients if cl.is_binder]
+        wire_free = functools.partial(self._wire_free, binders)
         with (
             self.telemetry.stage(
                 "bind", step=self._trace_step, pods=len(pending)
@@ -1616,50 +1643,14 @@ class Scheduler:
             if self.telemetry is not None and pending
             else _NOOP_SPAN
         ):
-            for entry in pending:
-                tb = self.clock.perf()
-                # bind spans are 1-in-N sampled (ObsConfig.bind_span_
-                # sample_n; deterministic counter, first bind always
-                # sampled): the journal below stays COMPLETE per pod — the
-                # span only adds the commit's wall duration, which
-                # sampling preserves statistically, and per-pod spans at
-                # sustained-stream volume are what the obs-overhead
-                # budget cannot afford
-                self._bind_commits += 1
-                bn = self._bind_sample_n
-                span_ctx = (
-                    self.obs.span(
-                        "bind", trace_id=entry[6], pod=entry[2].key,
-                        node=entry[3],
-                        **({"sample_n": bn} if bn > 1 else {}),
-                    )
-                    if bn <= 1 or self._bind_commits % bn == 1
-                    else _NOOP_SPAN
-                )
-                with span_ctx as bsp:
-                    try:
-                        ok = self._commit_binding(entry, res)
-                    except Exception as e:  # a buggy PreBind/PostBind plugin
-                        # must not strand the REST of the approved batch:
-                        # roll this pod back, keep committing, re-raise last
-                        ok = False
-                        first_err = first_err or e
-                        state, info, pod, node_name, cycle, _ts, step = entry
-                        with self.cluster.lock:
-                            self._unreserve_all(state, pod, node_name)
-                            res.bind_failures.append((pod.key, repr(e)))
-                            self._requeue(info, cycle)
-                            if self.journal is not None:
-                                self.journal.record(
-                                    step, cycle, pod, "bind_failure",
-                                    node=node_name, reason=repr(e),
-                                    attempts=info.attempts,
-                                )
-                    bsp.set(ok=ok)
-                bind_dur = self.clock.perf() - tb
-                metrics.framework_extension_point_duration_seconds.labels(
-                    "Bind", "Success" if ok else "Error", "all"
-                ).observe(bind_dur)
+            for held, run in itertools.groupby(pending, key=wire_free):
+                n_run = 0
+                with self._held_run() if held else _NOOP_SPAN:
+                    for entry in run:
+                        n_run += 1
+                        err = self._commit_entry(entry, res, binders, held)
+                        first_err = first_err or err
+                self._commit_path_children[held].inc(n_run)
         for gid, rd in gang_ready:
             # one atomic all-or-nothing commit per complete gang round
             try:
@@ -1709,6 +1700,101 @@ class Scheduler:
                 self._publish_degraded()
         if first_err is not None:
             raise first_err
+
+    def _wire_free(self, binders: list, entry: tuple) -> bool:
+        """True when nothing in the entry's binding cycle can leave the
+        process — exactly the calls _commit_binding's wire phase makes,
+        plus PostBind: no PreBind/PostBind plugin, no volume to bind,
+        no DRA claim, no binder extender that wants the pod. Read off
+        the entry itself; such an entry may commit under the lock."""
+        pod = entry[2]
+        return not (
+            self.registry.pre_bind
+            or self.registry.post_bind
+            or pod.pvc_names
+            or (self._dra and pod.resource_claim_names)
+            or any(cl.is_interested(pod) for cl in binders)
+        )
+
+    @contextlib.contextmanager
+    def _held_run(self):
+        """One hold of cluster.lock around a run of wire-free commits.
+        grant_fence and revoke_fence take the same lock, so the fence
+        token every bind of the run is checked against cannot change
+        inside the hold: a revoke waits for the run's end and refuses
+        the NEXT run whole. The per-pod code re-enters the RLock (an
+        owner check, no futex)."""
+        with self.cluster.lock:
+            self._in_held_run = True
+            try:
+                yield
+            finally:
+                self._in_held_run = False
+                # the run's own bind events skipped this (_on_event);
+                # requeues of failed binds moved pods between queues
+                self._refresh_pending_gauge()
+
+    def _commit_entry(
+        self, entry: tuple, res: BatchResult, binders: list, held: bool
+    ) -> Exception | None:
+        """One pod of the commit pass: its sampled bind span, the
+        binding cycle, the rollback of a plugin that raised, the Bind
+        extension-point observation. Returns what the plugin raised
+        (the pass goes on and re-raises the first at its end)."""
+        err = None
+        tb = self.clock.perf()
+        # bind spans are 1-in-N sampled (ObsConfig.bind_span_
+        # sample_n; deterministic counter, first bind always
+        # sampled): the journal stays COMPLETE per pod — the span only
+        # adds the commit's wall duration, which sampling preserves
+        # statistically, and per-pod spans at sustained-stream volume
+        # are what the obs-overhead budget cannot afford
+        self._bind_commits += 1
+        bn = self._bind_sample_n
+        span_ctx = (
+            self.obs.span(
+                "bind", trace_id=entry[6], pod=entry[2].key,
+                node=entry[3],
+                **({"sample_n": bn} if bn > 1 else {}),
+            )
+            if bn <= 1 or self._bind_commits % bn == 1
+            else _NOOP_SPAN
+        )
+        with span_ctx as bsp:
+            try:
+                ok = self._commit_binding(entry, res, binders, held)
+            except Exception as e:  # a buggy PreBind/PostBind plugin
+                # must not strand the REST of the approved batch:
+                # roll this pod back, keep committing, re-raise last
+                ok = False
+                err = e
+                state, info, pod, node_name, cycle, _ts, step = entry
+                with self.cluster.lock:
+                    self._unreserve_all(state, pod, node_name)
+                    res.bind_failures.append((pod.key, repr(e)))
+                    self._requeue(info, cycle)
+                    if self.journal is not None:
+                        self.journal.record(
+                            step, cycle, pod, "bind_failure",
+                            node=node_name, reason=repr(e),
+                            attempts=info.attempts,
+                        )
+            bsp.set(ok=ok)
+        self._pass_child(
+            metrics.framework_extension_point_duration_seconds,
+            "Bind", "Success" if ok else "Error", "all",
+        ).observe(self.clock.perf() - tb)
+        return err
+
+    def _pass_child(self, metric, *labels):
+        """``metric.labels(*labels)``, looked up once: the series still
+        appears in /metrics at its first observation, not before."""
+        child = self._pass_children.get((metric, labels))
+        if child is None:
+            child = self._pass_children[metric, labels] = metric.labels(
+                *labels
+            )
+        return child
 
     def _group_by_profile(
         self, infos: list
@@ -3762,41 +3848,24 @@ class Scheduler:
                 return (p.name(), st)
         return waits or None
 
-    def _commit_binding(self, entry: tuple, res: BatchResult) -> None:
+    def _commit_binding(
+        self, entry: tuple, res: BatchResult, binders: list, held: bool
+    ) -> bool:
         """The binding cycle for one approved pod — PreBind (out-of-tree
         plugins, then volumebinding's BindPodVolumes) -> Bind (extender
-        delegate or the binding subresource) -> PostBind. Runs WITHOUT
-        the cluster lock held (the bind may cross a wire); cache/queue
-        bookkeeping re-acquires it briefly. Any failure unreserves and
-        requeues with backoff (the bindingCycle failure path).
+        delegate or the binding subresource) -> PostBind — in three
+        phases: the wire phase (_bind_wire), the bind at the state
+        service, the confirmation (_confirm_binding). An entry with a
+        wire call in its cycle runs WITHOUT the cluster lock held (the
+        call may cross a wire) and its bookkeeping re-acquires the lock
+        briefly. A wire-free entry (``held``, decided by _wire_free)
+        has no wire phase: _commit_all holds the lock around its whole
+        run and every phase here re-enters it. Any failure unreserves
+        and requeues with backoff (the bindingCycle failure path).
         Returns True when the pod bound."""
-        state, info, pod, node_name, cycle, t_start, step = entry
+        state, _info, pod, node_name, _cycle, _t_start, _step = entry
         try:
-            for p in self.registry.pre_bind:
-                st = p.pre_bind(state, pod, node_name)
-                if not st.is_success:
-                    raise _Rejected(
-                        f"PreBind plugin {p.name()} rejected: "
-                        + "; ".join(st.reasons)
-                    )
-            if pod.pvc_names:
-                self.volume_binder.bind_pod_volumes(pod)
-            if self._dra and pod.resource_claim_names:
-                self.claim_allocator.bind_pod_claims(pod)
-            binder = next(
-                (
-                    cl
-                    for cl in self.extender_clients
-                    if cl.is_binder and cl.is_interested(pod)
-                ),
-                None,
-            )
-            if binder is not None:
-                # extender.go#Bind: the first interested binder extender
-                # owns the binding subresource call (scope note: the
-                # extender's own apiserver client carries its fence)
-                binder.bind(pod, node_name)
-            else:
+            if held or not self._bind_wire(state, pod, node_name, binders):
                 self.cluster.bind(
                     pod.namespace, pod.name, node_name,
                     fence=(
@@ -3806,47 +3875,93 @@ class Scheduler:
                     ),
                 )
         except (ApiError, VolumeBindingError, _Rejected, ExtenderError) as e:
-            reason = e.reason if isinstance(e, ApiError) else str(e)
-            fenced = isinstance(e, ApiError) and e.fenced
-            with self.cluster.lock:
-                if fenced:
-                    # this incarnation's fence token was revoked (lease
-                    # lost / partition / superseded): the state service
-                    # refused the commit — the zombie path the fence
-                    # exists to close. The pod requeues like any bind
-                    # conflict; the operator signal is the counter+log
-                    # (production wires reacquire_fence to lease
-                    # re-acquisition before commits can resume).
-                    metrics.commit_fenced_total.inc()
-                    self._fenced_commits += 1
-                    self._log.warning(
-                        "bind of %s fenced: this incarnation's commit "
-                        "fence (role %r) was revoked — operating as a "
-                        "zombie until the lease is re-acquired",
-                        pod.key, self._fence_role,
-                        extra={"step": step},
-                    )
-                self._unreserve_all(state, pod, node_name)
-                res.bind_failures.append((pod.key, reason))
-                if self.journal is not None:
-                    self.journal.record(
-                        step, cycle, pod, "bind_failure",
-                        node=node_name, reason=reason,
-                        attempts=info.attempts,
-                    )
-                try:
-                    self.cluster.get_pod(pod.namespace, pod.name)
-                except ApiError:
-                    # deleted while the bind was in flight (the unlocked
-                    # window): don't requeue a pod that no longer exists
-                    return False
-                self._requeue(info, cycle)
-                self._event(
-                    pod, "FailedScheduling",
-                    f"binding rejected: {reason}", type_="Warning",
-                    action="Binding",
-                )
+            self._bind_rejected(entry, res, e)
             return False
+        self._confirm_binding(entry, res)
+        return True
+
+    def _bind_wire(
+        self, state, pod: Pod, node_name: str, binders: list
+    ) -> bool:
+        """The wire phase of a binding cycle, never under the cluster
+        lock: PreBind plugins, volume and claim binding, and the first
+        interested binder extender's bind. True when that extender
+        bound the pod (the binding subresource call is then its own)."""
+        for p in self.registry.pre_bind:
+            st = p.pre_bind(state, pod, node_name)
+            if not st.is_success:
+                raise _Rejected(
+                    f"PreBind plugin {p.name()} rejected: "
+                    + "; ".join(st.reasons)
+                )
+        if pod.pvc_names:
+            self.volume_binder.bind_pod_volumes(pod)
+        if self._dra and pod.resource_claim_names:
+            self.claim_allocator.bind_pod_claims(pod)
+        binder = next(
+            (cl for cl in binders if cl.is_interested(pod)), None
+        )
+        if binder is None:
+            return False
+        # extender.go#Bind: the first interested binder extender
+        # owns the binding subresource call (scope note: the
+        # extender's own apiserver client carries its fence)
+        binder.bind(pod, node_name)
+        return True
+
+    def _bind_rejected(
+        self, entry: tuple, res: BatchResult, e: Exception
+    ) -> None:
+        """The bindingCycle failure path: unreserve, journal, requeue
+        with backoff unless the pod is gone."""
+        state, info, pod, node_name, cycle, _t_start, step = entry
+        reason = e.reason if isinstance(e, ApiError) else str(e)
+        fenced = isinstance(e, ApiError) and e.fenced
+        with self.cluster.lock:
+            if fenced:
+                # this incarnation's fence token was revoked (lease
+                # lost / partition / superseded): the state service
+                # refused the commit — the zombie path the fence
+                # exists to close. The pod requeues like any bind
+                # conflict; the operator signal is the counter+log
+                # (production wires reacquire_fence to lease
+                # re-acquisition before commits can resume).
+                metrics.commit_fenced_total.inc()
+                self._fenced_commits += 1
+                self._log.warning(
+                    "bind of %s fenced: this incarnation's commit "
+                    "fence (role %r) was revoked — operating as a "
+                    "zombie until the lease is re-acquired",
+                    pod.key, self._fence_role,
+                    extra={"step": step},
+                )
+            self._unreserve_all(state, pod, node_name)
+            res.bind_failures.append((pod.key, reason))
+            if self.journal is not None:
+                self.journal.record(
+                    step, cycle, pod, "bind_failure",
+                    node=node_name, reason=reason,
+                    attempts=info.attempts,
+                )
+            try:
+                self.cluster.get_pod(pod.namespace, pod.name)
+            except ApiError:
+                # deleted while the bind was in flight (the unlocked
+                # window): don't requeue a pod that no longer exists
+                return
+            self._requeue(info, cycle)
+            self._event(
+                pod, "FailedScheduling",
+                f"binding rejected: {reason}", type_="Warning",
+                action="Binding",
+            )
+
+    def _confirm_binding(self, entry: tuple, res: BatchResult) -> None:
+        """The confirmation phase of a bind that landed: cache and
+        exchange bookkeeping, the Scheduled event and the journal's
+        ``bound`` record under the lock, then the pod-level SLIs and
+        PostBind."""
+        state, info, pod, node_name, cycle, t_start, step = entry
         with self.cluster.lock:
             self.cache.finish_binding(pod.key)
             self.volume_binder.finish(pod.key)
@@ -3873,14 +3988,14 @@ class Scheduler:
         e2e = max(self.clock.now() - info.initial_attempt_timestamp, 0.0)
         res.e2e_latencies.append(e2e)
         metrics.pod_scheduling_attempts.observe(info.attempts)
-        metrics.pod_scheduling_sli_duration_seconds.labels(
-            str(min(info.attempts, 16))
+        self._pass_child(
+            metrics.pod_scheduling_sli_duration_seconds,
+            str(min(info.attempts, 16)),
         ).observe(e2e)
         for p in self.registry.post_bind:
             p.post_bind(state, pod, node_name)
         with self.cluster.lock:
             self._in_flight.pop(pod.key, None)
-        return True
 
     # called only from _schedule_cycle's locked region: ktpu: holds(cluster.lock)
     def _process_waiting(self, res: BatchResult, pending: list) -> None:
