@@ -291,6 +291,41 @@ h2d_bytes_total = Counter(
     "cache misses, and full session (re)uploads.",
     registry=REGISTRY,
 )
+solves_total = Counter(
+    "scheduler_tpu_solves_total",
+    "ExactSolver.solve calls (one per tensorized batch, before any "
+    "split into chained sub-solves), by device program: grouped = the "
+    "chunked program (_solve_grouped), scan = the per-pod scan "
+    "(_solve_scan). Same increments as ExactSolver.dispatch_counts.",
+    ["path"],
+    registry=REGISTRY,
+)
+solve_chunks_total = Counter(
+    "scheduler_tpu_solve_chunks_total",
+    "Chunks of group_size pods handed to the grouped program, by the "
+    "branch _chunk_kinds chose: slow = the full per-pod step replayed "
+    "pod by pod (scope grouped_slow), plain | spread | anti = the "
+    "multi-placement fast branches (scope grouped_fast). Chunks that "
+    "hold no pod (padding) are not counted. Same increments as "
+    "ExactSolver.dispatch_counts.",
+    ["kind"],
+    registry=REGISTRY,
+)
+spread_instances_total = Counter(
+    "scheduler_tpu_spread_instances_total",
+    "PodTopologySpread constraint instances (one per class and "
+    "constraint: SpreadTensors.num_instances) summed over "
+    "ExactSolver.solve calls; over scheduler_tpu_solves_total it is "
+    "the instances one batch carries.",
+    registry=REGISTRY,
+)
+class_table_uploads_total = Counter(
+    "scheduler_tpu_class_table_uploads_total",
+    "Session solves whose per-class tables (static masks, spread and "
+    "inter-pod instance tables) were not in the content-addressed "
+    "device cache and were placed anew.",
+    registry=REGISTRY,
+)
 d2h_bytes_total = Counter(
     "scheduler_tpu_device_to_host_bytes_total",
     "Device->host bytes downloaded by ExactSolver.solve: the per-batch "

@@ -1624,6 +1624,19 @@ class _DeviceSession:
         return ct, sum(np.asarray(a).nbytes for a in arrays)
 
 
+# dispatch_counts key -> the /metrics child that counts the same thing
+_TALLY_SERIES = {
+    "grouped": metrics.solves_total.labels("grouped"),
+    "scan": metrics.solves_total.labels("scan"),
+    "kind0": metrics.solve_chunks_total.labels("slow"),
+    "kind1": metrics.solve_chunks_total.labels("plain"),
+    "kind2": metrics.solve_chunks_total.labels("spread"),
+    "kind3": metrics.solve_chunks_total.labels("anti"),
+    "spread_instances": metrics.spread_instances_total,
+    "class_table_uploads": metrics.class_table_uploads_total,
+}
+
+
 def _capture_config_fingerprint(cfg: "ExactSolverConfig") -> dict:
     """JSON-safe config snapshot for the telemetry capture hook (lazy
     import: the solver must not pull the obs layer in at module load)."""
@@ -1652,12 +1665,14 @@ class ExactSolver:
         # callable, never touches device state.
         self.capture_hook = None
         # Cumulative executable-dispatch histogram: "scan" counts whole
-        # per-pod-scan solves, "kindK" counts grouped chunks by the
-        # _chunk_kinds dispatch (0 slow replay / 1 plain / 2 spread
-        # quota / 3 anti quota). Benchmarks report THIS instead of
-        # asserting which path a workload takes (a round-3 bench label
-        # claimed grouping was disabled on workloads where the quota
-        # chunks in fact engaged).
+        # per-pod-scan solves and "grouped" grouped ones, "kindK" counts
+        # grouped chunks that hold a pod by the _chunk_kinds dispatch
+        # (0 slow replay / 1 plain / 2 spread quota / 3 anti quota),
+        # "padding" those that hold none. _tally is the one increment;
+        # /metrics exports the same counts (_TALLY_SERIES). Benchmarks
+        # report THIS instead of asserting which path a workload takes
+        # (a round-3 bench label claimed grouping was disabled on
+        # workloads where the quota chunks in fact engaged).
         from collections import Counter
 
         self.dispatch_counts: Counter = Counter()
@@ -1671,6 +1686,15 @@ class ExactSolver:
         from ..utils.compile_cache import enable_persistent_cache
 
         enable_persistent_cache()
+
+    def _tally(self, key: str, n: int = 1) -> None:
+        """The one increment behind ``dispatch_counts`` and the /metrics
+        series that export it (``_TALLY_SERIES``)."""
+        if n:
+            self.dispatch_counts[key] += n
+            series = _TALLY_SERIES.get(key)
+            if series is not None:
+                series.inc(n)
 
     def reset_session(self) -> None:
         """Drop the device-resident session so the next solve re-uploads
@@ -1949,6 +1973,8 @@ class ExactSolver:
                 digest=chain_key[0] if chain_key is not None else None,
             )
             h2d_bytes += ct_bytes
+            if ct_bytes:  # a cache miss: the tables were placed anew
+                self._tally("class_table_uploads")
         else:
             _, put = placers(mesh, nodes.padded)
             nt = {
@@ -2113,17 +2139,23 @@ class ExactSolver:
                 pods, static, ports, spread, interpod, group,
                 use_spread, use_interpod,
             )
-            for v, cnt in zip(*np.unique(kinds_host, return_counts=True)):
-                self.dispatch_counts[f"kind{int(v)}"] += int(cnt)
+            c = pods.padded // group
+            pvc = pod_valid[:, 0].reshape(c, group)
+            vc = pvc.sum(axis=1).astype(np.int32)
+            self._tally("grouped")
+            # chunks that hold no pod are kind 1 by construction
+            # (_chunk_kinds) and place nothing: not a plain chunk
+            self._tally("padding", int((vc == 0).sum()))
+            for v, cnt in zip(
+                *np.unique(kinds_host[vc > 0], return_counts=True)
+            ):
+                self._tally(f"kind{int(v)}", int(cnt))
             kinds = jnp.asarray(kinds_host)
             # COMPACT eligibility (wire-cost fast path, _solve_grouped
             # docstring): every chunk's validity is a prefix and its valid
             # per-pod rows are identical — then one representative row per
             # chunk + a valid count replaces the [P, *] uploads, and even
             # kind-0 chunks replay bit-identically from the broadcast.
-            c = pods.padded // group
-            pvc = pod_valid[:, 0].reshape(c, group)
-            vc = pvc.sum(axis=1).astype(np.int32)
             if cfg.compact_wire and bool(
                 (pvc == (np.arange(group)[None, :] < vc[:, None])).all()
             ):
@@ -2151,12 +2183,13 @@ class ExactSolver:
                     xbool = np.ascontiguousarray(
                         xbool.reshape(c, group, -1)[:, 0]
                     )
-                    self.dispatch_counts["compact_batches"] += 1
+                    self._tally("compact_batches")
         else:
             group = 1
             kinds = jnp.zeros(1, dtype=jnp.int32)
             kinds_host = None
-            self.dispatch_counts["scan"] += 1
+            self._tally("scan")
+        self._tally("spread_instances", int(spread.num_instances))
 
         # streaming chain eligibility: session + deferred + un-nominated
         stream = (
@@ -2375,7 +2408,7 @@ class ExactSolver:
             else None
         )
         if chain_start is not None:
-            self.dispatch_counts["stream_chained"] += 1
+            self._tally("stream_chained")
             # the carry is consumed (donated) by the first dispatch —
             # it can no longer be offered to anyone else
             self._session.stream_carry = None
@@ -2449,7 +2482,7 @@ class ExactSolver:
             self._session.stream_carry = None
             self._session.stream_key = None
             self._session.stream_versions = None
-        self.dispatch_counts["chained_subbatches"] += len(handles)
+        self._tally("chained_subbatches", len(handles))
         return handles
 
     @staticmethod
