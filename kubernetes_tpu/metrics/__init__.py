@@ -319,6 +319,26 @@ spread_instances_total = Counter(
     "the instances one batch carries.",
     registry=REGISTRY,
 )
+spread_count_rows_total = Counter(
+    "scheduler_tpu_spread_count_rows_total",
+    "Rows of SpreadTensors.cnt0 (matching placed pods per node, one row "
+    "per constraint instance with a selector) that the scheduler cache's "
+    "per-selector node counts (SchedulerCache.spread_counts) handed to a "
+    "batch, by where the counts came from: kept = the selector was "
+    "already tracked, walk = it was counted then, by a pass over the "
+    "placed pods (one pass for all the new selectors of a batch). An "
+    "index built on the spot for a caller with no cache (the extender, "
+    "solver/evaluate.py) is not counted.",
+    ["source"],
+    registry=REGISTRY,
+)
+spread_tracked_selectors = Gauge(
+    "scheduler_tpu_spread_tracked_selectors",
+    "(namespace, selector) pairs whose per-node match counts the "
+    "scheduler cache keeps up to date (SchedulerCache.spread_counts), "
+    "read after each batch that carries a spread constraint.",
+    registry=REGISTRY,
+)
 class_table_uploads_total = Counter(
     "scheduler_tpu_class_table_uploads_total",
     "Session solves whose per-class tables (static masks, spread and "

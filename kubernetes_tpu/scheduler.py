@@ -2797,7 +2797,7 @@ class Scheduler:
                 class_key_extra=class_key_extra,
             )
             placed_by_slot: dict[int, list[Pod]] = {}
-            if need_ports or need_spread or need_interpod:
+            if need_ports or need_interpod:
                 for slot, name in enumerate(self.snapshot.names):
                     info = self.cache.nodes.get(name) if name else None
                     if info is not None and info.node is not None and info.pods:
@@ -2830,10 +2830,15 @@ class Scheduler:
                 spread = _timed(
                     "PodTopologySpread", build_spread_tensors,
                     pods, static.reps, pbatch, slot_nodes,
-                    placed_by_slot, batch.padded, static.c_pad,
+                    {}, batch.padded, static.c_pad,
                     services=services,
                     defaulting=solver.config.spread_defaulting,
                     nominated=nom_peers,
+                    counts=self.cache.spread_counts,
+                    slot_of=self.snapshot.slots,
+                )
+                metrics.spread_tracked_selectors.set(
+                    len(self.cache.spread_counts)
                 )
             interpod = None
             if need_interpod:

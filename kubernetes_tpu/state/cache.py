@@ -21,8 +21,10 @@ from __future__ import annotations
 
 from dataclasses import dataclass, field
 
+from .. import metrics
 from ..api.objects import Node, Pod
 from ..utils.clock import Clock
+from .spread_counts import SpreadCounts
 
 
 class CacheError(Exception):
@@ -85,6 +87,12 @@ class SchedulerCache:
         self._assumed: dict[str, _AssumedInfo] = {}
         # where each cached pod currently lives (node name), incl. assumed
         self._pod_node: dict[str, str] = {}
+        # matching pods per node NAME for the selectors spread constraints
+        # ask about; it follows HostNodeInfo.pods of the nodes whose
+        # ``node`` is set, under the lock that guards ``nodes``
+        self.spread_counts = SpreadCounts(  # ktpu: guarded-by(cluster.lock)
+            self._live_pods, metrics.spread_count_rows_total
+        )
 
     # -- generation --
 
@@ -105,6 +113,7 @@ class SchedulerCache:
         if info is None or info.node is None:
             raise CacheError(f"assume on unknown node {node_name}")
         info.add_pod(pod)
+        self._counted(pod, node_name)
         self._bump(info)
         self._pod_node[pod.key] = node_name
         self._assumed[pod.key] = _AssumedInfo(
@@ -177,8 +186,12 @@ class SchedulerCache:
                 self._remove_from_node(key)
                 self._add_to_node(pod)
             else:
-                # confirm: swap the stored object for the API one (same sums)
+                # confirm: swap the stored object for the API one (same
+                # sums; its labels may differ from the assumed object's)
                 info = self.nodes[pod.node_name]
+                if info.node is not None:
+                    self._uncounted(info.pods[key], pod.node_name)
+                    self._counted(pod, pod.node_name)
                 info.pods[key] = pod
                 self._bump(info)
         elif key in self._pod_node:
@@ -214,16 +227,36 @@ class SchedulerCache:
             info = HostNodeInfo(node=None, generation=0)
             self.nodes[name] = info
         info.add_pod(pod)
+        if info.node is not None:
+            self._counted(pod, name)
         self._bump(info)
         self._pod_node[pod.key] = name
 
     def _remove_from_node(self, pod_key: str) -> None:
         name = self._pod_node.pop(pod_key)
         info = self.nodes[name]
-        info.remove_pod(pod_key)
+        pod = info.remove_pod(pod_key)
+        if info.node is not None:
+            self._uncounted(pod, name)
         self._bump(info)
         if info.node is None and not info.pods:
             del self.nodes[name]
+
+    # -- per-selector node counts (state/spread_counts.py) --
+
+    def _live_pods(self):
+        """(node name, its pods) of every node a spread constraint counts."""
+        for name, info in self.nodes.items():
+            if info.node is not None:
+                yield name, info.pods.values()
+
+    # every mutator runs under the cluster lock: ktpu: holds(cluster.lock)
+    def _counted(self, pod: Pod, node_name: str) -> None:
+        self.spread_counts.pod_added(pod, node_name)
+
+    # every mutator runs under the cluster lock: ktpu: holds(cluster.lock)
+    def _uncounted(self, pod: Pod, node_name: str) -> None:
+        self.spread_counts.pod_removed(pod, node_name)
 
     def add_node(self, node: Node) -> None:
         info = self.nodes.get(node.name)
@@ -231,6 +264,9 @@ class SchedulerCache:
             info = HostNodeInfo(node=node, generation=0)
             self.nodes[node.name] = info
         else:
+            if info.node is None:  # back, with the pods that stayed
+                for pod in info.pods.values():
+                    self._counted(pod, node.name)
             info.node = node
         self._bump(info)
 
@@ -242,6 +278,9 @@ class SchedulerCache:
         if info is None:
             return
         if info.pods:
+            if info.node is not None:
+                for pod in info.pods.values():
+                    self._uncounted(pod, name)
             info.node = None  # keep resource bookkeeping for remaining pods
             self._bump(info)
         else:

@@ -54,6 +54,11 @@ class Snapshot:
     def slot_of(self, name: str) -> int:
         return self._slot_of[name]
 
+    @property
+    def slots(self) -> dict[str, int]:
+        """node name -> slot of every named slot (``names`` inverted)."""
+        return self._slot_of
+
     def name_of(self, slot: int) -> str:
         return self.names[slot]
 

@@ -26,6 +26,7 @@ import numpy as np
 
 from ..api.objects import Node, Pod
 from ..ops.oracle import spread as osp
+from ..state.spread_counts import SpreadCounts
 from .schema import PodBatch, bucket_pow2
 
 INST_PAD = 8  # instance-axis quantum
@@ -90,6 +91,8 @@ def build_spread_tensors(
     services: Sequence | None = None,
     defaulting: str = "System",
     nominated: Sequence[tuple[Pod, int]] = (),
+    counts: SpreadCounts | None = None,
+    slot_of: Mapping[str, int] | None = None,
 ) -> SpreadTensors:
     """class_reps comes from the static tensorizer so all per-class tables
     share one class id space (xs carries class_of for the gather).
@@ -103,7 +106,14 @@ def build_spread_tensors(
     ``cnt0`` exactly like placed pods (the
     RunFilterPluginsWithNominatedPods convention the synchronous filter
     path already applies via the ports tensorizer) so a spread
-    constraint sees a nominated peer as occupying its slot."""
+    constraint sees a nominated peer as occupying its slot.
+
+    The placed pods come from ONE of two places. A caller with a
+    scheduler cache passes ``counts``, its per-selector node counts kept by
+    node name, with ``slot_of``, the name -> slot map of this batch, and an
+    empty ``placed_by_slot``. A caller with only lists passes
+    ``placed_by_slot`` and neither of the two: the same index is built over
+    it here and dropped. Any other combination is refused."""
     # collect instances per class
     per_class: list[tuple[list, list]] = []  # (hard ECs, soft ECs)
     insts: list[tuple[int, osp.EffectiveConstraint, bool, Pod]] = []
@@ -130,17 +140,20 @@ def build_spread_tensors(
     hard_tbl = np.full((c_pad, sh), -1, dtype=np.int32)
     soft_tbl = np.full((c_pad, ss), -1, dtype=np.int32)
 
-    # domain vocab per topology key (over all live nodes)
-    all_keys = {ec.topology_key for _, ec, _, _ in insts}
-    key_vocab: dict[str, dict[str, int]] = {k: {} for k in all_keys}
-    for node in slot_nodes:
-        if node is None:
-            continue
-        for key in all_keys:
-            v = node.labels.get(key)
+    # dom is a function of the topology key alone: one [N] row per key of
+    # the batch (domain ids per key in slot order, over all live nodes),
+    # shared by every instance that names the key
+    key_vocab: dict[str, dict[str, int]] = {}
+    dom_rows: dict[str, np.ndarray] = {}
+    for key in {ec.topology_key for _, ec, _, _ in insts}:
+        vocab = key_vocab[key] = {}
+        row = dom_rows[key] = np.full(padded_n, -1, dtype=np.int32)
+        for n_i, node in enumerate(slot_nodes):
+            v = None if node is None else node.labels.get(key)
             if v is not None:
-                vocab = key_vocab[key]
-                vocab.setdefault(v, len(vocab))
+                d = vocab.setdefault(v, len(vocab))
+                if n_i < padded_n:
+                    row[n_i] = d
     max_domains = max((len(v) for v in key_vocab.values()), default=1)
     d_pad = bucket_pow2(max_domains, floor=DOM_PAD)
 
@@ -154,21 +167,49 @@ def build_spread_tensors(
     placed_match = np.zeros((pbatch.padded, j_pad), dtype=bool)
 
     # counting eligibility is shared by every instance of one (class,
-    # hardness) bucket (upstream counts one node set per bucket) — compute
-    # each bucket's [N] row once, not once per instance
-    elig_cache: dict[tuple[int, bool], np.ndarray] = {}
+    # hardness) bucket (upstream counts one node set per bucket), and by
+    # every bucket _node_counted cannot tell apart: the row is keyed on
+    # exactly what it reads of the bucket and the rep
+    elig_rows: dict[tuple, np.ndarray] = {}
 
     def bucket_elig(c: int, is_hard: bool) -> np.ndarray:
-        row = elig_cache.get((c, is_hard))
+        bucket = per_class[c][0] if is_hard else per_class[c][1]
+        rep = class_reps[c]
+        na = rep.affinity.node_affinity if rep.affinity else None
+        reads = (
+            frozenset(ec.topology_key for ec in bucket),
+            (
+                tuple(sorted((rep.node_selector or {}).items())),
+                None if na is None else na.required,
+            )
+            if any(ec.node_affinity_policy == "Honor" for ec in bucket)
+            else None,
+            tuple(rep.tolerations)
+            if any(ec.node_taints_policy == "Honor" for ec in bucket)
+            else None,
+        )
+        row = elig_rows.get(reads)
         if row is None:
-            bucket = per_class[c][0] if is_hard else per_class[c][1]
-            rep = class_reps[c]
-            row = np.zeros(padded_n, dtype=bool)
-            for n_i, node in enumerate(slot_nodes):
-                if node is not None and n_i < padded_n:
+            row = elig_rows[reads] = np.zeros(padded_n, dtype=bool)
+            for n_i, node in enumerate(slot_nodes[:padded_n]):
+                if node is not None:
                     row[n_i] = osp._node_counted(rep, node, bucket)
-            elig_cache[(c, is_hard)] = row
         return row
+
+    # matching placed pods per node: from the counts the scheduler cache
+    # keeps, or from an index built here over placed_by_slot and dropped
+    if counts is None and slot_of is None:
+        counts = SpreadCounts(placed_by_slot.items)
+    elif counts is None or slot_of is None or placed_by_slot:
+        raise ValueError(
+            "placed pods come from placed_by_slot alone, or from counts "
+            "with slot_of and an empty placed_by_slot"
+        )
+    cnt0[: len(insts)] = counts.rows(
+        [(rep.namespace, ec.selector) for _, ec, _, rep in insts],
+        padded_n,
+        slot_of,
+    )
 
     hard_fill: dict[int, int] = {}
     soft_fill: dict[int, int] = {}
@@ -184,23 +225,8 @@ def build_spread_tensors(
         self_match[j] = osp._sel_matches(ec.selector, rep.labels)
         is_hostname[j] = ec.topology_key == osp.HOSTNAME_KEY
         elig[j] = bucket_elig(c, is_hard)
+        dom[j] = dom_rows[ec.topology_key]
 
-        vocab = key_vocab.get(ec.topology_key, {})
-        for n_i, node in enumerate(slot_nodes):
-            if node is None or n_i >= padded_n:
-                continue
-            v = node.labels.get(ec.topology_key)
-            if v is not None:
-                dom[j, n_i] = vocab[v]
-        for n_i, placed in placed_by_slot.items():
-            if n_i >= padded_n:
-                continue
-            cnt0[j, n_i] = sum(
-                1
-                for p in placed
-                if p.namespace == rep.namespace
-                and osp._sel_matches(ec.selector, p.labels)
-            )
         for p, n_i in nominated:
             # nominated-pod parity: count a matching nominated pod at
             # its slot exactly like a placed pod
