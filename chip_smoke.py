@@ -876,7 +876,7 @@ def phase_b_child(args) -> int:
 
     def capacity_ok(step, a, cpu, mem, nb0) -> None:
         """No node over cpu / memory / pod count under the actual
-        request vectors (weighted bincounts, bench.py's gates)."""
+        request vectors (weighted bincounts)."""
         placed = a >= 0
         require(int(a.max()) < n_nodes, f"{step}: bound to a padding row")
         for name, w, used0, cap in (
@@ -894,7 +894,7 @@ def phase_b_child(args) -> int:
         cnt = np.bincount(a[placed], minlength=npad) + nb0.pod_count
         require(bool((cnt <= nb0.max_pods).all()), f"{step}: pods overcommit")
 
-    # -- exact session solve (bench ladder #7 / north-star exact) --
+    # -- exact session solve (the north star: 51,200 nodes x 10,240 pods) --
     cfg = ExactSolverConfig(tie_break="random", group_size=1024)
     cpu1 = np.full(n_pods, 1000, np.int64)
     mem1 = np.full(n_pods, 2 << 30, np.int64)
@@ -937,7 +937,8 @@ def phase_b_child(args) -> int:
         )
 
     # -- single-shot auction, relax + auction repair: one preloaded
-    # heterogeneous cluster, 8 request classes (bench ladders #5/#16) --
+    # heterogeneous cluster, 8 request classes (BASELINE.json
+    # configuration 5's rebalance shape) --
     from kubernetes_tpu.solver.relax import RelaxConfig, RelaxSolver
     from kubernetes_tpu.solver.single_shot import (
         SingleShotConfig,
@@ -978,7 +979,8 @@ def phase_b_child(args) -> int:
     timed("relax_with_auction_repair", relax)
 
     # -- Scheduler.drain_backlog through run_streaming's ring on the
-    # hard-spread shape (bench ladder #11), then a preemption dry run
+    # hard-spread shape (every pod maxSkew / DoNotSchedule over zones),
+    # then a preemption dry run
     # and a dirty-column heal on the same resident session --
     from kubernetes_tpu import metrics
     from kubernetes_tpu.api.wrappers import MakeNode, MakePod

@@ -15,7 +15,7 @@ re-validate each placement pre-assume (fleet/reconciler.py), retrying
 conflicts through the scheduler's existing requeue machinery.
 
 Wiring: set ``SchedulerConfig.fleet = FleetConfig(replica=...,
-replicas=(...))``; replicas sharing a process (sim, tests, bench)
+replicas=(...))``; replicas sharing a process (sim, tests)
 share one ``OccupancyExchange``; cross-process replicas share the same
 hub over the bulk gRPC service's ``HubOp`` method
 (``RemoteOccupancyExchange``, config key ``fleet.hubAddress``) with

@@ -26,7 +26,7 @@ This module is the failover half of hub HA:
   server uses, so in-process and on-wire semantics cannot drift.
 
 Scope note: ``HubLease`` coordinates hubs within one process tree (the
-sim, tests, the bench ladder). A multi-host deployment backs the same
+sim, tests). A multi-host deployment backs the same
 interface with a real coordination store (the Lease objects the
 per-shard LeaderElectors already use); the hub only ever calls
 ``try_acquire`` / ``renew`` / ``valid``.

@@ -61,8 +61,8 @@ class FleetConfig:
     # base lease name for the per-shard LeaderElector identity
     # (<lease>-shard-<i>, i = rank of the replica in the sorted universe)
     lease: str = "kubernetes-tpu-scheduler"
-    # the occupancy exchange hub. In-process fleets (the sim, tests, the
-    # bench A/B) share one OccupancyExchange; cross-process replicas
+    # the occupancy exchange hub. In-process fleets (the sim, tests)
+    # share one OccupancyExchange; cross-process replicas
     # reach a shared hub over the bulk gRPC boundary — pass a
     # RemoteOccupancyExchange here, or just set hub_address below and
     # let FleetRuntime construct one. None + no hub_address = private
@@ -102,15 +102,16 @@ class FleetConfig:
     # (kubernetes_tpu/tuning, knob "fleet_flush"); 0 = the adapter's
     # built-in default. In-process hubs ignore it (no wire to batch).
     flush_batch: int = 0
-    # per-domain CAS versioning (config key fleet.casDomain; the
-    # occupancy module docstring's granularity scope note): scope each
+    # per-domain CAS versioning (the occupancy module docstring's
+    # granularity scope note; no config key reaches it): scope each
     # compare_and_stage to the row's interference domain instead of
     # the one hub-wide version, so N replicas' concurrent write-behind
     # flushes (a fleet backlog drain's steady state) stop costing
     # every constrained admit a spurious re-fetch round. Off by
-    # default — measure scheduler_fleet_admit_cas_conflict_total
-    # first; the bench fleet-drain ladder turns it on and reports the
-    # conflict delta.
+    # default and on in nothing (ROADMAP Design 3): only the hub side
+    # is tested, directly (tests/test_fleet_drain.py,
+    # domain_scope=True); measure
+    # scheduler_fleet_admit_cas_conflict_total before turning it on.
     cas_domain: bool = False
 
     def __post_init__(self) -> None:
@@ -174,8 +175,8 @@ class RemoteOccupancyExchange:
     ``apply_ops`` RPC — before every read (so any view this replica
     admits against reflects its own prior writes), at the buffer cap,
     and at every resync poll. Per-row unary RPCs would otherwise put
-    a wire round trip inside the per-pod apply loop (measured ~4x
-    throughput loss on the ladder #8 fleet arm). This is sound
+    a wire round trip inside the per-pod apply loop (~4x throughput
+    loss on a CPU fleet drive, not measured on the chip). This is sound
     because the admission-critical row landings don't ride the
     buffer: a cross-shard-CONSTRAINED placement lands synchronously
     via ``compare_and_stage`` (the atomic admit), commit is a
@@ -1372,7 +1373,7 @@ class FleetRuntime:
         if not self._needs_reconcile(pod):
             # no cross-shard-scoped constraint: ownership (disjoint
             # shards) is the whole fleet story for this pod — skip the
-            # O(peer rows) view (the bench's plain sustained arm would
+            # O(peer rows) view (an unconstrained stream would
             # otherwise pay it per pod)
             self._reject_counts.pop(pod.key, None)
             return None

@@ -470,8 +470,7 @@ tuning_guardrail_rejections_total = Counter(
     "— e.g. a drain-chunk candidate whose HBM budget-model estimate "
     "(solver/budget.py) exceeds the per-device budget. A rejection is "
     "the guardrail working; a tuner-applied value failing its guard "
-    "would be a breach, which the sim invariant and bench ladder pin "
-    "at zero.",
+    "would be a breach, which the sim's tuning invariant pins at zero.",
     ["knob"],
     registry=REGISTRY,
 )
@@ -770,16 +769,16 @@ telemetry_bundles_total = Counter(
 slo_p50_pod_latency_seconds = Gauge(
     "scheduler_slo_p50_pod_latency_seconds",
     "Sliding-window median per-pod scheduling latency (first queue "
-    "entry -> bind commit, the bench ladder's sustained-latency "
-    "definition), computed by the live SLO engine from the latencies "
-    "the apply path already materializes — zero new device syncs.",
+    "entry -> bind commit), computed by the live SLO engine from the "
+    "latencies the apply path already materializes — zero new device "
+    "syncs.",
     registry=REGISTRY,
 )
 slo_p99_pod_latency_seconds = Gauge(
     "scheduler_slo_p99_pod_latency_seconds",
     "Sliding-window p99 per-pod scheduling latency (first queue entry "
     "-> bind commit) from the live SLO engine — 'are we meeting the "
-    "latency SLO right now' without a bench ladder run.",
+    "latency SLO right now', read off the running server.",
     registry=REGISTRY,
 )
 slo_bind_throughput = Gauge(

@@ -122,9 +122,9 @@ class ObsConfig:
     # deterministic, so same-seed sim runs stay byte-identical. 1 =
     # span every event (the PR 3 behavior). Batch-level spans
     # (schedule_batch/dispatch/apply/bind/...) are never sampled: they
-    # are the trace's structure. The shipped default keeps the whole
-    # obs layer inside the <= 5% sustained-throughput budget bench
-    # ladder #13 asserts.
+    # are the trace's structure. The default was chosen on a CPU; on
+    # the chip the traced cells read 0.3-1.8 % lower with the layer on
+    # (PERF.md §6, PR 25).
     enqueue_span_sample_n: int = 64
     # deterministic 1-in-N sampling for the PER-POD bind span (the
     # other per-pod-volume family). The decision JOURNAL stays
@@ -223,8 +223,8 @@ class Telemetry:
     The scheduler's hot path pays one ``is not None`` check when
     telemetry is off; when on, every write here is host-side arithmetic
     over numbers the loops already computed, or a profiler annotation
-    that is free outside a profiler session (TPU001-clean — the whole
-    layer rides inside bench ladder #13's <= 5% obs budget)."""
+    that is free outside a profiler session (TPU001-clean; what the
+    layer costs on the chip is in PERF.md §6, PR 25)."""
 
     def __init__(
         self,

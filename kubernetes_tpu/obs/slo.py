@@ -1,5 +1,5 @@
 """Live SLO engine: are we meeting latency SLOs *right now*, answered
-from counters the scheduling loops already tick — no bench ladder run,
+from counters the scheduling loops already tick — no offline run,
 no new device syncs (the PR 13 ``CounterWindow`` sampling discipline:
 host-side reads of numbers the apply path already materialized).
 
@@ -7,9 +7,9 @@ One ``SloEngine`` per Scheduler, ticked from ``_record_metrics`` (the
 chokepoint every dispatch loop — sync, pipelined, streaming, drain —
 funnels applied batches through):
 
-- **sliding-window pod latency** — p50/p99 of first-enqueue→bind (the
-  ladder's sustained-latency definition, ``BatchResult.e2e_latencies``,
-  already computed per batch) over a bounded sample pool;
+- **sliding-window pod latency** — p50/p99 of first-enqueue→bind
+  (``BatchResult.e2e_latencies``, already computed per batch) over a
+  bounded sample pool;
 - **bind throughput** — pods bound per wall second over the window;
 - **multi-window error-budget burn rate** — the SRE burn-rate form:
   (observed bad fraction) / (allowed bad fraction), where an event is

@@ -384,7 +384,7 @@ class FullOracle:
         tie-set parity (``validate_assignments``) is the sequential
         solvers' contract, not the planner's. Unplaced pods are not
         flagged — under-placement is an objective-quality question the
-        bench/sim ratio floors own, not a validity violation.
+        sim's megaplan ratio floor owns, not a validity violation.
         ``sample``: step indices to verify, as in
         ``validate_assignments`` — every step is still REPLAYED so the
         state stays exact; only the per-step filter run is skipped

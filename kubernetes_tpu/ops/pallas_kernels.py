@@ -27,7 +27,7 @@ routes its [T, D] aggregation through ``domain_counts_padded`` below
 when ``ExactSolverConfig.pallas`` is set, inside the production per-pod
 scan, with parity pinned end to end by tests/test_pallas_kernels.py
 (production ExactSolver.solve, flag on vs off, bit-identical
-assignments) and a ladder micro-bench in bench.py.
+assignments); chip_smoke.py compiles it with Mosaic on the chip.
 
 **x64.** The solver REQUIRES ``jax_enable_x64`` process-wide (int64
 resource arithmetic; memory bytes overflow int32), and under x64 every

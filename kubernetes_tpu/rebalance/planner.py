@@ -54,8 +54,6 @@ class Move:
     pod: Pod
     source: str  # node name the pod is evicted from
     target: str  # node name the auction placed it on (nominated hint)
-    source_slot: int
-    target_slot: int
     gain: int  # packing-score improvement, percent points
 
 
@@ -69,8 +67,8 @@ class RebalancePlan:
 # the planner's auction posture: pack objective (fullest feasible nodes
 # first) with a NARROW bid window — the round-robin fan-out spreads a
 # class across its whole window, so a wide window would scatter instead
-# of consolidate; 8 fullest nodes per round measured a good balance of
-# rounds vs packing on the bench shapes
+# of consolidate; 8 fullest nodes per round was chosen on a CPU, not
+# measured on the chip (ROADMAP Design 6)
 PLAN_TOP_T = 8
 
 
@@ -93,7 +91,8 @@ def plan_auction_config(base: SingleShotConfig | None = None) -> SingleShotConfi
 # engine routing: below this pods x padded-nodes product the auction's
 # sequential rounds are cheap and its narrow-window consolidation is
 # the better plan; above it the relaxation's matmul iterations win the
-# wall-clock race outright (bench ladder #16: >= 10x at 512k x 102k)
+# wall-clock race. The crossover was chosen on a CPU, not measured on
+# the chip (ROADMAP Design 6)
 RELAX_PLAN_CELLS = 1 << 24
 
 
@@ -139,8 +138,8 @@ def plan_moves(
     run on — an infeasible target would otherwise evict the pod just
     for the real solve to bounce it back, a perpetual churn loop the
     strict-gain selection alone cannot prevent (the gain math is
-    packing-only). Without ``slot_nodes`` (synthetic tensor callers,
-    e.g. the bench) the mask degrades to schedulable-only.
+    packing-only). Without ``slot_nodes`` (synthetic tensor callers)
+    the mask degrades to schedulable-only.
 
     ``engine``: ``"auction"`` (the narrow-window pack auction),
     ``"relax"`` (the convex-relaxation mega-planner, solver/relax.py —
@@ -278,8 +277,6 @@ def select_moves(
             pod=pod,
             source=slot_names[src],
             target=slot_names[dst],
-            source_slot=src,
-            target_slot=dst,
             gain=gain,
         )
         for pod, src, dst, gain in selected

@@ -61,7 +61,7 @@ class RebalanceConfig:
 
 @dataclass(frozen=True)
 class RunRecord:
-    """One rebalance pass, for the sim invariants and the bench."""
+    """One rebalance pass, for the sim invariants."""
 
     t: float  # clock.now() at the pass
     packing_before: float  # detector's packed utilization at the pass

@@ -155,8 +155,8 @@ class ResilienceConfig:
     quarantine_ttl: float = 60.0
     quarantine_backoff: float = 2.0
     max_quarantine_ttl: float = 900.0
-    # pin the ladder to one tier (bench ladder #9's forced host-greedy
-    # arm; tests). The breaker machinery is bypassed entirely.
+    # pin the ladder to one tier (tests/test_resilience.py drives each
+    # rung that way). The breaker machinery is bypassed entirely.
     force_tier: str | None = None
     # master switch for pre-apply output validation (the ladder itself
     # has no switch: with no failures it is zero-cost)

@@ -278,7 +278,8 @@ class BatchResult:
     # per-pod schedule latency (pop -> bind committed), for the p99 metric
     latencies: list[float] = field(default_factory=list)
     # per-pod end-to-end latency (first queue entry -> bind committed, on
-    # the scheduler clock) — the open-loop sustained benchmark's p99
+    # the scheduler clock): what the SLO engine (obs/slo.py) and
+    # `python -m kubernetes_tpu perf` read
     e2e_latencies: list[float] = field(default_factory=list)
     # perf_counter when this batch's bindings finished committing; lets
     # throughput collectors sample pods/s across overlapped batches
@@ -287,7 +288,7 @@ class BatchResult:
     @property
     def progressed(self) -> bool:
         """Did this cycle do ANY work a drive loop should keep ticking
-        for? One definition for every drain/settle/bench loop, so a new
+        for? One definition for every drain/settle loop, so a new
         outcome field can't silently go missing from some call sites."""
         return bool(
             self.scheduled
@@ -301,11 +302,11 @@ class BatchResult:
 
 @dataclass
 class BacklogDrainReport:
-    """What one ``Scheduler.drain_backlog`` pass did, for the bench
-    ladder, the sim footer, and operators (the same numbers back the
+    """What one ``Scheduler.drain_backlog`` pass did, for the sim
+    footer and operators (the same numbers back the
     ``scheduler_backlog_*`` metrics). ``results`` holds the underlying
     per-chunk BatchResults so callers can fold them into their own
-    accounting (the sim's bind tracker, the bench's latency pool)."""
+    accounting (the sim's bind tracker)."""
 
     pods: int = 0  # backlog size at drain start
     drained: int = 0  # pods bound by this pass
@@ -319,8 +320,6 @@ class BacklogDrainReport:
     budget_bytes: int = 0  # per-device budget asserted against
     drain_seconds: float = 0.0
     pods_per_sec: float = 0.0
-    p99_e2e_latency_s: float = 0.0  # first queue entry -> bind commit
-    median_chunk_solve_s: float = 0.0  # per the ladder-#10 convention
     stream_chained_batches: int = 0  # cross-batch carry chains engaged
     chain_fraction: float = 0.0  # chained / (chunks - 1)
     estimated_per_device_bytes: int = 0  # HBM model, resident worst case
@@ -669,15 +668,10 @@ class Scheduler:
             )
         # streaming dispatcher (run_streaming) infrastructure: the
         # completion thread + its handle queue are created lazily on the
-        # first streaming cycle; the hidden/paid read tally feeds the
-        # bench ladder's RTT attribution (driver thread only — a read is
-        # "paid" when the driver actually blocked on it > 1 ms, which is
-        # deterministic under FakeClock: virtual reads never block).
+        # first streaming cycle.
         self._completion_thread = None
         self._completion_q = None
         self._streaming_active = False
-        self._reads_hidden = 0
-        self._reads_paid = 0
         # backlog drain (drain_backlog): while active, dispatch spans
         # and journal records carry the drain-chunk id (prep.step -
         # base) so `obs explain` attributes a pod to the chunk that
@@ -4572,19 +4566,17 @@ class Scheduler:
                     flight, res, pending, fence=prep.fence
                 )
                 self._note_flight_timing(flight, len(infos))
-                # read attribution (ladder #6): a deferred read that
-                # blocked the driver > 1 ms was not hidden; anything
-                # faster was hidden by overlapped
-                # host work / the completion thread's pre-wait. The
-                # threshold makes this deterministic under FakeClock
-                # (virtual reads never block).
-                if isinstance(flight.handle, DeferredAssignments):
-                    if flight.read_seconds > 1e-3:
-                        self._reads_paid += 1
-                        if self._streaming_active:
-                            metrics.stream_unhidden_reads_total.inc()
-                    else:
-                        self._reads_hidden += 1
+                # a deferred read that blocked the driver > 1 ms was
+                # not hidden by overlapped host work / the completion
+                # thread's pre-wait. The threshold makes this
+                # deterministic under FakeClock (virtual reads never
+                # block).
+                if (
+                    self._streaming_active
+                    and isinstance(flight.handle, DeferredAssignments)
+                    and flight.read_seconds > 1e-3
+                ):
+                    metrics.stream_unhidden_reads_total.inc()
                 if applied:
                     # host cost = this batch's own tensorize + apply
                     # phases; wall-since-pop would charge the overlapped
@@ -5029,7 +5021,7 @@ class Scheduler:
         t.start()
         # the static target keeps the Scheduler collectable; this makes
         # the thread follow it out — processes that build schedulers
-        # repeatedly (restart recovery, fleet sims, bench ladders) must
+        # repeatedly (restart recovery, fleet sims) must
         # not accumulate one parked thread + queue per instance. GC-time
         # only (atexit=False): waking a parked daemon thread during
         # interpreter shutdown exits it through C++ frames
@@ -5725,14 +5717,6 @@ class Scheduler:
         report.budget_bytes = budget
         report.drain_seconds = dt
         report.pods_per_sec = report.drained / dt if dt > 0 else 0.0
-        lats = sorted(x for r in results for x in r.e2e_latencies)
-        if lats:
-            report.p99_e2e_latency_s = lats[int(0.99 * (len(lats) - 1))]
-        solves = sorted(
-            r.solve_seconds for r in results if r.solve_seconds > 0
-        )
-        if solves:
-            report.median_chunk_solve_s = solves[len(solves) // 2]
         report.stream_chained_batches = (
             sum(
                 s.dispatch_counts.get("stream_chained", 0)

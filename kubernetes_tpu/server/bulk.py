@@ -23,7 +23,7 @@ server/tensorcodec.py — columnar arrays + one JSON header):
 Columnar pods deliberately carry only resources + priority: richer pods
 (affinity, spread, ports) flow through the JSON ingest + webhook path where
 the full object model applies. This mirrors the north-star workload shape
-(BASELINE.json ladder #5: resource rebalance at 50k x 10k).
+(BASELINE.json configuration 5: resource rebalance at 50k x 10k).
 
 Uses grpc.method_handlers_generic_handler with identity serializers —
 the wire is opaque bytes (tensorcodec framing); no protoc codegen exists

@@ -1671,8 +1671,8 @@ class ExactSolver:
         # "padding" those that hold none. _tally is the one increment;
         # /metrics exports the same counts (_TALLY_SERIES). Benchmarks
         # report THIS instead of asserting which path a workload takes
-        # (a round-3 bench label claimed grouping was disabled on
-        # workloads where the quota chunks in fact engaged).
+        # (PERF.md §4: the spread cell was assumed to take the per-pod
+        # scan until these counts showed the quota chunks engaged).
         from collections import Counter
 
         self.dispatch_counts: Counter = Counter()
@@ -2299,7 +2299,7 @@ class ExactSolver:
             # the SPMD partitioner rejects the flatten+concat of the
             # sharded state with a dtype-mixed dynamic_update_slice
             # (s64 index vs s32 shard offset, XLA verifier error), and a
-            # sharded standalone solve is a dryrun/bench/test context
+            # sharded standalone solve is a dryrun/smoke/test context
             # where four reads instead of one is acceptable
             pack_result=not session and mesh is None,
             compact=compact,

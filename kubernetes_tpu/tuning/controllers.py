@@ -15,8 +15,8 @@ around three safety properties the convergence tests pin:
   by explicit ``unsettle``.
 - **revert on regression**: a rejected probe restores the incumbent
   value immediately. The knob never stays at a measured-worse setting
-  longer than one evaluation window, which is what makes the tuned
-  bench arm ">= static" by construction rather than by luck.
+  longer than one evaluation window, which is what makes a tuned
+  drive ">= static" by construction rather than by luck.
 - **settle detection**: after both directions fail to improve
   ``settle_after`` times, the climber stops proposing entirely (zero
   steady-state overhead). ``unsettle`` re-opens it.

@@ -22,7 +22,7 @@ Knobs and their application discipline:
   (``solver/budget.estimate`` + the index-headroom audit) BEFORE it is
   applied, so a tuner-proposed chunk can never raise ``BudgetExceeded``
   from the dispatch path — that is the "guardrail breach" the metrics
-  and the bench ladder pin at zero.
+  and the sim's tuning invariant pin at zero.
 - ``fleet_flush`` — the write-behind flush batch of the fleet's remote
   occupancy exchange (``RemoteOccupancyExchange``); applied through
   ``FleetRuntime.set_flush_batch``, a no-op for in-process hubs.
@@ -109,13 +109,12 @@ class TuningRuntime:
         self.decisions: list[Decision] = []
         # guardrail BREACHES: a tuner-applied value failing its guard at
         # apply time. Proposals are guarded BEFORE application, so this
-        # stays 0 — the counter exists to prove it (the bench ladder and
-        # the sim invariant both pin it).
+        # stays 0 — the counter exists to prove it (the sim's tuning
+        # invariant pins it).
         self.guardrail_breaches = 0
         self.shifts = 0
         # window.batches when every active controller first settled
-        # (re-recorded after each unsettle; the bench ladder hoists the
-        # first value as tuning_convergence_batches)
+        # (re-recorded after each unsettle; the sim footer prints it)
         self.convergence_batches: int | None = None
         # frozen: ticks become no-ops. The sim harness sets this at
         # quiescence — once churn stops, the draining tail is teardown,
@@ -539,7 +538,7 @@ class TuningRuntime:
         return bool(engaged) and all(c.settled for c in engaged)
 
     def summary(self) -> dict:
-        """Deterministic run summary (the sim footer / bench row): all
+        """Deterministic run summary (the sim footer): all
         python-side counters, so same-seed sim runs stay
         byte-identical. Retired climbers (a finished drain's chunk
         controller) keep contributing their counters; ``settled``

@@ -5,7 +5,8 @@
 #      suppression-debt ratchet, and the lock-order artifact drift
 #      check; findings uploaded as SARIF + JSON artifacts; budgeted at
 #      < 10 s wall so the gate stays instant)
-#   2. tier-1 tests   (ROADMAP.md invocation, minus the soak marker)
+#   2. tier-1 tests   (the driver's selection: tests/ minus the soak
+#      marker, six xdist workers, one file per worker at a time)
 #   3. sim smokes     (one fixed-seed run per scenario profile, plus a
 #      determinism self-check on the flagship churn profile)
 #   4. obs smoke      (journaled fixed-seed sim -> JSONL schema check ->
@@ -36,7 +37,7 @@ if [ -z "${SKIP_TESTS:-}" ]; then
     echo "== tier-1 tests =="
     JAX_PLATFORMS=cpu python -m pytest tests/ -q -m 'not slow' \
         --continue-on-collection-errors -p no:cacheprovider \
-        -p no:xdist -p no:randomly
+        -p xdist -n 6 --dist loadfile -p no:randomly
 fi
 
 echo "== sim smokes (fixed seed, every profile) =="

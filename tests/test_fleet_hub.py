@@ -366,8 +366,8 @@ def test_remote_exchange_fence_maps_typed(hub_server):
 
 def test_remote_exchange_write_behind_buffer(hub_server):
     """Plain stage/commit/withdraw buffer client-side and land as ONE
-    apply_ops RPC at the next read — per-row unary RPCs were a
-    measured ~4x throughput loss on the ladder #8 fleet arm — while
+    apply_ops RPC at the next read — per-row unary RPCs put a wire
+    round trip inside the per-pod apply loop — while
     the CAS path always flushes first so admission ordering holds."""
     hub, addr = hub_server
     remote = RemoteOccupancyExchange(addr, "r0")

@@ -1,5 +1,5 @@
 """Single-shot solver: feasibility, work conservation, priority dominance,
-and scale smoke (the 50k x 10k config runs on the real TPU via bench.py)."""
+and scale smoke (the 51,200 x 10,240 shape runs on the TPU in chip_smoke.py)."""
 
 import numpy as np
 
@@ -174,8 +174,8 @@ def test_quality_vs_exact():
     # SCORE quality (VERDICT r3 #7): the snapshot-headroom objective of
     # the auction's placements must be within 10% of the exact anchor's
     # under the same formula (identical empty nodes here, so the check
-    # reduces to placement balance surviving the objective lens; the
-    # preloaded heterogeneous shapes run in bench._quality_table on TPU)
+    # reduces to placement balance surviving the objective lens;
+    # _preloaded_scarce below is the preloaded heterogeneous shape)
     cap_cpu = 8000.0
     cap_mem = 32 * 1024**3
     score = []
@@ -197,7 +197,7 @@ def test_quality_vs_exact():
 
 
 def _preloaded_scarce(seed=3, n_nodes=256, n_pods=1200, rc=8):
-    """Miniature of the bench quality table's scarce_rc8 shape: unevenly
+    """Miniature of a scarce 8-request-class shape: unevenly
     preloaded nodes (heterogeneous base scores), big request classes,
     demand > capacity — the regime where a narrow top-T window strands
     capacity on the fullest (lowest-scored) nodes."""

@@ -69,8 +69,8 @@ class TestHillClimber:
         assert c.value == 1
 
     def test_flat_objective_settles_at_start_value(self):
-        # no direction improves past the margin: stay put (the tuned
-        # bench arm's >= static guarantee rides on this)
+        # no direction improves past the margin: stay put (a tuned
+        # drive's >= static guarantee rides on this)
         c = HillClimber(
             "k", 4, 1, 16, eval_batches=2, hysteresis=0.05,
             settle_after=1,
