@@ -319,6 +319,19 @@ spread_instances_total = Counter(
     "the instances one batch carries.",
     registry=REGISTRY,
 )
+domain_reductions_total = Counter(
+    "scheduler_tpu_domain_reductions_total",
+    "Domain tables carried by ExactSolver.solve calls (the "
+    "PodTopologySpread table of any solve; the inter-pod table of a "
+    "grouped solve, whose anti quota branch reduces it), by the form "
+    "ops/domains.py gives the program's per-domain sums and winners, "
+    "chosen at trace time from the table's padded domain count: dense = "
+    "masked reductions over the node axis (at most DENSE_MAX_SLOTS "
+    "slots), scatter = jax.ops.segment_sum / segment_max. Same "
+    "increments as ExactSolver.dispatch_counts.",
+    ["form"],
+    registry=REGISTRY,
+)
 spread_count_rows_total = Counter(
     "scheduler_tpu_spread_count_rows_total",
     "Rows of SpreadTensors.cnt0 (matching placed pods per node, one row "

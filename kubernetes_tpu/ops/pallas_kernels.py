@@ -3,10 +3,10 @@
 SURVEY.md §3.4: the reference implements its hot loops in pure Go; the
 "native equivalent" obligation here maps to Pallas TPU kernels with jax.lax
 reference implementations for parity (the parity tests ARE the sanitizer).
-First kernel: the (term, domain) count aggregation that PodTopologySpread
-and InterPodAffinity run every scan step (ops/spread.py#_domain_aggregate,
-ops/interpod.py#domain_counts currently lower it through
-jax.ops.segment_sum).
+First kernel: the (term, domain) count aggregation that InterPodAffinity
+runs every scan step (ops/interpod.py#domain_counts lowers it through
+jax.ops.segment_sum; PodTopologySpread's, ops/spread.py#_domain_aggregate,
+goes through ops/domains.py).
 
 domain_counts_pallas computes, for T term rows at once,
 
@@ -53,9 +53,15 @@ scan step with the flag on, on the chip. The workload that made this
 aggregation expensive — hostname-topology terms, where d_pad ~ N — is
 served by ``ops/interpod.domain_counts``' IDENTITY mode (unique-domain
 rows need no aggregation at all), so what remains for the kernel is the
-small-d_pad zone-topology case. Whether it wins there inside the scan
-is ROADMAP Speed item 3's question; until a cell answers it the flag
-stays a flag.
+small-d_pad zone-topology case. ROADMAP Speed item 3 asked whether the
+one-hot form wins there, and PR 30 answered it for the spread path
+without this kernel: ``ops/domains.py`` reduces the per-domain sums and
+winners of ``ops/spread.py`` and ``_solve_grouped`` as masked reductions
+over the node axis (2 us against the scatter's 74 us at 8 slots on a
+v5e; PERF.md section 5), plain XLA that fuses with its producers. This
+kernel still serves only the inter-pod scan's ``[T, D]`` aggregation,
+which no cell runs; until one does the flag stays a flag, and
+``ops/domains.py`` is where ``domain_counts`` would go first.
 
 On non-TPU backends ``domain_counts_padded`` selects interpret mode at
 trace time, which is how the tier-1 parity tests exercise the wired

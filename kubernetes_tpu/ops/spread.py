@@ -2,12 +2,12 @@
 
 Domain bookkeeping that the reference keeps in hash maps
 (podtopologyspread/filtering.go: TpPairToMatchNum, TpKeyToCriticalPaths) is
-recomputed per scan step as segment reductions over the node axis: counts
-per domain = segment_sum of per-node match counts keyed by domain id, the
-"critical path" minimum = masked min over registered domains. This is the
-TPU-shaped tradeoff — O(N) fused vector work per constraint per step beats
-maintaining device-side sorted structures, and the node axis is already
-lane-resident.
+recomputed per scan step as reductions over the node axis: counts per
+domain = per-node match counts summed by domain id (ops/domains.py picks
+the form by the domain axis's size), the "critical path" minimum = masked
+min over registered domains. This is the TPU-shaped tradeoff — O(N) fused
+vector work per constraint per step beats maintaining device-side sorted
+structures, and the node axis is already lane-resident.
 
 Sentinel: INF_COUNT stands in for the reference's math.MaxInt32 initial
 criticalPaths value — an empty domain set means the constraint cannot be
@@ -17,7 +17,8 @@ violated (skew is hugely negative), matching filtering.go#minMatchNum.
 from __future__ import annotations
 
 import jax.numpy as jnp
-from jax import ops as jops
+
+from .domains import domain_sum
 
 MAX_NODE_SCORE = 100
 INF_COUNT = jnp.int32(2**30)
@@ -30,12 +31,8 @@ def _domain_aggregate(dom_row, elig_row, cnt_row, d_pad: int):
     hk = dom_row >= 0
     dd = jnp.where(hk, dom_row, 0)
     counted = elig_row & hk
-    dom_counts = jops.segment_sum(
-        jnp.where(counted, cnt_row, 0), dd, num_segments=d_pad
-    )
-    dom_present = (
-        jops.segment_sum(counted.astype(jnp.int32), dd, num_segments=d_pad) > 0
-    )
+    dom_counts = domain_sum(jnp.where(counted, cnt_row, 0), dd, d_pad)
+    dom_present = domain_sum(counted.astype(jnp.int32), dd, d_pad) > 0
     n_dom = jnp.sum(dom_present.astype(jnp.int32))
     min_match = jnp.min(jnp.where(dom_present, dom_counts, INF_COUNT))
     node_cnt = dom_counts[dd]  # [N]

@@ -37,7 +37,6 @@ from dataclasses import dataclass
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax import ops as jops
 
 from .. import metrics
 from ..ops import fastmath
@@ -45,6 +44,7 @@ from ..ops import interpod as ip
 from ..ops import noderesources as nr
 from ..ops import plugins as pl
 from ..ops import spread as sp
+from ..ops.domains import dense_form, domain_max, domain_sum
 from ..parallel.sharding import (
     REPLICATED_TABLE_NAMES,
     mesh_fingerprint,
@@ -680,10 +680,7 @@ def _solve_grouped(
                     base_cnt = st["spr_cnt"][jj]
                     skew_lim = spr["max_skew"][jj]
                     dom_present = (
-                        jops.segment_sum(
-                            counted.astype(jnp.int32), dd, num_segments=d_pad
-                        )
-                        > 0
+                        domain_sum(counted.astype(jnp.int32), dd, d_pad) > 0
                     )
                     dpad_local = d_pad
                 elif mode == "anti":
@@ -709,7 +706,7 @@ def _solve_grouped(
                 bypass quotas."""
                 if mode == "spread":
                     cnt_now = jnp.where(counted, base_cnt + m, 0)
-                    dc = jops.segment_sum(cnt_now, dd, num_segments=dpad_local)
+                    dc = domain_sum(cnt_now, dd, dpad_local)
                     mn = jnp.min(
                         jnp.where(dom_present, dc, jnp.int32(2**30))
                     )
@@ -721,7 +718,7 @@ def _solve_grouped(
                     cnt_now = jnp.where(
                         hk, base_cnt + (v_in + v_ex) * m, 0
                     )
-                    dc = jops.segment_sum(cnt_now, dd, num_segments=dpad_local)
+                    dc = domain_sum(cnt_now, dd, dpad_local)
                     node_dc = dc[dd]
                     ok = (~hk) | (node_dc == 0)
                     quota_d = jnp.where(dc == 0, 1, 0).astype(jnp.int32)
@@ -833,16 +830,14 @@ def _solve_grouped(
                             # placement's skew bound holds for any
                             # maxSkew >= 1, and mask changes can only add
                             # ties or remove non-chosen nodes.
-                            seg_elig = jops.segment_sum(
-                                ec.astype(jnp.int32),
-                                dd,
-                                num_segments=dpad_local,
+                            seg_elig = domain_sum(
+                                ec.astype(jnp.int32), dd, dpad_local
                             )
                             d_present = jnp.sum(
                                 dom_present.astype(jnp.int32)
                             )
                             # dc_now comes from this iteration's
-                            # domain_eval — no second segment_sum
+                            # domain_eval — no second domain_sum
                             mx_dc = jnp.max(
                                 jnp.where(dom_present, dc_now, -1)
                             )
@@ -934,14 +929,12 @@ def _solve_grouped(
 
                         def winner_accept(_):
                             # sort-free single-round selection: one
-                            # segment_max winner per domain with quota
+                            # domain_max winner per domain with quota
                             # (TPU sorts cost ~1 ms per [5k] vector; the
                             # 1-3 placements of an unbalanced iteration
                             # can't amortize one)
-                            seg_key = jops.segment_max(
-                                jnp.where(ec, rb, -1),
-                                dd,
-                                num_segments=dpad_local,
+                            seg_key = domain_max(
+                                jnp.where(ec, rb, -1), dd, dpad_local
                             )
                             if mode == "spread":
                                 # re-entry gate for maxSkew > 1 (min may
@@ -1028,7 +1021,11 @@ def _solve_grouped(
                             idx_multi = jnp.where(
                                 take, placed + pos_iter, group
                             )
-                            asg = asg.at[idx_multi].set(iota_n, mode="drop")
+                            # asg[idx_multi[n]] = n: the positions are
+                            # distinct, so the write is a max per slot
+                            # (lanes at `group` land in none)
+                            lane = domain_max(iota_n, idx_multi, group)
+                            asg = jnp.where(lane >= 0, lane, asg)
                             single = (~multi) & feasible
                             asg = asg.at[
                                 jnp.where(single, placed, group)
@@ -1633,6 +1630,8 @@ _TALLY_SERIES = {
     "kind2": metrics.solve_chunks_total.labels("spread"),
     "kind3": metrics.solve_chunks_total.labels("anti"),
     "spread_instances": metrics.spread_instances_total,
+    "domains_dense": metrics.domain_reductions_total.labels("dense"),
+    "domains_scatter": metrics.domain_reductions_total.labels("scatter"),
     "class_table_uploads": metrics.class_table_uploads_total,
 }
 
@@ -1668,7 +1667,9 @@ class ExactSolver:
         # per-pod-scan solves and "grouped" grouped ones, "kindK" counts
         # grouped chunks that hold a pod by the _chunk_kinds dispatch
         # (0 slow replay / 1 plain / 2 spread quota / 3 anti quota),
-        # "padding" those that hold none. _tally is the one increment;
+        # "padding" those that hold none, "domains_dense|scatter" the
+        # domain tables by the form ops/domains.py reduces them in.
+        # _tally is the one increment;
         # /metrics exports the same counts (_TALLY_SERIES). Benchmarks
         # report THIS instead of asserting which path a workload takes
         # (PERF.md §4: the spread cell was assumed to take the per-pod
@@ -2190,6 +2191,18 @@ class ExactSolver:
             kinds_host = None
             self._tally("scan")
         self._tally("spread_instances", int(spread.num_instances))
+        # the domain tables whose reductions go through ops/domains.py:
+        # the spread table's in every program, the inter-pod table's only
+        # in the grouped program's anti branch (the per-pod step counts
+        # inter-pod domains over T * d_pad slots, ops/interpod.py)
+        for carried, slots in (
+            (use_spread, spread.d_pad),
+            (use_interpod and grouped, interpod.d_pad),
+        ):
+            if carried:
+                self._tally(
+                    "domains_dense" if dense_form(slots) else "domains_scatter"
+                )
 
         # streaming chain eligibility: session + deferred + un-nominated
         stream = (

@@ -9,6 +9,7 @@ suite on the 8 virtual CPU devices it was written for. This is the one
 place outside the virtual-time sim that sets jax_platforms in code.
 """
 
+import contextlib
 import os
 
 _flags = os.environ.get("XLA_FLAGS", "")
@@ -18,7 +19,28 @@ if "xla_force_host_platform_device_count" not in _flags:
     ).strip()
 
 import jax
+import pytest
 
 jax.config.update("jax_platforms", "cpu")
 # int64 resource arithmetic (memory bytes overflow int32) — parity requires it
 jax.config.update("jax_enable_x64", True)
+
+
+@pytest.fixture
+def all_scatter(monkeypatch):
+    """A context manager under which ops/domains.py takes the scatter at
+    every d_pad (its limit patched to 0: a test's patch, not an option of
+    the program), for the parity tests that solve one seeded batch in both
+    forms. The rule is read when a program is traced, so the jit caches
+    are dropped on the way in and on the way out."""
+    from kubernetes_tpu.ops import domains
+
+    @contextlib.contextmanager
+    def scatter():
+        jax.clear_caches()
+        with monkeypatch.context() as m:
+            m.setattr(domains, "DENSE_MAX_SLOTS", 0)
+            yield
+        jax.clear_caches()
+
+    return scatter

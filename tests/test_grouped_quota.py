@@ -4,8 +4,11 @@ the ungrouped scan; random mode must be sequentially valid (oracle
 replay) and respect the workload invariants."""
 
 import numpy as np
+import pytest
 
+from kubernetes_tpu import metrics
 from kubernetes_tpu.api.wrappers import MakeNode, MakePod
+from kubernetes_tpu.ops.domains import DENSE_MAX_SLOTS
 from kubernetes_tpu.ops.oracle.profile import FullOracle, make_oracle_nodes
 from kubernetes_tpu.solver.exact import ExactSolver, ExactSolverConfig
 from kubernetes_tpu.tensorize.interpod import build_interpod_tensors
@@ -48,6 +51,8 @@ def mk_pods(n, kind):
         )
         if kind == "spread":
             b = b.spread_constraint(1, ZONE, "DoNotSchedule", {"app": kind})
+        elif kind == "hostspread":
+            b = b.spread_constraint(1, HOST, "DoNotSchedule", {"app": kind})
         elif kind == "anti":
             b = b.pod_anti_affinity(HOST, {"app": kind})
         out.append(b.obj())
@@ -72,10 +77,9 @@ def solve(nodes, pods, tie_break, group, seed=3):
     solver = ExactSolver(
         ExactSolverConfig(tie_break=tie_break, group_size=group, seed=seed)
     )
-    return (
-        solver.solve(nbatch, pbatch, static, ports, spread, interpod),
-        nbatch,
-    )
+    a = solver.solve(nbatch, pbatch, static, ports, spread, interpod)
+    nbatch.dispatch_counts = solver.dispatch_counts  # which chunks ran
+    return a, nbatch
 
 
 def test_chunk_kinds_classification():
@@ -213,3 +217,57 @@ def test_spread_skew_blocks_when_unavoidable():
     zones = np.asarray([int(nb.names[x].split("-")[1]) % 2 for x in a])
     counts = np.bincount(zones, minlength=2)
     assert abs(int(counts[0]) - int(counts[1])) <= 1
+
+
+@pytest.mark.parametrize("tie_break", ["random", "first"])
+@pytest.mark.parametrize("kind,chunk", [("spread", "kind2"), ("anti", "kind3")])
+def test_quota_chunks_dense_equal_scatter(kind, chunk, tie_break, all_scatter):
+    """ops/domains.py: the per-domain sums and winners of the quota
+    branches as masked reductions (as shipped: 8 zone slots, 32 hostname
+    slots here) against the scatter they replace, one seeded batch:
+    the same draws, quotas and tie sets, so the same assignments and the
+    same carried node state."""
+    nodes = mk_nodes(24)
+    pods = mk_pods(48 if kind == "spread" else 20, kind)
+    with all_scatter():
+        a_s, nb_s = solve(nodes, pods, tie_break, GROUP, seed=11)
+    a_d, nb_d = solve(nodes, pods, tie_break, GROUP, seed=11)
+    assert nb_d.dispatch_counts[chunk] >= 1  # the branch under test ran
+    assert nb_s.dispatch_counts["domains_scatter"] == 1
+    assert nb_d.dispatch_counts["domains_dense"] == 1
+    assert "domains_scatter" not in nb_d.dispatch_counts
+    assert int((np.asarray(a_d) >= 0).sum()) == len(pods)
+    np.testing.assert_array_equal(a_d, a_s)
+    for name in ("used", "nonzero_used", "pod_count"):
+        np.testing.assert_array_equal(getattr(nb_d, name), getattr(nb_s, name))
+
+
+def _forms():
+    return {
+        f: metrics.domain_reductions_total.labels(f)._value.get()
+        for f in ("dense", "scatter")
+    }
+
+
+@pytest.mark.parametrize(
+    "kind,n_nodes,want",
+    [
+        ("spread", 24, {"dense": 1, "scatter": 0}),  # 3 zones: 8 slots
+        ("hostspread", 300, {"dense": 0, "scatter": 1}),  # 300 hosts: 512 slots
+        ("anti", 300, {"dense": 0, "scatter": 1}),  # the inter-pod table's
+        ("plain", 24, {"dense": 0, "scatter": 0}),  # no domain table
+    ],
+)
+def test_domain_reductions_counter_says_which_form(kind, n_nodes, want):
+    """scheduler_tpu_domain_reductions_total: one increment a solve and
+    domain table, by the form the table's padded slot count selects."""
+    assert 8 <= DENSE_MAX_SLOTS < 512
+    before = _forms()
+    nodes = mk_nodes(n_nodes)
+    a, nb = solve(nodes, mk_pods(GROUP, kind), "first", GROUP)
+    assert int((np.asarray(a) >= 0).sum()) == GROUP
+    after = _forms()
+    assert {f: after[f] - before[f] for f in after} == want
+    # one tally: /metrics and dispatch_counts are the same increments
+    for form, n in want.items():
+        assert nb.dispatch_counts.get(f"domains_{form}", 0) == n

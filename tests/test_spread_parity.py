@@ -1,6 +1,7 @@
 """PodTopologySpread: oracle unit tests + solver-vs-oracle parity."""
 
 import numpy as np
+import pytest
 
 from kubernetes_tpu.api.wrappers import MakeNode, MakePod
 from kubernetes_tpu.ops.oracle import spread as osp
@@ -294,3 +295,36 @@ def test_spread_with_existing_cluster_state():
     }
     pods = [spread_pod(i, max_skew=2) for i in range(4)]
     assert_parity(nodes, pods, existing)
+
+
+def _mixed_pods(n):
+    return [
+        MakePod()
+        .name(f"m{i:03}")
+        .label("app", "api")
+        .req({"cpu": "200m", "memory": "512Mi"})
+        .spread_constraint(2, "zone", "DoNotSchedule", match_labels={"app": "api"})
+        .spread_constraint(1, "zone", "ScheduleAnyway", match_labels={"app": "api"})
+        .obj()
+        for i in range(n)
+    ]
+
+
+@pytest.mark.parametrize("tie_break", ["first", "random"])
+@pytest.mark.parametrize("shape", ["hard", "hard_and_soft"])
+def test_dense_domain_reductions_equal_the_scatter(shape, tie_break, all_scatter):
+    """ops/domains.py under this file's solves (a hard constraint: the
+    grouped program; with a soft one: the per-pod scan, hard_violations and
+    soft_scores): dense (as shipped, 8 zone slots) against the scatter,
+    one seeded batch: the same assignments and carried node state."""
+    nodes = zone_nodes(6, 3)
+    pods = (
+        [spread_pod(i) for i in range(12)] if shape == "hard" else _mixed_pods(12)
+    )
+    with all_scatter():
+        a_s, nb_s = run_solver(nodes, pods, tie_break=tie_break)
+    a_d, nb_d = run_solver(nodes, pods, tie_break=tie_break)
+    assert all(x >= 0 for x in a_d)
+    np.testing.assert_array_equal(a_d, a_s)
+    for name in ("used", "nonzero_used", "pod_count"):
+        np.testing.assert_array_equal(getattr(nb_d, name), getattr(nb_s, name))

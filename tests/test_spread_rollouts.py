@@ -155,6 +155,7 @@ def solve_standalone(cfg, specs, group):
         pods, static.reps, pbatch, slot_nodes, {}, nbatch.padded, static.c_pad
     )
     solver = ExactSolver(ExactSolverConfig(tie_break="first", group_size=group))
+    solver.nodes_after = nbatch  # standalone mode writes the state back
     return solver.solve(nbatch, pbatch, static, ports, spread), solver, spread
 
 
@@ -169,6 +170,27 @@ def test_slow_chunks_equal_the_per_pod_scan(max_skew):
     assert s_scan.dispatch_counts["scan"] == 1 and "kind0" not in s_scan.dispatch_counts
     assert (grouped >= 0).all()
     np.testing.assert_array_equal(grouped, scanned)
+
+
+@pytest.mark.parametrize("max_skew", [5, 1])
+def test_slow_chunks_dense_equal_scatter(max_skew, all_scatter):
+    """Chunk kind 0: the per-pod step's _domain_aggregate through
+    ops/domains.py, dense (as shipped, 8 zone slots) against the scatter,
+    one seeded batch: the same assignments and carried node state."""
+    cfg = rollout_cfg(max_skew=max_skew, nodes=24)
+    specs = gen.RolloutStream(cfg, seed=7).take(BATCH - 5)
+    with all_scatter():
+        a_s, s_s, _ = solve_standalone(cfg, specs, GROUP)
+    a_d, s_d, _ = solve_standalone(cfg, specs, GROUP)
+    assert s_d.dispatch_counts["kind0"] == BATCH // GROUP
+    assert s_s.dispatch_counts["domains_scatter"] == 1
+    assert s_d.dispatch_counts["domains_dense"] == 1
+    assert (a_d >= 0).all()
+    np.testing.assert_array_equal(a_d, a_s)
+    for name in ("used", "nonzero_used", "pod_count"):
+        np.testing.assert_array_equal(
+            getattr(s_d.nodes_after, name), getattr(s_s.nodes_after, name)
+        )
 
 
 # -- (c) the counters ----------------------------------------------------------
