@@ -33,10 +33,9 @@ compile cache, whose key ignores metadata) reads as None, never as 0.
 from __future__ import annotations
 
 import json
-import os
 import sys
 
-from . import files, trace_reduce
+from . import trace_reduce
 
 # solver/exact.py's SCOPES, restated (the benchmark imports nothing of
 # the program; tests/test_named_scopes.py holds the two equal)
@@ -282,18 +281,15 @@ def _gap_row(gap, by_stage: dict, ingest: list, lo_ns: int) -> dict:
 
 
 def for_cell(ctx: dict) -> dict | None:
-    """The attribution of this run's capture, found from the cell's name
-    (run.py hands a reader the reduction, not the planes; the capture
-    stays in the cell's work directory until the next run). None when
-    the run was not traced, or the capture cannot be read."""
+    """The attribution of this run's capture: the file run.py reduced
+    (``trace["xplane"]``; a reader is handed the reduction, not the
+    planes, and the file stays in the cell's work directory until the
+    next run). None when the run was not traced, or the capture cannot
+    be read."""
     tr = ctx.get("trace")
     if not tr:
         return None
-    path = trace_reduce.find_xplane(
-        os.path.join(files.ROOT, ".bench_work", ctx["cell"]["name"], "trace")
-    )
-    if path is None:
-        return None
+    path = tr["xplane"]
     key = (path, tr["lo_ns"], tr["hi_ns"])
     if key not in _captures:
         try:

@@ -24,10 +24,9 @@ there and none of the named ones is: the cell ran no such branch.
 """
 
 import json
-import os
 import sys
 
-from benchmarks.lib import files, span_attrib, trace_reduce
+from benchmarks.lib import span_attrib, trace_reduce
 
 OUTER = ("grouped_slow", "grouped_fast")
 _done: dict = {}  # capture path -> {scope or "neither": seconds} or None
@@ -83,11 +82,7 @@ def read(ctx, scopes):
     traced = ctx.get("traced")
     if not ctx.get("trace") or not traced or not traced["pods"]:
         return None
-    path = trace_reduce.find_xplane(
-        os.path.join(files.ROOT, ".bench_work", ctx["cell"]["name"], "trace")
-    )
-    if path is None:
-        return None
+    path = ctx["trace"]["xplane"]  # the capture run.py reduced
     if path not in _done:
         try:
             _done[path] = by_outer_scope(path)

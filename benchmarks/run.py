@@ -30,8 +30,15 @@ from benchmarks.lib import (  # noqa: E402
     trace_reduce,
 )
 
-TRACE_START_SHARE = 0.3  # of the window, before the capture starts
-TRACE_SECONDS = 3.0  # length of the profiler capture (at most a third of the window)
+TRACE_START_SHARE = 0.3  # of the window, before the first capture starts
+TRACE_SECONDS = 3.0  # length of a profiler capture (at most a third of the window)
+# the sign that the device worked inside a capture: batches applied
+# between a scrape at its start and one this long before its stop (the
+# stop itself takes seconds in which the loop goes on)
+SOLVES = "scheduler_tpu_solve_batch_size_count"
+SIGN_LEAD_S = 0.2
+# a further capture is asked only while it would stop this long before t1
+RETAKE_MARGIN_S = 1.0
 # a backlog cell's queue is first-in first-out: a pod offered more than
 # this share of the queue's depth ahead of the newest bound one and still
 # undecided at the close is lost
@@ -71,43 +78,134 @@ def sleep_until(t: float) -> None:
         time.sleep(min(wait, 0.05))
 
 
-class Marks(threading.Thread):
-    """Scrapes and profiler requests at fixed times of the window, off
-    the posting thread (its own HTTP connection)."""
+class NoCapture(serve_mod.ServeError):
+    """A --trace 1 run none of whose captures holds device work under
+    its anchor: the run prints no result."""
 
-    def __init__(self, system, t0: float, seconds: float, trace: bool, workdir: str):
+
+class _Mark(threading.Thread):
+    """One thread of the window's marks; what it raises is re-raised by
+    the main thread at ``Marks.finish``."""
+
+    def __init__(self, body):
         super().__init__(daemon=True)
-        self.system, self.t0, self.t1 = system, t0, t0 + seconds
-        self.trace = trace
-        self.trace_dir = os.path.join(workdir, "trace")
-        self.trace_s = min(TRACE_SECONDS, seconds / 3.0)
-        self.t_trace = t0 + TRACE_START_SHARE * seconds
-        self.out: dict = {}
+        self.body = body
         self.error: BaseException | None = None
 
     def run(self) -> None:
         try:
-            sleep_until(self.t0)
-            self.out["log0"] = _log_size(self.system)
-            self.out["m0"] = self.system.scrape()
-            if self.trace:
-                sleep_until(self.t_trace)
-                self.out["trace_on"] = self.system.ask("trace_start", self.trace_dir)
-                sleep_until(time.monotonic() + self.trace_s)
-                self.out["trace_off"] = self.system.ask("trace_stop", timeout=240.0)
-            sleep_until(self.t1)
-            self.out["m1"] = self.system.scrape()
-            self.out["log1"] = _log_size(self.system)
-        except BaseException as e:  # re-raised by the main thread at join
+            self.body()
+        except BaseException as e:
             self.error = e
 
+
+class Marks:
+    """The window's marks, off the posting thread and each kind on a
+    thread (so on an HTTP connection) of its own: the two scrapes at
+    ``t0`` and ``t1``, whatever the profiler is doing, and with
+    ``--trace 1`` the profiler captures."""
+
+    def __init__(self, system, t0: float, seconds: float, trace: bool, workdir: str):
+        self.system, self.t0, self.t1 = system, t0, t0 + seconds
+        self.workdir = workdir
+        self.trace_s = min(TRACE_SECONDS, seconds / 3.0)
+        self.t_trace = t0 + TRACE_START_SHARE * seconds
+        self.out: dict = {}  # log0, m0, m1, log1 and when m0 / m1 were asked and answered
+        self.captures: list = []  # one dict a capture, in the order taken
+        self._threads = [_Mark(self._scrapes)] + ([_Mark(self._captures)] if trace else [])
+
+    def start(self) -> None:
+        for th in self._threads:
+            th.start()
+
+    def _scrapes(self) -> None:
+        sleep_until(self.t0)
+        self.out["log0"] = _log_size(self.system)
+        self._scrape("m0")
+        sleep_until(self.t1)
+        self._scrape("m1")
+        self.out["log1"] = _log_size(self.system)
+
+    def _scrape(self, key: str) -> None:
+        asked = time.monotonic()
+        self.out[key] = self.system.scrape()
+        self.out["t_" + key] = (asked, time.monotonic())
+
+    def _captures(self) -> None:
+        """The first capture is asked at a fixed place of the window; one
+        whose sign says the device did nothing inside it is followed by
+        another, while the window holds one."""
+        sleep_until(self.t_trace)
+        while True:
+            cap: dict = {"dir": os.path.join(self.workdir, f"trace-{len(self.captures)}")}
+            self.captures.append(cap)
+            cap["on"] = self.system.ask("trace_start", cap["dir"])
+            t_stop = time.monotonic() + self.trace_s
+            s_on = self.system.scrape()
+            sleep_until(t_stop - SIGN_LEAD_S)
+            s_off = self.system.scrape()
+            sleep_until(t_stop)
+            cap["off"] = self.system.ask("trace_stop", timeout=240.0)
+            cap["solves_inside"] = (
+                serve_mod.metric_sum(s_off, SOLVES) - serve_mod.metric_sum(s_on, SOLVES)
+            )
+            if (
+                cap["solves_inside"] > 0
+                or self.t1 - time.monotonic() < self.trace_s + RETAKE_MARGIN_S
+            ):
+                return
+
     def finish(self) -> dict:
-        self.join(timeout=300.0)
-        if self.is_alive():
-            raise serve_mod.ServeError("window marks did not finish")
-        if self.error is not None:
-            raise self.error
+        for th in self._threads:
+            th.join(timeout=300.0)
+            if th.is_alive():
+                raise serve_mod.ServeError("window marks did not finish")
+            if th.error is not None:
+                raise th.error
         return self.out
+
+
+def pick_capture(captures: list) -> dict | None:
+    """The reduction of the first capture, in the order taken, that holds
+    a device operation AND the anchor that places it on the host's clock
+    (with ``xplane``, the file it was read from, and ``capture``, which
+    one it was). Each capture looked at is marked ``reduced`` and, where
+    it does not qualify, ``why_not``."""
+    os.environ["JAX_PLATFORMS"] = "cpu"  # the child is gone; read, don't grab
+    for n, cap in enumerate(captures):
+        cap["reduced"] = False
+        if "off" not in cap:
+            cap["why_not"] = "no answer to trace_stop"
+        elif (path := trace_reduce.find_xplane(cap["dir"])) is None:
+            cap["why_not"] = "no *.xplane.pb was written"
+        else:
+            t_r = time.monotonic()
+            trace = trace_reduce.reduce(trace_reduce.load_xplane(path))
+            log(f"trace {os.path.getsize(path)} B reduced in {time.monotonic() - t_r:.1f}s")
+            if trace is None or not trace["busy_s"] > 0:
+                cap["why_not"] = "no device plane holds an operation"
+            elif trace["anchor_ns"] is None:
+                cap["why_not"] = "the anchor event is missing"
+            else:
+                cap["reduced"] = True
+                return {**trace, "xplane": path, "capture": n}
+    return None
+
+
+def _capture_rows(captures: list, t0: float) -> list:
+    """The captures as ``{"info": "window"}`` and a refusal print them."""
+    rows = []
+    for cap in captures:
+        on, off = cap.get("on"), cap.get("off")
+        rows.append({
+            "from_t0": on["t_ask"] - t0 if on else None,
+            "asked_seconds": off["t_ask"] - on["t_on"] if on and off else None,
+            "stop_seconds": off["t_off"] - off["t_ask"] if off else None,
+            "solves_inside": cap.get("solves_inside"),
+            "reduced": cap.get("reduced"),
+            "why_not": cap.get("why_not"),
+        })
+    return rows
 
 
 def drive(args, cell: dict, cfg: dict, system, workdir: str) -> dict:
@@ -230,14 +328,7 @@ def drive(args, cell: dict, cfg: dict, system, workdir: str) -> dict:
         log(f"reference: {note}")
 
     # -- metrics -------------------------------------------------------------
-    trace = None
-    if args.trace and "trace_off" in seen:
-        os.environ["JAX_PLATFORMS"] = "cpu"  # the child is gone; read, don't grab
-        path = trace_reduce.find_xplane(marks.trace_dir)
-        if path:
-            t_r = time.monotonic()
-            trace = trace_reduce.reduce(trace_reduce.load_xplane(path))
-            log(f"trace {os.path.getsize(path)} B reduced in {time.monotonic() - t_r:.1f}s")
+    trace = pick_capture(marks.captures) if args.trace else None
     m0, m1 = seen.get("m0"), seen.get("m1")
 
     def delta(name: str, **labels):
@@ -247,11 +338,12 @@ def drive(args, cell: dict, cfg: dict, system, workdir: str) -> dict:
         return s(m1, name, **labels) - s(m0, name, **labels)
 
     traced = None
-    if trace is not None and trace["anchor_ns"] is not None:
+    if trace is not None:
         # the span of the device's own events, placed on the host's
         # monotonic clock by the wrapper's anchor event; the pods bound
         # inside it, and the solves at the window's solves per pod
-        off = seen["trace_on"]["t_anchor"] - trace["anchor_ns"] / 1e9
+        cap = marks.captures[trace["capture"]]
+        off = cap["on"]["t_anchor"] - trace["anchor_ns"] / 1e9
         lo, hi = trace["lo_ns"] / 1e9 + off, trace["hi_ns"] / 1e9 + off
         pods = sum(1 for t in tail.times if lo <= t < hi)
         solves = delta("scheduler_tpu_solve_batch_size_count")
@@ -261,15 +353,16 @@ def drive(args, cell: dict, cfg: dict, system, workdir: str) -> dict:
                 pods * solves / len(in_window) if solves and in_window else None
             ),
             "seconds": hi - lo,
-            "asked_seconds": seen["trace_off"]["t_ask"] - seen["trace_on"]["t_on"],
+            "asked_seconds": cap["off"]["t_ask"] - cap["on"]["t_on"],
             "from_t0": lo - t0,
+            "capture": trace["capture"],
             "programs": trace["programs"],
         }
     ctx = {
         "cell": cell, "config": cfg, "seconds": seconds, "t0": t0, "t1": t1,
         "setup_s": setup_s, "serve_ready_s": ready_s,
         "m0": m0, "m1": m1, "delta": delta,
-        "trace": trace if traced else None, "traced": traced, "peaks": peaks,
+        "trace": trace, "traced": traced, "peaks": peaks,
         "bound_times": tail.times, "bound_at": bound_at,
         "bound_in_window": len(in_window),
         "posts": offer.posts, "metric_sum": serve_mod.metric_sum,
@@ -281,9 +374,15 @@ def drive(args, cell: dict, cfg: dict, system, workdir: str) -> dict:
         value = files.load_reader(m)(ctx, **m.get("args", {}))
         if value is not None:
             metrics[name] = {"value": value, "unit": m["unit"]}
+    captures = _capture_rows(marks.captures, t0)
     if m0 and m1:
         say(info="window", stage_seconds=_stage_seconds(m0, m1),
             compile=_compile_counts(m0, m1), traced=traced,
+            captures=captures,
+            scrapes={
+                "m0_from_t0": [t - t0 for t in seen["t_m0"]],
+                "m1_from_t1": [t - t1 for t in seen["t_m1"]],
+            },
             posts=len(offer.posts), offered=offer.n_posted, bound=tail.n_bound,
             bound_in_window=len(in_window),
             setup_posted=n_setup_posted,
@@ -301,6 +400,12 @@ def drive(args, cell: dict, cfg: dict, system, workdir: str) -> dict:
             largest_commit_gap_s=max(
                 (b - a for a, b in zip(in_window, in_window[1:])), default=None
             ))
+
+    if args.trace and not rehearse and trace is None:
+        raise NoCapture(
+            "no capture of this --trace 1 run holds device work under its "
+            f"anchor: {json.dumps(captures)}"
+        )
 
     dev = dict(device)
     if not rehearse:

@@ -22,3 +22,18 @@ def mixed_cfg(nodes=None):
     if nodes:
         cfg["nodes"]["count"] = nodes
     return cfg
+
+
+def recorded_slice():
+    """data/trace_slice.json.gz (a recorded slice of a TPU v5e trace, PR
+    23) as ``trace_reduce.load_xplane`` would give it."""
+    import gzip
+    import json
+
+    path = os.path.join(os.path.dirname(os.path.abspath(__file__)), "data", "trace_slice.json.gz")
+    with gzip.open(path, "rt") as f:
+        doc = json.load(f)
+    return {
+        p: {ln: [tuple(e) for e in evs] for ln, evs in lines.items()}
+        for p, lines in doc["planes"].items()
+    }

@@ -3,6 +3,7 @@
 import json
 import os
 import re
+import shutil
 
 import pytest
 
@@ -26,6 +27,77 @@ def line(text):
 
 def test_committed_manifest_is_what_the_files_build(doc):
     assert doc == manifest.build()
+
+
+def test_a_new_file_is_appended_wherever_its_name_sorts(tmp_path, doc):
+    """A later PR may only append: a metric, a cell and a configuration
+    whose names sort first land at the end of their lists, and every
+    committed entry keeps its place."""
+    bench = tmp_path / "benchmarks"
+    shutil.copytree(
+        files.BENCH, bench,
+        ignore=shutil.ignore_patterns("__pycache__", ".pytest_cache", "data"),
+    )
+    shutil.copy(os.path.join(files.ROOT, "BENCHMARK.json"), tmp_path)
+    assert manifest.build(str(bench)) == doc
+    probe = dict(files.load_metrics()["window_compiles.backlog"], name="a_probe.backlog")
+    (bench / "metrics" / "a_probe.backlog.json").write_text(json.dumps(probe))
+    cfg = dict(files.load_config("sched-perf-basic-5000n"), name="a-5000n", source="a probe")
+    (bench / "configs" / "a-5000n.json").write_text(json.dumps(cfg))
+    cell = dict(files.load_workload("basic-5k.backlog"), name="a-5k.backlog", config="a-5000n")
+    (bench / "workloads" / "a-5k.backlog.json").write_text(json.dumps(cell))
+    built = manifest.build(str(bench))
+    want = json.loads(json.dumps(doc))
+    want["per_layer"].append(
+        {k: probe[k] for k in ("name", "unit", "better", "source", "layer", "moves")}
+    )
+    want["configs"].append({
+        "name": "a-5000n", "source": "a probe", "file": "benchmarks/configs/a-5000n.json",
+        "reduced": [], "why": cfg["why"],
+    })
+    want["workloads"].append(
+        {k: cell[k] for k in ("name", "config", "traffic", "chips", "why")}
+    )
+    want["end_to_end"][0]["workloads"].append("a-5k.backlog")
+    assert built == want
+    # with no committed manifest beside it, every list goes by name
+    os.remove(tmp_path / "BENCHMARK.json")
+    by_name = manifest.build(str(bench))
+    for key in ("configs", "workloads", "end_to_end", "per_layer"):
+        names = [e["name"] for e in by_name[key]]
+        assert names == sorted(names) and set(names) == {e["name"] for e in want[key]}
+
+
+def test_the_two_counter_shares_earlier_prs_withdrew(doc):
+    """Data files over the reader metrics/counter_share_pct.py, named
+    without a sorting prefix, owed by the cells in which the program
+    counts something under the counter."""
+    ms = files.load_metrics()
+    new = ("domain_reductions_dense_pct.backlog", "spread_rows_walked_pct.backlog")
+    # appended behind the 26 the manifest had, where later entries leave them
+    assert [m["name"] for m in doc["per_layer"]][26:28] == list(new)
+    for n in new:
+        assert ms[n]["reader"] == "counter_share_pct.py" and ms[n]["unit"] == "%"
+        assert ms[n]["workloads"] == ["spread-5k.backlog", "spread-5k.rollouts"]
+    counters = {
+        ("scheduler_tpu_domain_reductions_total", (("form", "dense"),)): 40.0,
+        ("scheduler_tpu_spread_count_rows_total", (("source", "kept"),)): 30.0,
+        ("scheduler_tpu_spread_count_rows_total", (("source", "walk"),)): 10.0,
+    }
+    ctx = {
+        "m1": counters,
+        "delta": lambda name, **labels: sum(
+            v for (n, ls), v in counters.items()
+            if n == name and set(labels.items()) <= set(ls)
+        ),
+    }
+    read = {n: files.load_reader(ms[n])(ctx, **ms[n]["args"]) for n in new}
+    assert read == {new[0]: pytest.approx(100.0), new[1]: pytest.approx(25.0)}
+    # the basic cell counts nothing under either: no reading, and it owes none
+    ctx["m1"] = {}
+    assert [files.load_reader(ms[n])(ctx, **ms[n]["args"]) for n in new] == [None, None]
+    basic = files.metrics_of_cell(files.load_workload("basic-5k.backlog"), "per_layer")
+    assert not set(new) & set(basic)
 
 
 def test_top_level(doc):

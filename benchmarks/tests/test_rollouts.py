@@ -152,8 +152,8 @@ def read_new(ctx):
     return {n: files.load_reader(ms[n])(ctx, **ms[n].get("args", {})) for n in NEW}
 
 
-def test_the_four_readers_on_a_run_of_the_change(tmp_path, monkeypatch, capsys):
-    ctx = ctx_for(tmp_path, monkeypatch, ops=OPS)
+def test_the_four_readers_on_a_run_of_the_change(tmp_path, capsys):
+    ctx = ctx_for(tmp_path, ops=OPS)
     hist = "scheduler_plugin_execution_duration_seconds_sum"
     spread_pre = (("extension_point", "PreFilter"), ("plugin", "PodTopologySpread"))
     counters = {
@@ -189,9 +189,9 @@ def test_the_four_readers_on_a_run_of_the_change(tmp_path, monkeypatch, capsys):
     assert sum(line["seconds"].values()) == pytest.approx(660e-9)
 
 
-def test_a_cell_that_takes_no_slow_chunk_reads_zero_and_the_parent_none(tmp_path, monkeypatch):
+def test_a_cell_that_takes_no_slow_chunk_reads_zero_and_the_parent_none(tmp_path):
     fast_only = [op for op in OPS if op[1] is None or "grouped_slow" not in op[1]]
-    ctx = ctx_for(tmp_path, monkeypatch, ops=fast_only)
+    ctx = ctx_for(tmp_path, ops=fast_only)
     counters = {
         ("scheduler_tpu_solve_chunks_total", (("kind", "slow"),)): 0.0,
         ("scheduler_tpu_solve_chunks_total", (("kind", "plain"),)): 16.0,
@@ -219,9 +219,12 @@ def test_a_cell_that_takes_no_slow_chunk_reads_zero_and_the_parent_none(tmp_path
     assert read_new(ctx)["y_slow_chunk_us_per_pod.backlog"] is None
 
 
-def test_new_metrics_are_owed_by_every_backlog_cell_and_sort_last():
+def test_new_metrics_are_owed_by_every_backlog_cell():
     ms = files.load_metrics()
-    assert list(ms)[-len(NEW):] == list(NEW)
+    assert {ms[n]["reader"] for n in NEW} == {
+        "counter_share_pct.py", "scope_path_us_per_pod.py", "counter_ratio.py",
+        "labelled_s_per_kpod.py",
+    }
     for n in NEW:
         assert ms[n]["moves"] == "pods_bound_per_s" and "workloads" not in ms[n]
     for cell in files.names("workloads"):

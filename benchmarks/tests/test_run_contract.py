@@ -38,6 +38,29 @@ def test_rehearsal_last_line_has_the_contracts_keys():
     assert out.stderr.strip().splitlines()[-1].startswith("compared ")
 
 
+def test_traced_last_line_has_the_device_share(tmp_path, capsys):
+    """The traced half: ``device`` has ``window_s`` and ``busy_s`` (above
+    0, at most ``window_s``) and the line a ``breakdown``; against the
+    stand-in handing out a recorded capture, through drive() as a
+    measured run makes it (tests/test_marks.py has the refusals)."""
+    from test_marks import GOOD, drive
+
+    line, _, _ = drive(tmp_path, capsys, 1, 1.0, captures=[GOOD])
+    assert list(line) == [
+        "correct", "attempted", "failed", "metrics", "device", "breakdown", "compared",
+    ]
+    dev = line["device"]
+    assert set(dev) == {
+        "platform", "kind", "count", "memory_peak_bytes", "busy_s", "window_s",
+    }
+    assert dev["platform"] == "tpu" and 0 < dev["busy_s"] <= dev["window_s"]
+    assert set(line["breakdown"]) == {"device_ops", "idle_gaps"}
+    assert all(len(rows) <= 10 for rows in line["breakdown"].values())
+    for m in line["metrics"].values():
+        assert set(m) == {"value", "unit"}
+    assert "pods_bound_per_s" not in line["metrics"]  # --trace 1: the per-layer metrics
+
+
 def test_without_rehearse_flag_a_non_tpu_device_gives_no_result():
     env = dict(os.environ)
     env.pop("JAX_PLATFORMS", None)  # the child is given JAX_PLATFORMS=tpu anyway

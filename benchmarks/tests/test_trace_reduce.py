@@ -3,13 +3,13 @@ per-program device time: on a case worked by hand, and on a small
 recorded slice of a TPU v5e trace (data/trace_slice.json.gz: a few
 programs of a mixed-5k.backlog run, PR 23) against a second method."""
 
-import gzip
 import json
 import os
 
 import pytest
 
 from benchmarks.lib import trace_reduce as tr
+from conftest import recorded_slice
 
 DATA = os.path.join(os.path.dirname(os.path.abspath(__file__)), "data")
 
@@ -65,12 +65,7 @@ def sweep_busy(intervals):
 
 
 def test_recorded_slice():
-    with gzip.open(os.path.join(DATA, "trace_slice.json.gz"), "rt") as f:
-        doc = json.load(f)
-    planes = {
-        p: {ln: [tuple(e) for e in evs] for ln, evs in lines.items()}
-        for p, lines in doc["planes"].items()
-    }
+    planes = recorded_slice()
     got = tr.reduce(planes)
     ops = planes["/device:TPU:0"]["XLA Ops"]
     mods = planes["/device:TPU:0"]["XLA Modules"]
