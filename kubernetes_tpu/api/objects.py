@@ -728,6 +728,67 @@ class Pod:
         return {"apiVersion": "v1", "kind": "Pod", "metadata": meta, "spec": spec, "status": status}
 
 
+class PodDecoder:
+    """``Pod.from_dict`` for a stream of manifests, with one parse per
+    distinct pod spec: a Deployment's replicas arrive with equal
+    ``spec`` and ``status`` objects and differ in ``metadata``.
+
+    Keeps the ``KEEP`` most recently seen (spec, status, parsed fields),
+    newest first. A manifest whose spec and status compare equal (``==``
+    on the decoded JSON; ``1 == 1.0 == True`` there, and every reader of
+    such a field takes its number) to an entry gets that entry's parsed
+    fields and its own metadata; any other goes through
+    ``Pod.from_dict`` and becomes the newest entry. Everything a Pod takes from ``spec`` is a
+    scalar or a tuple of frozen dataclasses and is shared; the dict
+    fields are copied per pod, and the memoized requests start None.
+    Not thread-safe: one decoder per writer thread."""
+
+    KEEP = 16
+    # what from_dict reads from ``metadata``: the rest is the template
+    _META = ("name", "namespace", "uid", "labels", "annotations", "resource_version")
+
+    def __init__(self) -> None:
+        self._recent: list[tuple[Mapping, Mapping, dict]] = []
+        self.reused = 0
+        self.parsed = 0
+
+    def decode(self, d: Mapping) -> Pod:
+        spec = d.get("spec") or {}
+        status = d.get("status") or {}
+        recent = self._recent
+        for i, (seen_spec, seen_status, fields) in enumerate(recent):
+            if seen_spec == spec and seen_status == status:
+                if i:
+                    recent.insert(0, recent.pop(i))
+                break
+        else:
+            pod = Pod.from_dict(d)
+            # snapshot now: a bind writes node_name on the live pod
+            fields = dict(vars(pod))
+            for name in self._META:
+                del fields[name]
+            fields["node_selector"] = dict(pod.node_selector)
+            fields["overhead"] = dict(pod.overhead)
+            recent.insert(0, (spec, status, fields))
+            del recent[self.KEEP:]
+            self.parsed += 1
+            return pod
+        meta = d.get("metadata") or {}
+        pod = Pod.__new__(Pod)  # no __post_init__: the fields are the state
+        own = vars(pod)
+        own.update(fields)
+        own["name"] = meta.get("name") or ""
+        own["namespace"] = meta.get("namespace") or "default"
+        own["uid"] = meta.get("uid") or ""
+        own["labels"] = dict(meta.get("labels") or {})
+        own["annotations"] = dict(meta.get("annotations") or {})
+        own["resource_version"] = int(meta.get("resourceVersion") or 0)
+        own["node_selector"] = dict(fields["node_selector"])
+        own["overhead"] = dict(fields["overhead"])
+        self.reused += 1
+        return pod
+
+
 # ---------------------------------------------------------------------------
 # PersistentVolume / PersistentVolumeClaim — the slice the volume plugins
 # read ([BOUNDARY], SURVEY.md §3.2: static F-stage checks; dynamic

@@ -742,6 +742,16 @@ ingest_pods_total = Counter(
     "Pods applied through POST /api/pods.",
     registry=REGISTRY,
 )
+ingest_pod_specs_total = Counter(
+    "scheduler_ingest_pod_specs_total",
+    "Pods decoded by POST /api/pods, by how their spec was decoded: "
+    "reused = spec and status compared equal to one of the 16 most "
+    "recently parsed (a Deployment's replicas), so the pod took that "
+    "parse's fields and its own metadata; parsed = through "
+    "Pod.from_dict. Incremented once a body.",
+    ["decode"],
+    registry=REGISTRY,
+)
 
 # -- flight telemetry (kubernetes_tpu/obs/{profile,sentinel,bundle}) --
 

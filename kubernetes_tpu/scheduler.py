@@ -535,9 +535,10 @@ class Scheduler:
             if self.config.obs is not None
             else 1
         )
-        # the commit pass (_commit_all): True inside a held run, where
-        # the only watch events are the pass's own binds and the
-        # pending gauge is refreshed once, at the run's end
+        # True inside a held run (held_run: the commit pass's, or the
+        # ingest handler's): whoever holds cluster.lock for the run
+        # owns it, the watch events inside are the run's own, and the
+        # pending gauge is refreshed once, at its end
         self._in_held_run = False  # ktpu: guarded-by(cluster.lock)
         # labels() is a lock and a dict walk: the histogram children
         # the pass observes per pod are looked up once each, at first
@@ -1639,7 +1640,7 @@ class Scheduler:
         ):
             for held, run in itertools.groupby(pending, key=wire_free):
                 n_run = 0
-                with self._held_run() if held else _NOOP_SPAN:
+                with self.held_run() if held else _NOOP_SPAN:
                     for entry in run:
                         n_run += 1
                         err = self._commit_entry(entry, res, binders, held)
@@ -1711,10 +1712,14 @@ class Scheduler:
         )
 
     @contextlib.contextmanager
-    def _held_run(self):
-        """One hold of cluster.lock around a run of wire-free commits.
-        grant_fence and revoke_fence take the same lock, so the fence
-        token every bind of the run is checked against cannot change
+    def held_run(self):
+        """One hold of cluster.lock around a run of per-pod writes whose
+        watch events are the run's own: the commit pass's wire-free
+        binds (_commit_all), or a POST body's upserts on the server's
+        thread (server/extender.py post_pods). Every pod still gets its
+        own event; the lock take and the pending gauge's refresh happen
+        once. grant_fence and revoke_fence take the same lock, so the
+        fence token every bind of a run is checked against cannot change
         inside the hold: a revoke waits for the run's end and refuses
         the NEXT run whole. The per-pod code re-enters the RLock (an
         owner check, no futex)."""
@@ -1724,8 +1729,8 @@ class Scheduler:
                 yield
             finally:
                 self._in_held_run = False
-                # the run's own bind events skipped this (_on_event);
-                # requeues of failed binds moved pods between queues
+                # the run's own events skipped this (_on_event): pods
+                # entered the queue, or failed binds were requeued
                 self._refresh_pending_gauge()
 
     def _commit_entry(

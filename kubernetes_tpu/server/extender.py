@@ -46,13 +46,14 @@ accepts/returns bare node names resolved against that state.
 
 from __future__ import annotations
 
+import contextlib
 import json
 import time
 from typing import Mapping, Sequence
 
 import numpy as np
 
-from ..api.objects import Node, Pod
+from ..api.objects import Node, Pod, PodDecoder
 from ..ops.oracle import preemption as opr
 from ..ops.oracle.profile import FullOracle, make_oracle_nodes
 from ..state.cluster import ApiError, ClusterState
@@ -680,6 +681,15 @@ def make_app(
             return web.json_response({"error": e.reason}, status=404)
         return web.json_response({})
 
+    # this app's handlers run on one event-loop thread, and post_pods
+    # touches the decoder between awaits only: no other thread sees it
+    pod_decoder = PodDecoder()
+    held_run = (
+        scheduler.held_run if scheduler is not None else contextlib.nullcontext
+    )
+    ingest_specs_reused = metrics.ingest_pod_specs_total.labels("reused")
+    ingest_specs_parsed = metrics.ingest_pod_specs_total.labels("parsed")
+
     async def post_pods(request):
         body = await request.read()
         # parse + apply, timed from the server's side (a client's round
@@ -693,16 +703,17 @@ def make_app(
             else None
         )
         t0 = time.perf_counter()
-        created = 0
-        for pd in _items(json.loads(body)):
-            pod = Pod.from_dict(pd)
-            try:
-                core.cluster.create_pod(pod)
-            except ApiError:
-                core.cluster.update_pod(pod)
-            created += 1
+        # a body is a batch: decoded with no lock held, one parse per
+        # distinct spec, then applied under one hold of cluster.lock
+        reused, parsed = pod_decoder.reused, pod_decoder.parsed
+        pods = [pod_decoder.decode(pd) for pd in _items(json.loads(body))]
+        with held_run():
+            core.cluster.create_pods(pods)
+        created = len(pods)
         metrics.ingest_seconds_total.inc(time.perf_counter() - t0)
         metrics.ingest_pods_total.inc(created)
+        ingest_specs_reused.inc(pod_decoder.reused - reused)
+        ingest_specs_parsed.inc(pod_decoder.parsed - parsed)
         if ann is not None:
             ann.set_metadata(pods=created)
             ann.__exit__(None, None, None)

@@ -772,15 +772,21 @@ class ClusterState:
 
         return [dataclasses.replace(le) for le in self._leases.values()]
 
-    # -- bulk helpers for benchmarks --
+    # -- bulk helpers: one hold of the lock around the loop --
 
     def create_nodes(self, nodes: Iterable[Node]) -> None:
         for n in nodes:
             self.create_node(n)
 
     def create_pods(self, pods: Iterable[Pod]) -> None:
+        """The ingest route's upsert of a POST body, under ONE hold of
+        the lock (``_locked``): per pod its own resourceVersion and its
+        own event, in order; a pod that already exists is updated, alone."""
         for p in pods:
-            self.create_pod(p)
+            try:
+                self.create_pod(p)
+            except ApiError:
+                self.update_pod(p)
 
     # -- events (events.k8s.io/v1 subset; SURVEY §6.5 events row) --
 

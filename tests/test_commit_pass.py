@@ -549,3 +549,38 @@ def test_held_runs_against_many_ingest_threads():
     assert path_delta(before) == {"held": total, "wire": 0}
     assert not s._in_held_run and not s._in_flight
     assert sum(s.queue.pending_counts().values()) == 0
+
+
+@pytest.mark.parametrize("pods", [1, 64])
+@pytest.mark.parametrize("with_scheduler", [True, False])
+def test_a_posted_body_takes_the_lock_once(with_scheduler, pods, monkeypatch):
+    """POST /api/pods applies a body under one hold (PR 35): the same
+    number of takes for 64 pods as for one, a pod that exists included."""
+    import asyncio
+
+    from aiohttp.test_utils import TestClient, TestServer
+
+    from kubernetes_tpu.server.extender import ExtenderCore, make_app
+
+    _clock, cs, s = build(nodes=2)
+    # the app's drain loop polls `pending` under the same lock: it
+    # reads 0 here without it, so every take counted is the handler's
+    monkeypatch.setattr(Scheduler, "pending", property(lambda self: 0))
+    app = make_app(
+        ExtenderCore(cs, backend="oracle"), scheduler=s if with_scheduler else None
+    )
+    cs.create_pod(plain("p0"))
+    body = {"items": [plain(f"p{i}").to_dict() for i in range(pods)]}
+    takes = []
+
+    async def go():
+        async with TestClient(TestServer(app)) as client:
+            before = cs.lock.outermost
+            resp = await client.post("/api/pods", json=body)
+            takes.append(cs.lock.outermost - before)
+            assert await resp.json() == {"applied": pods}
+
+    asyncio.run(go())
+    assert takes == [1]
+    assert len(cs.list_pods()) == pods
+    assert not s._in_held_run
