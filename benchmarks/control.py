@@ -31,6 +31,9 @@ from benchmarks.lib import files, standin  # noqa: E402
 
 PACE_PODS_PER_S = 2000.0  # a closed loop needs a pace
 SOUND_FIRST = 90  # pods decided soundly before the fault starts
+# the stand-in publishes a batch every few seconds at most while it holds
+# pods; a fault that leaves pods undecided stops the loop before t1
+BATCH_WAIT_S = 10.0
 
 
 def main(argv=None, trace: int = 0) -> int:
@@ -59,7 +62,9 @@ def main(argv=None, trace: int = 0) -> int:
         seed=args.seed, seconds=args.seconds, trace=trace, rehearse_cpu=True
     )
     try:
-        line = bench_run.drive(run_args, cell, cfg, system, workdir)
+        line = bench_run.drive(
+            run_args, cell, cfg, system, workdir, batch_wait_s=BATCH_WAIT_S
+        )
     finally:
         system.close()
     line["control"] = args.fault

@@ -1,8 +1,9 @@
 """Reader of the decision journal the child streams (``serve
 --obs-journal``): one JSON object per line, flushed per record, each
-``dec`` record stamped ``t = time.monotonic()`` at the bind commit.
-CLOCK_MONOTONIC is one clock for every process on the machine, so the
-generator's own ``time.monotonic()`` stamps subtract from it."""
+``dec`` record stamped ``t = time.monotonic()`` at the bind commit and
+``step``, the id of the popped batch it came from. CLOCK_MONOTONIC is
+one clock for every process on the machine, so the generator's own
+``time.monotonic()`` stamps subtract from it."""
 
 from __future__ import annotations
 
@@ -20,6 +21,7 @@ class JournalTail:
         self.keys: list[str] = []  # pod key of each bound record
         self.nodes: list[str] = []
         self.times: list[float] = []
+        self.steps: list[int] = []  # the batch each bound record came from
         self.other: list[dict] = []  # every decision that is not "bound"
 
     @property
@@ -44,10 +46,25 @@ class JournalTail:
             rec = json.loads(line)
             if rec.get("k") != "dec":
                 continue
+            if "step" not in rec:
+                raise ValueError(f"{self.path}: a decision without its batch's step: {rec}")
             if rec["outcome"] == "bound":
                 self.keys.append(rec["pod"])
                 self.nodes.append(rec["node"])
                 self.times.append(rec["t"])
+                self.steps.append(rec["step"])
             else:
                 self.other.append(rec)
         return len(self.keys)
+
+
+def batch_done_after(times: list, steps: list, t: float) -> bool:
+    """Whether the batch of the first bound record stamped at or after
+    ``t`` is complete: a record of a later batch follows it (the program
+    commits its batches in order). Looks back from the newest record."""
+    first = None
+    for i in range(len(times) - 1, -1, -1):
+        if times[i] < t:
+            break
+        first = i
+    return first is not None and any(s > steps[first] for s in steps[first + 1:])

@@ -57,10 +57,10 @@ def wait_bound(serve, tail, target: int, timeout: float) -> None:
 def backlog(
     offer: Offer, tail, stream, depth: int, chunk: int,
     stop_at: float | None = None, until_bound: int | None = None,
-    max_offered: int | None = None, timeout: float = 1500.0,
+    until=None, max_offered: int | None = None, timeout: float = 1500.0,
 ) -> None:
-    """Keep posted - bound >= depth until ``stop_at`` (monotonic) or until
-    ``until_bound`` pods are bound."""
+    """Keep posted - bound >= depth until ``stop_at`` (monotonic), until
+    ``until_bound`` pods are bound, or until ``until()`` holds."""
     deadline = time.monotonic() + timeout
     while True:
         bound = tail.poll()
@@ -68,6 +68,8 @@ def backlog(
         if stop_at is not None and now >= stop_at:
             return
         if until_bound is not None and bound >= until_bound:
+            return
+        if until is not None and until():
             return
         if now > deadline:
             raise TimeoutError(f"backlog loop: {bound} bound after {timeout}s")

@@ -95,7 +95,8 @@ class StandIn:
         self._queue: queue.Queue = queue.Queue()
         self._stop = threading.Event()
         self._solves = 0
-        self._step = 0
+        self._step = 0  # batches published, as the program's ``step``
+        self._decided = 0  # pods decided
         self._file = open(self.journal, "w")
         self._worker = threading.Thread(target=self._run, daemon=True)
         self._worker.start()
@@ -115,7 +116,7 @@ class StandIn:
                     break
             t_batch = time.monotonic()
             n_in = len(batch)
-            fault = self.fault if self._step >= self.fault_after else None
+            fault = self.fault if self._decided >= self.fault_after else None
             if fault == "drop_half":
                 batch = batch[::2]  # the other half is never looked at
             self._sched.carry = fault != "stale_state"
@@ -130,8 +131,9 @@ class StandIn:
 
     def _publish(self, placed: list) -> None:
         self._solves += 1
+        self._step += 1
+        self._decided += len(placed)
         for spec, node in placed:
-            self._step += 1
             rec = {
                 "k": "dec", "step": self._step, "pod": spec.key,
                 "outcome": "bound" if node else "unschedulable",
@@ -173,7 +175,7 @@ class StandIn:
              (("device_kind", self.device_kind), ("platform", self.platform))): 1.0,
             ("scheduler_tpu_host_to_device_bytes_total", ()): float(self._solves),
             ("scheduler_tpu_solve_batch_size_count", ()): float(self._solves),
-            ("scheduler_tpu_solve_batch_size_sum", ()): float(self._step),
+            ("scheduler_tpu_solve_batch_size_sum", ()): float(self._decided),
         }
 
     def ask(self, *words, timeout: float = 0.0) -> dict:

@@ -39,6 +39,12 @@ SOLVES = "scheduler_tpu_solve_batch_size_count"
 SIGN_LEAD_S = 0.2
 # a further capture is asked only while it would stop this long before t1
 RETAKE_MARGIN_S = 1.0
+# after t1 the loop goes on until a pod of the batch after the one that
+# straddles t1 is bound, so that batch's end is known: two batches at most,
+# ~4 s in the slowest cell read (a 1,024-pod batch every 2.06 s, PR 36), and
+# a build inside a window has held the loop 21.6 s (PR 32). A run that waits
+# this long has no pods_bound_per_s (its reader says why), never the count.
+BATCH_AFTER_T1_WAIT_S = 60.0
 # a backlog cell's queue is first-in first-out: a pod offered more than
 # this share of the queue's depth ahead of the newest bound one and still
 # undecided at the close is lost
@@ -208,7 +214,10 @@ def _capture_rows(captures: list, t0: float) -> list:
     return rows
 
 
-def drive(args, cell: dict, cfg: dict, system, workdir: str) -> dict:
+def drive(
+    args, cell: dict, cfg: dict, system, workdir: str,
+    batch_wait_s: float = BATCH_AFTER_T1_WAIT_S,
+) -> dict:
     """Everything of a run but the look for a chip and the spawn:
     ``system`` is the served scheduler (``lib.serve.Serve``, or a
     stand-in in control.py and the tests). Returns the result line."""
@@ -265,8 +274,16 @@ def drive(args, cell: dict, cfg: dict, system, workdir: str) -> dict:
     sleep_until(t1)
 
     # -- after the close: late answers are late, not wrong -------------------
-    time.sleep(0.3)  # the records of the batch that straddles t1
-    tail.poll()
+    def t1_batch_done() -> bool:
+        return journal.batch_done_after(tail.times, tail.steps, t1)
+
+    loops.backlog(
+        offer, tail, stream, depth, chunk, stop_at=time.monotonic() + batch_wait_s,
+        until=t1_batch_done, max_offered=max_offered,
+    )
+    waited_s = time.monotonic() - t1
+    if not t1_batch_done():
+        log(f"no pod of a batch after the one at t1 was bound in {batch_wait_s:.0f}s")
     child_alive = system.alive()
     seen = marks.finish() if child_alive else dict(marks.out)
     m_end = system.scrape() if child_alive else seen.get("m1", {})
@@ -363,7 +380,7 @@ def drive(args, cell: dict, cfg: dict, system, workdir: str) -> dict:
         "setup_s": setup_s, "serve_ready_s": ready_s,
         "m0": m0, "m1": m1, "delta": delta,
         "trace": trace, "traced": traced, "peaks": peaks,
-        "bound_times": tail.times, "bound_at": bound_at,
+        "bound_times": tail.times, "bound_steps": tail.steps, "bound_at": bound_at,
         "bound_in_window": len(in_window),
         "posts": offer.posts, "metric_sum": serve_mod.metric_sum,
         "solve_work": solve_work,
@@ -385,6 +402,11 @@ def drive(args, cell: dict, cfg: dict, system, workdir: str) -> dict:
             },
             posts=len(offer.posts), offered=offer.n_posted, bound=tail.n_bound,
             bound_in_window=len(in_window),
+            window_count_per_s=len(in_window) / seconds,
+            waited_after_t1_s=waited_s,
+            bound_steps_out_of_order=sum(
+                1 for a, b in zip(tail.steps, tail.steps[1:]) if b < a
+            ),
             setup_posted=n_setup_posted,
             other_decisions=[
                 {k: r.get(k) for k in ("pod", "outcome", "reason", "t")}
