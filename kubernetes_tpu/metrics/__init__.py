@@ -332,6 +332,27 @@ domain_reductions_total = Counter(
     ["form"],
     registry=REGISTRY,
 )
+interpod_terms_total = Counter(
+    "scheduler_tpu_interpod_terms_total",
+    "InterPodAffinity term instances carried by ExactSolver.solve calls, "
+    "by side: incoming = the batch pod classes' own terms "
+    "(InterpodTensors.num_in), existing = the deduplicated terms owned by "
+    "placed, nominated and batch pods (num_ex; the axis padded to "
+    "te_pad). Same increments as ExactSolver.dispatch_counts; over "
+    "scheduler_tpu_solves_total it is the terms one batch carries.",
+    ["side"],
+    registry=REGISTRY,
+)
+interpod_placed_visits_total = Counter(
+    "scheduler_tpu_interpod_placed_visits_total",
+    "Placed and nominated pods that build_interpod_tensors went over for "
+    "the scheduler's batches, once for each pass it made over them: the "
+    "owner-term pass plus one pass per incoming term, (1 + incoming "
+    "terms) x placed pods a call, added once a call. A call for a caller "
+    "with no scheduler cache (the extender, solver/evaluate.py) is not "
+    "counted.",
+    registry=REGISTRY,
+)
 spread_count_rows_total = Counter(
     "scheduler_tpu_spread_count_rows_total",
     "Rows of SpreadTensors.cnt0 (matching placed pods per node, one row "

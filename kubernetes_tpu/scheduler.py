@@ -2847,6 +2847,7 @@ class Scheduler:
                     placed_by_slot, batch.padded, static.c_pad,
                     hard_pod_affinity_weight=solver.config.hard_pod_affinity_weight,
                     nominated=nom_peers,
+                    visits=metrics.interpod_placed_visits_total,
                 )
 
             # nominated-pod load (RunFilterPluginsWithNominatedPods analog):

@@ -1630,6 +1630,8 @@ _TALLY_SERIES = {
     "kind2": metrics.solve_chunks_total.labels("spread"),
     "kind3": metrics.solve_chunks_total.labels("anti"),
     "spread_instances": metrics.spread_instances_total,
+    "interpod_incoming": metrics.interpod_terms_total.labels("incoming"),
+    "interpod_existing": metrics.interpod_terms_total.labels("existing"),
     "domains_dense": metrics.domain_reductions_total.labels("dense"),
     "domains_scatter": metrics.domain_reductions_total.labels("scatter"),
     "class_table_uploads": metrics.class_table_uploads_total,
@@ -1668,7 +1670,8 @@ class ExactSolver:
         # grouped chunks that hold a pod by the _chunk_kinds dispatch
         # (0 slow replay / 1 plain / 2 spread quota / 3 anti quota),
         # "padding" those that hold none, "domains_dense|scatter" the
-        # domain tables by the form ops/domains.py reduces them in.
+        # domain tables by the form ops/domains.py reduces them in,
+        # "interpod_incoming|existing" the inter-pod terms a solve carries.
         # _tally is the one increment;
         # /metrics exports the same counts (_TALLY_SERIES). Benchmarks
         # report THIS instead of asserting which path a workload takes
@@ -2191,6 +2194,8 @@ class ExactSolver:
             kinds_host = None
             self._tally("scan")
         self._tally("spread_instances", int(spread.num_instances))
+        self._tally("interpod_incoming", int(interpod.num_in))
+        self._tally("interpod_existing", int(interpod.num_ex))
         # the domain tables whose reductions go through ops/domains.py:
         # the spread table's in every program, the inter-pod table's only
         # in the grouped program's anti branch (the per-pod step counts

@@ -160,12 +160,18 @@ def build_interpod_tensors(
     c_pad: int,
     hard_pod_affinity_weight: int = 1,
     nominated: Sequence[tuple[Pod, int]] = (),
+    visits=None,
 ) -> InterpodTensors:
     """``nominated`` carries (pod, node slot) pairs for unbound pods whose
     ``status.nominatedNodeName`` resolved to a live slot: they fold into
     ``in_cnt0`` and ``ex_cnt0`` exactly like placed pods (the
     RunFilterPluginsWithNominatedPods convention), so both the incoming
-    terms and the symmetry direction see a nominated peer at its slot."""
+    terms and the symmetry direction see a nominated peer at its slot.
+
+    ``visits`` (a counter with ``inc``, the scheduler's
+    ``metrics.interpod_placed_visits_total``) is given, once a call, the
+    placed and nominated pods walked by every pass over them: the
+    owner-term pass and one pass per incoming term."""
     # ---- incoming terms per class ----
     in_terms: list[tuple[int, PodAffinityTerm, int, int]] = []  # (cls, term, kind, w)
     per_class: list[tuple[list[int], list[int], list[int]]] = []
@@ -206,6 +212,8 @@ def build_interpod_tensors(
     placed_pods += [
         (n_i, p) for p, n_i in nominated if 0 <= n_i < padded_n
     ]
+    if visits is not None:
+        visits.inc((1 + len(in_terms)) * len(placed_pods))
     owner_map_placed: list[tuple[int, int]] = []  # (slot, ex_id)
     for slot, p in placed_pods:
         for kind, t, w in _ex_terms_of(p):
