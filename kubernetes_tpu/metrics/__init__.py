@@ -337,20 +337,37 @@ interpod_terms_total = Counter(
     "InterPodAffinity term instances carried by ExactSolver.solve calls, "
     "by side: incoming = the batch pod classes' own terms "
     "(InterpodTensors.num_in), existing = the deduplicated terms owned by "
-    "placed, nominated and batch pods (num_ex; the axis padded to "
-    "te_pad). Same increments as ExactSolver.dispatch_counts; over "
-    "scheduler_tpu_solves_total it is the terms one batch carries.",
+    "placed, nominated and batch pods that select at least one pod of the "
+    "batch (num_ex; the axis padded to te_pad). Same increments as "
+    "ExactSolver.dispatch_counts; over scheduler_tpu_solves_total it is "
+    "the terms one batch carries.",
     ["side"],
     registry=REGISTRY,
 )
 interpod_placed_visits_total = Counter(
     "scheduler_tpu_interpod_placed_visits_total",
-    "Placed and nominated pods that build_interpod_tensors went over for "
-    "the scheduler's batches, once for each pass it made over them: the "
-    "owner-term pass plus one pass per incoming term, (1 + incoming "
-    "terms) x placed pods a call, added once a call. A call for a caller "
-    "with no scheduler cache (the extender, solver/evaluate.py) is not "
-    "counted.",
+    "Placed pods that build_interpod_tensors walked for the scheduler's "
+    "batches, once for each pass over them: the one pass that counts the "
+    "selectors of incoming terms new to the scheduler cache's "
+    "per-selector node counts, and the one pass for terms those counts "
+    "cannot serve (a namespaceSelector). A batch whose selectors are all "
+    "tracked walks none. A call for a caller with no scheduler cache "
+    "(the extender, solver/evaluate.py) is not counted.",
+    registry=REGISTRY,
+)
+interpod_count_rows_total = Counter(
+    "scheduler_tpu_interpod_count_rows_total",
+    "Rows of InterpodTensors.in_cnt0 (placed pods per node that an "
+    "incoming inter-pod term selects, one row per (namespace, selector) "
+    "the term asks about) handed to a batch, by where the counts came "
+    "from: kept = the scheduler cache's per-selector node counts "
+    "(SchedulerCache.spread_counts) already tracked the selector, walk = "
+    "it was counted then, by a pass over the placed pods (one pass for "
+    "all the new selectors of a batch), or the term cannot be served by "
+    "those counts (a namespaceSelector) and was counted by a pass of its "
+    "own. An index built on the spot for a caller with no cache (the "
+    "extender, solver/evaluate.py) is not counted.",
+    ["source"],
     registry=REGISTRY,
 )
 spread_count_rows_total = Counter(

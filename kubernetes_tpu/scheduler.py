@@ -2795,16 +2795,10 @@ class Scheduler:
                 added_affinity=solver.config.added_affinity,
                 class_key_extra=class_key_extra,
             )
-            placed_by_slot: dict[int, list[Pod]] = {}
-            if need_ports or need_interpod:
-                for slot, name in enumerate(self.snapshot.names):
-                    info = self.cache.nodes.get(name) if name else None
-                    if info is not None and info.node is not None and info.pods:
-                        placed_by_slot[slot] = list(info.pods.values())
             if need_ports:
                 ports = _timed(
                     "NodePorts", build_port_tensors,
-                    pods, pbatch, slot_nodes, placed_by_slot, batch.padded,
+                    pods, pbatch, slot_nodes, self._placed_by_slot(), batch.padded,
                     nominated=nom_pairs,
                     # occupancy staging reuse: valid while the cache is
                     # byte-unchanged since the staged scan (any watch
@@ -2844,10 +2838,13 @@ class Scheduler:
                 interpod = _timed(
                     "InterPodAffinity", build_interpod_tensors,
                     pods, static.reps, pbatch, slot_nodes,
-                    placed_by_slot, batch.padded, static.c_pad,
+                    {}, batch.padded, static.c_pad,
                     hard_pod_affinity_weight=solver.config.hard_pod_affinity_weight,
                     nominated=nom_peers,
                     visits=metrics.interpod_placed_visits_total,
+                    counts=self.cache.spread_counts,
+                    owners=self.cache.interpod_owners,
+                    slot_of=self.snapshot.slots,
                 )
 
             # nominated-pod load (RunFilterPluginsWithNominatedPods analog):

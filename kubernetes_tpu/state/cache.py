@@ -24,6 +24,7 @@ from dataclasses import dataclass, field
 from .. import metrics
 from ..api.objects import Node, Pod
 from ..utils.clock import Clock
+from .interpod_owners import OwnerTerms
 from .spread_counts import SpreadCounts
 
 
@@ -88,11 +89,18 @@ class SchedulerCache:
         # where each cached pod currently lives (node name), incl. assumed
         self._pod_node: dict[str, str] = {}
         # matching pods per node NAME for the selectors spread constraints
-        # ask about; it follows HostNodeInfo.pods of the nodes whose
-        # ``node`` is set, under the lock that guards ``nodes``
+        # and incoming inter-pod terms ask about, and the owners per node
+        # NAME of every inter-pod term a pod owns; both follow
+        # HostNodeInfo.pods of the nodes whose ``node`` is set, under the
+        # lock that guards ``nodes``
         self.spread_counts = SpreadCounts(  # ktpu: guarded-by(cluster.lock)
-            self._live_pods, metrics.spread_count_rows_total
+            self._live_pods,
+            {
+                "spread": metrics.spread_count_rows_total,
+                "interpod": metrics.interpod_count_rows_total,
+            },
         )
+        self.interpod_owners = OwnerTerms()  # ktpu: guarded-by(cluster.lock)
 
     # -- generation --
 
@@ -242,10 +250,12 @@ class SchedulerCache:
         if info.node is None and not info.pods:
             del self.nodes[name]
 
-    # -- per-selector node counts (state/spread_counts.py) --
+    # -- per-selector node counts (state/spread_counts.py) and inter-pod
+    # term owners (state/interpod_owners.py) --
 
     def _live_pods(self):
-        """(node name, its pods) of every node a spread constraint counts."""
+        """(node name, its pods) of every node a spread constraint or an
+        inter-pod term counts."""
         for name, info in self.nodes.items():
             if info.node is not None:
                 yield name, info.pods.values()
@@ -253,10 +263,16 @@ class SchedulerCache:
     # every mutator runs under the cluster lock: ktpu: holds(cluster.lock)
     def _counted(self, pod: Pod, node_name: str) -> None:
         self.spread_counts.pod_added(pod, node_name)
+        aff = pod.affinity
+        if aff and (aff.pod_affinity or aff.pod_anti_affinity):
+            self.interpod_owners.pod_added(pod, node_name)
 
     # every mutator runs under the cluster lock: ktpu: holds(cluster.lock)
     def _uncounted(self, pod: Pod, node_name: str) -> None:
         self.spread_counts.pod_removed(pod, node_name)
+        aff = pod.affinity
+        if aff and (aff.pod_affinity or aff.pod_anti_affinity):
+            self.interpod_owners.pod_removed(pod, node_name)
 
     def add_node(self, node: Node) -> None:
         info = self.nodes.get(node.name)

@@ -8,6 +8,8 @@ counts themselves change only where a pod enters or leaves a node. So the
 scheduler cache keeps them (``SchedulerCache.spread_counts``), and a caller
 with no cache behind its pod lists (solver/evaluate.py, the extender
 webhook) builds the same index on the spot and throws it away.
+InterPodAffinity asks the same question of an incoming term ("pods of
+namespace ns matching selector S, per node") and reads the same counts.
 
 A pod is checked only against the selectors that can match it: each
 selector is filed under one label pair it requires (a ``matchLabels`` entry,
@@ -19,7 +21,7 @@ selector that requires no such pair (only ``Exists`` / ``NotIn`` /
 from __future__ import annotations
 
 from dataclasses import dataclass, field
-from typing import Callable, Hashable, Iterable, Mapping, Sequence
+from typing import Callable, Collection, Hashable, Iterable, Mapping, Sequence
 
 import numpy as np
 
@@ -51,7 +53,8 @@ class _Kept:
 
 class SelectorDispatch:
     """(namespace, selector, item) triples, filed so that ``matching(pod)``
-    looks only at the selectors that can match the pod."""
+    looks only at the selectors that can match the pod. A None namespace
+    matches a pod of any namespace."""
 
     def __init__(self) -> None:
         # label key -> label value -> [(namespace, selector, item)]
@@ -96,7 +99,7 @@ class SelectorDispatch:
     def _matched(filed: list, pod: Pod, out: list | None) -> list | None:
         namespace, labels = pod.namespace, pod.labels
         for ns, selector, item in filed:
-            if ns == namespace and selector.matches(labels):
+            if (ns == namespace or ns is None) and selector.matches(labels):
                 if out is None:
                     out = []
                 out.append(item)
@@ -117,18 +120,19 @@ def _count(dispatch: SelectorDispatch, pod: Pod, node: Hashable, delta: int) -> 
 class SpreadCounts:
     """``placed()`` yields (node key, pods on it) for every node that
     counts: it is walked only for selectors not tracked yet.
-    ``rows_total`` is a counter labelled by source (kept | walk) that
-    tallies the rows handed out; an index built on the spot and dropped
-    has no hit rate to report and leaves it out. Not thread safe: the
-    owner's lock guards it."""
+    ``rows_total`` maps the family that asks (``spread`` | ``interpod``)
+    to a counter labelled by source (kept | walk) that tallies the rows
+    handed to it; an index built on the spot and dropped has no hit rate
+    to report and leaves it out. Not thread safe: the owner's lock guards
+    it."""
 
     def __init__(
         self,
-        placed: Callable[[], Iterable[tuple[Hashable, Iterable[Pod]]]],
-        rows_total=None,
+        placed: Callable[[], Iterable[tuple[Hashable, Collection[Pod]]]],
+        rows_total: Mapping[str, object] | None = None,
     ) -> None:
         self._placed = placed
-        self._rows_total = rows_total
+        self._rows_total = rows_total or {}
         self._tracked: dict[tuple[str, Selector], _Kept] = {}
         self._dispatch = SelectorDispatch()
         self._batch = 0
@@ -149,17 +153,28 @@ class SpreadCounts:
         if self._tracked:
             _count(self._dispatch, pod, node, -1)
 
+    def _tally(self, family: str, kept: int, walk: int) -> None:
+        rows_total = self._rows_total.get(family)
+        if rows_total is not None:
+            rows_total.labels("walk").inc(walk)
+            rows_total.labels("kept").inc(kept)
+
     def rows(
         self,
         wanted: Sequence[tuple[str, Selector | None]],
         padded_n: int,
         slot_of: Mapping[Hashable, int] | None = None,
+        family: str = "spread",
+        visits=None,
     ) -> np.ndarray:
         """[len(wanted), padded_n] int32: row i holds, per node slot, the
         pods that match ``wanted[i]`` (a None selector matches nothing).
         ``slot_of`` maps a node key to its slot (None: the keys are the
         slots); a key it lacks, or a slot past ``padded_n``, is left out.
-        One call is one batch of the ``KEEP_BATCHES`` bound."""
+        ``family`` names the tally; ``visits`` (a counter with ``inc``) is
+        given the placed pods the first count walked. One call is one
+        batch of the ``KEEP_BATCHES`` bound: a batch that both families
+        ask makes two."""
         self._batch += 1
         fresh: dict[tuple[str, Selector], _Kept] = {}
         for key in wanted:
@@ -176,10 +191,14 @@ class SpreadCounts:
             for (namespace, selector), kept in fresh.items():
                 first.add(namespace, selector, kept.by_node)
                 self._dispatch.add(namespace, selector, kept.by_node)
+            walked = 0
             for node, pods in self._placed():
+                walked += len(pods)
                 for pod in pods:
                     _count(first, pod, node, 1)
             self._tracked.update(fresh)
+            if visits is not None:
+                visits.inc(walked)
 
         out = np.zeros((len(wanted), padded_n), dtype=np.int32)
         n_kept = n_walk = 0
@@ -195,9 +214,7 @@ class SpreadCounts:
                 slot = node if slot_of is None else slot_of.get(node, -1)
                 if 0 <= slot < padded_n:
                     row[slot] = n
-        if self._rows_total is not None:
-            self._rows_total.labels("walk").inc(n_walk)
-            self._rows_total.labels("kept").inc(n_kept)
+        self._tally(family, n_kept, n_walk)
 
         stale = [
             key
@@ -206,4 +223,33 @@ class SpreadCounts:
         ]
         for key in stale:
             self._dispatch.remove(key[1], self._tracked.pop(key).by_node)
+        return out
+
+    def walked(
+        self,
+        preds: Sequence[Callable[[Pod], bool]],
+        padded_n: int,
+        slot_of: Mapping[Hashable, int] | None,
+        family: str,
+        visits=None,
+    ) -> np.ndarray:
+        """[len(preds), padded_n] int32: row i holds, per node slot, the
+        pods for which ``preds[i]`` holds, by one pass over the placed
+        pods, kept nowhere: for a question the index does not file (an
+        inter-pod term with a namespaceSelector). Each row is tallied as
+        walked; ``slot_of`` and ``visits`` as in ``rows``."""
+        out = np.zeros((len(preds), padded_n), dtype=np.int32)
+        walked = 0
+        for node, pods in self._placed():
+            walked += len(pods)
+            slot = node if slot_of is None else slot_of.get(node, -1)
+            if not 0 <= slot < padded_n:
+                continue
+            for pod in pods:
+                for i, pred in enumerate(preds):
+                    if pred(pod):
+                        out[i, slot] += 1
+        if visits is not None:
+            visits.inc(walked)
+        self._tally(family, 0, len(preds))
         return out

@@ -6,14 +6,17 @@ symmetry direction):
 
 INCOMING terms (T_in) — the batch pod classes' own affinity terms:
   req-affinity / req-anti-affinity / preferred(±weight). State
-  in_cnt[T_in, N] counts existing pods matching the term per node;
-  placed batch pods fold in via in_match[P, T_in].
+  in_cnt[T_in, N] counts existing pods matching the term per node (the
+  per-selector node counts of state/spread_counts.py); placed batch pods
+  fold in via in_match[P, T_in].
 
-EXISTING-side terms (T_ex) — terms OWNED by pods (placed or batch), needed
-for the symmetry checks (filtering.go#satisfyExistingPodsAntiAffinity,
-scoring's symmetric preferred/hard-affinity contributions): required-anti
-(filter-blocking), preferred ±w and required-affinity (scored with
-hardPodAffinityWeight). State ex_cnt[T_ex, N] counts OWNER pods per node;
+EXISTING-side terms (T_ex) — terms OWNED by pods (placed or batch) that
+select a pod of the batch, needed for the symmetry checks
+(filtering.go#satisfyExistingPodsAntiAffinity, scoring's symmetric
+preferred/hard-affinity contributions): required-anti (filter-blocking),
+preferred ±w and required-affinity (scored with hardPodAffinityWeight).
+State ex_cnt[T_ex, N] counts OWNER pods per node (the term owners of
+state/interpod_owners.py);
 batch pods that own terms fold in via ex_owned[P, T_ex]. Whether instance u
 concerns incoming pod p (selector+namespace vs p) is the per-pod bit/weight
 matrix m_anti[P, T_ex] / m_w[P, T_ex] — precompiled host-side, so the
@@ -26,6 +29,7 @@ restructuring of the reference's topologyToMatchedTermCount maps.
 
 from __future__ import annotations
 
+import itertools
 from dataclasses import dataclass
 from typing import Mapping, Sequence
 
@@ -33,16 +37,19 @@ import numpy as np
 
 from ..api.objects import Node, Pod, PodAffinityTerm
 from ..ops.oracle import interpod as oip
+from ..state.interpod_owners import (
+    K_PREF_AFF,
+    K_PREF_ANTI,
+    K_REQ_AFF,
+    K_REQ_ANTI,
+    OwnerTerms,
+    owned_terms,
+)
+from ..state.spread_counts import SelectorDispatch, SpreadCounts
 from .schema import PodBatch, bucket_pow2
 
 INST_PAD = 8
 DOM_PAD = 8
-
-# existing-term kinds
-K_REQ_ANTI = 0
-K_PREF_AFF = 1
-K_PREF_ANTI = 2
-K_REQ_AFF = 3
 
 
 @dataclass
@@ -133,21 +140,26 @@ def trivial_interpod_tensors(
     )
 
 
-def _ex_terms_of(pod: Pod):
-    """(kind, term, weight) triples owned by ``pod`` that the symmetry
-    machinery needs. Terms are made EFFECTIVE here (matchLabelKeys merged
-    from the owner's labels) because the dedup key and the per-pod match
-    rows depend on the owner-resolved selector, not the raw spec."""
-    out = []
-    for t in oip._required_anti_terms(pod):
-        out.append((K_REQ_ANTI, oip.effective_term(t, pod), 0))
-    for wt in oip._preferred_terms(pod, anti=False):
-        out.append((K_PREF_AFF, oip.effective_term(wt.term, pod), wt.weight))
-    for wt in oip._preferred_terms(pod, anti=True):
-        out.append((K_PREF_ANTI, oip.effective_term(wt.term, pod), -wt.weight))
-    for t in oip._required_aff_terms(pod):
-        out.append((K_REQ_AFF, oip.effective_term(t, pod), 0))
-    return out
+def _dispatch(entries) -> SelectorDispatch:
+    """(effective term, owner namespace, item) triples, filed by selector
+    for ``_selected``; a term with a nil selector selects nothing and is
+    left out."""
+    dispatch = SelectorDispatch()
+    for term, owner_ns, item in entries:
+        if term.label_selector is not None:
+            dispatch.add(None, term.label_selector, (term, owner_ns, item))
+    return dispatch
+
+
+def _selected(dispatch: SelectorDispatch, pod: Pod) -> list:
+    """The items of ``dispatch`` whose term selects ``pod``: the selector
+    filed them, the namespace rule has the last word."""
+    namespace = pod.namespace
+    return [
+        item
+        for term, owner_ns, item in dispatch.matching(pod)
+        if term.matches_namespace(owner_ns, namespace)
+    ]
 
 
 def build_interpod_tensors(
@@ -161,6 +173,9 @@ def build_interpod_tensors(
     hard_pod_affinity_weight: int = 1,
     nominated: Sequence[tuple[Pod, int]] = (),
     visits=None,
+    counts: SpreadCounts | None = None,
+    owners: OwnerTerms | None = None,
+    slot_of: Mapping[str, int] | None = None,
 ) -> InterpodTensors:
     """``nominated`` carries (pod, node slot) pairs for unbound pods whose
     ``status.nominatedNodeName`` resolved to a live slot: they fold into
@@ -168,10 +183,23 @@ def build_interpod_tensors(
     RunFilterPluginsWithNominatedPods convention), so both the incoming
     terms and the symmetry direction see a nominated peer at its slot.
 
+    The placed pods come from ONE of two places. A caller with a
+    scheduler cache passes ``counts`` (its per-selector node counts),
+    ``owners`` (its inter-pod term owners), both kept by node name, with
+    ``slot_of``, the name -> slot map of this batch, and an empty
+    ``placed_by_slot``. A caller with only lists passes ``placed_by_slot``
+    and none of the three: the same indexes are built over it here and
+    dropped. Any other combination is refused.
+
+    The existing-term axis holds only the terms that select a pod of the
+    batch (a nonzero ``m_anti`` or ``m_w`` in some row): a term that
+    selects none neither blocks nor scores one, so the axis follows the
+    batch and not every selector ever placed.
+
     ``visits`` (a counter with ``inc``, the scheduler's
-    ``metrics.interpod_placed_visits_total``) is given, once a call, the
-    placed and nominated pods walked by every pass over them: the
-    owner-term pass and one pass per incoming term."""
+    ``metrics.interpod_placed_visits_total``) is given the placed pods
+    walked: the one pass for the incoming selectors ``counts`` does not
+    track yet, and the one pass for the terms it cannot serve."""
     # ---- incoming terms per class ----
     in_terms: list[tuple[int, PodAffinityTerm, int, int]] = []  # (cls, term, kind, w)
     per_class: list[tuple[list[int], list[int], list[int]]] = []
@@ -191,37 +219,54 @@ def build_interpod_tensors(
             in_terms.append((c, wt.term, K_PREF_ANTI, -wt.weight))
         per_class.append((aff_ids, anti_ids, pref_ids))
 
-    # ---- existing-side terms (owned by placed AND batch pods), deduped ----
-    ex_index: dict = {}
-    ex_terms: list[tuple[int, PodAffinityTerm, int, str]] = []  # kind, term, w, owner_ns
+    # placed pods: from the indexes the scheduler cache keeps, or from
+    # indexes built here over placed_by_slot and dropped
+    if counts is None and owners is None and slot_of is None:
+        counts = SpreadCounts(placed_by_slot.items)
+        owners = OwnerTerms()
+        for slot, ps in placed_by_slot.items():
+            for q in ps:
+                owners.pod_added(q, slot)
+    elif counts is None or owners is None or slot_of is None or placed_by_slot:
+        raise ValueError(
+            "placed pods come from placed_by_slot alone, or from counts and "
+            "owners with slot_of and an empty placed_by_slot"
+        )
+    noms = [(q, n_i) for q, n_i in nominated if 0 <= n_i < padded_n]
 
-    def ex_intern(kind: int, term: PodAffinityTerm, w: int, owner_ns: str) -> int:
-        key = (kind, term, w, owner_ns)
-        i = ex_index.get(key)
-        if i is None:
-            i = len(ex_terms)
-            ex_index[key] = i
-            ex_terms.append((kind, term, w, owner_ns))
-        return i
+    # ---- existing-side terms that select a pod of the batch ----
+    # owned by placed pods: the kept terms filed under the batch pods'
+    # labels; owned by batch pods and nominated peers: those terms the
+    # index does not hold, checked against the batch here
+    owned_by_pod = [owned_terms(p) for p in pods]
+    extra: dict[tuple, None] = {}
+    for q, terms in itertools.chain(
+        zip(pods, owned_by_pod), ((q, owned_terms(q)) for q, _ in noms)
+    ):
+        for kind, t, w in terms:
+            key = (kind, t, w, q.namespace)
+            if owners.counts(key) is None:
+                extra[key] = None
+    extra_dispatch = _dispatch((key[1], key[3], key) for key in extra)
 
-    placed_pods: list[tuple[int, Pod]] = [
-        (slot, p) for slot, ps in placed_by_slot.items() for p in ps
-    ]
-    # nominated pods count exactly like placed pods at their slot — both
-    # in the incoming count state and as existing-side term owners
-    placed_pods += [
-        (n_i, p) for p, n_i in nominated if 0 <= n_i < padded_n
-    ]
-    if visits is not None:
-        visits.inc((1 + len(in_terms)) * len(placed_pods))
-    owner_map_placed: list[tuple[int, int]] = []  # (slot, ex_id)
-    for slot, p in placed_pods:
-        for kind, t, w in _ex_terms_of(p):
-            owner_map_placed.append((slot, ex_intern(kind, t, w, p.namespace)))
-    owner_map_batch: list[tuple[int, int]] = []  # (pod idx, ex_id)
+    def score_w(kind: int, w: int) -> int:
+        if kind in (K_PREF_AFF, K_PREF_ANTI):
+            return w
+        return hard_pod_affinity_weight if kind == K_REQ_AFF else 0
+
+    selects: dict[tuple, list[int]] = {}  # term key -> batch pods it acts on
     for p_i, p in enumerate(pods):
-        for kind, t, w in _ex_terms_of(p):
-            owner_map_batch.append((p_i, ex_intern(kind, t, w, p.namespace)))
+        for key in itertools.chain(
+            (o.key for o in owners.selecting(p)), _selected(extra_dispatch, p)
+        ):
+            if key[0] == K_REQ_ANTI or score_w(key[0], key[2]):
+                selects.setdefault(key, []).append(p_i)
+    # one order whatever the index's history: by the first pod a term acts
+    # on, then by the term itself
+    ex_terms = sorted(
+        selects, key=lambda k: (selects[k][0], k[0], k[3], k[2], repr(k[1]))
+    )
+    ex_index = {key: e_i for e_i, key in enumerate(ex_terms)}
 
     if not in_terms and not ex_terms:
         return trivial_interpod_tensors(pbatch, padded_n, c_pad)
@@ -231,7 +276,7 @@ def build_interpod_tensors(
 
     # ---- domain vocab per topology key ----
     all_keys = {t.topology_key for _, t, _, _ in in_terms} | {
-        t.topology_key for _, t, _, _ in ex_terms
+        key[1].topology_key for key in ex_terms
     }
     key_vocab: dict[str, dict[str, int]] = {k: {} for k in all_keys}
     for node in slot_nodes:
@@ -280,16 +325,48 @@ def build_interpod_tensors(
         cls_req_anti[c, : len(anti_ids)] = anti_ids
         cls_pref[c, : len(pref_ids)] = pref_ids
 
+    # placed pods per node that an incoming term selects: the kept
+    # per-selector counts, one row per namespace the term asks about; a
+    # term with a namespaceSelector is not filed there and is walked
+    eff_in = []  # (effective term, owner namespace, term index)
+    wanted: list[tuple[str, object]] = []
+    wanted_term: list[int] = []
+    walk_terms: list[int] = []
     for t_i, (c, term, kind, w) in enumerate(in_terms):
         rep = class_reps[c]
         in_dom[t_i] = dom_for(term.topology_key)
         in_pref_w[t_i] = w
-        for slot, q in placed_pods:
-            if slot < padded_n and oip.term_matches_pod(term, rep, q):
-                in_cnt0[t_i, slot] += 1
-        for p_i, q in enumerate(pods):
-            if oip.term_matches_pod(term, rep, q):
-                in_match[p_i, t_i] = 1
+        eff = oip.effective_term(term, rep)
+        eff_in.append((eff, rep.namespace, t_i))
+        if eff.namespace_selector is not None:
+            walk_terms.append(t_i)
+            continue
+        for ns in dict.fromkeys(eff.namespaces or (rep.namespace,)):
+            wanted.append((ns, eff.label_selector))
+            wanted_term.append(t_i)
+    if wanted:
+        rows = counts.rows(
+            wanted, padded_n, slot_of, family="interpod", visits=visits
+        )
+        for t_i, row in zip(wanted_term, rows):
+            in_cnt0[t_i] += row
+    if walk_terms:
+        in_cnt0[walk_terms] = counts.walked(
+            [
+                lambda q, c=in_terms[t_i][0], t=in_terms[t_i][1]: (
+                    oip.term_matches_pod(t, class_reps[c], q)
+                )
+                for t_i in walk_terms
+            ],
+            padded_n, slot_of, family="interpod", visits=visits,
+        )
+    in_dispatch = _dispatch(eff_in)
+    for q, n_i in noms:
+        for t_i in _selected(in_dispatch, q):
+            in_cnt0[t_i, n_i] += 1
+    for p_i, p in enumerate(pods):
+        for t_i in _selected(in_dispatch, p):
+            in_match[p_i, t_i] = 1
 
     # ---- existing tables ----
     ex_dom = np.full((te_pad, padded_n), -1, dtype=np.int32)
@@ -299,27 +376,29 @@ def build_interpod_tensors(
     m_anti = np.zeros((pbatch.padded, te_pad), dtype=bool)
     m_w = np.zeros((pbatch.padded, te_pad), dtype=np.int32)
 
-    for e_i, (kind, term, w, owner_ns) in enumerate(ex_terms):
+    for e_i, key in enumerate(ex_terms):
+        kind, term, w, _ = key
         ex_dom[e_i] = dom_for(term.topology_key)
         ex_anti[e_i] = kind == K_REQ_ANTI
-        score_w = w if kind in (K_PREF_AFF, K_PREF_ANTI) else (
-            hard_pod_affinity_weight if kind == K_REQ_AFF else 0
-        )
-        for p_i, p in enumerate(pods):
-            if not term.matches_namespace(owner_ns, p.namespace):
-                continue
-            if term.label_selector is not None and term.label_selector.matches(
-                p.labels
-            ):
-                if kind == K_REQ_ANTI:
-                    m_anti[p_i, e_i] = True
-                elif score_w:
-                    m_w[p_i, e_i] = score_w
-    for slot, e_i in owner_map_placed:
-        if slot < padded_n:
-            ex_cnt0[e_i, slot] += 1
-    for p_i, e_i in owner_map_batch:
-        ex_owned[p_i, e_i] += 1
+        for p_i in selects[key]:
+            if kind == K_REQ_ANTI:
+                m_anti[p_i, e_i] = True
+            else:
+                m_w[p_i, e_i] = score_w(kind, w)
+        for node, n in (owners.counts(key) or {}).items():
+            slot = node if slot_of is None else slot_of.get(node, -1)
+            if 0 <= slot < padded_n:
+                ex_cnt0[e_i, slot] = n
+    for q, n_i in noms:
+        for kind, t, w in owned_terms(q):
+            e_i = ex_index.get((kind, t, w, q.namespace))
+            if e_i is not None:
+                ex_cnt0[e_i, n_i] += 1
+    for p_i, (p, terms) in enumerate(zip(pods, owned_by_pod)):
+        for kind, t, w in terms:
+            e_i = ex_index.get((kind, t, w, p.namespace))
+            if e_i is not None:
+                ex_owned[p_i, e_i] += 1
 
     # ---- self-affinity bits (first-pod special case) ----
     self_aff = np.zeros(pbatch.padded, dtype=bool)

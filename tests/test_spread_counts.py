@@ -773,3 +773,54 @@ def test_placed_pods_come_from_one_source(source):
             pods, static.reps, pbatch, slot_nodes, lists, nbatch.padded,
             static.c_pad, **kw,
         )
+
+
+def test_a_spread_row_and_an_inter_pod_row_share_counts_and_tally_apart():
+    """One batch in which a spread constraint and a required anti term ask
+    about the same (namespace, selector): the counts are one entry of the
+    index, counted by one pass, and each family tallies its own row."""
+    from kubernetes_tpu.tensorize.interpod import build_interpod_tensors
+
+    nodes, placed = mixed_cluster()
+    web = ML({"app": "web"})
+    pod = spread_pod("w", {"app": "web"}, [
+        TopologySpreadConstraint(1, ZONE, "DoNotSchedule", web)])
+    pod = dataclasses.replace(pod, affinity=MakePod().pod_anti_affinity(
+        HOSTNAME, {"app": "web"}).obj().affinity)
+    pods = [pod]
+    vocab = ResourceVocab.build(pods, nodes)
+    nbatch = build_node_batch(nodes, {}, vocab=vocab)
+    pbatch = build_pod_batch(pods, vocab)
+    slot_nodes = list(nodes) + [None] * (nbatch.padded - len(nodes))
+    static = build_static_tensors(pods, pbatch, slot_nodes, nbatch.padded)
+    slot_of = {n.name: i for i, n in enumerate(nodes)}
+    cache = SchedulerCache()
+    for n in nodes:
+        cache.add_node(n)
+    for name, ps in placed.items():
+        for p in ps:
+            cache.add_pod(dataclasses.replace(p, node_name=name))
+
+    def interpod_rows():
+        return {
+            s: metrics.interpod_count_rows_total.labels(s)._value.get()
+            for s in ("kept", "walk")
+        }
+
+    s0, i0 = rows_walked(), interpod_rows()
+    spread = build_spread_tensors(
+        pods, static.reps, pbatch, slot_nodes, {}, nbatch.padded, static.c_pad,
+        counts=cache.spread_counts, slot_of=slot_of,
+    )
+    interpod = build_interpod_tensors(
+        pods, static.reps, pbatch, slot_nodes, {}, nbatch.padded, static.c_pad,
+        counts=cache.spread_counts, owners=cache.interpod_owners, slot_of=slot_of,
+    )
+    s1, i1 = rows_walked(), interpod_rows()
+    # the spread row counted the selector; the inter-pod row found it kept
+    assert (s1["walk"] - s0["walk"], s1["kept"] - s0["kept"]) == (1, 0)
+    assert (i1["walk"] - i0["walk"], i1["kept"] - i0["kept"]) == (0, 1)
+    assert len(cache.spread_counts) == 1
+    np.testing.assert_array_equal(spread.cnt0[0], interpod.in_cnt0[0])
+    want = walk(cache, "default", web)
+    assert interpod.in_cnt0[0].sum() == sum(want.values()) > 0

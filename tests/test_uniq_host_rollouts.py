@@ -7,10 +7,13 @@ Several rollouts interleaved leave no chunk of ``group_size`` pods
 uniform, so every chunk replays the full per-pod step (chunk kind 0,
 scope ``grouped_slow``) with the ``InterPodAffinity`` scope inside it.
 These tests hold that path to the sequential oracle and to the
-benchmark's plain reference, and pin the two counters of the inter-pod
-tensorizer: the terms a solve carries, by side, and the placed pods
-``build_interpod_tensors`` walks, once a pass.
+benchmark's plain reference, and pin the counters of the inter-pod
+tensorizer: the terms a solve carries, by side, the placed pods
+``build_interpod_tensors`` walks, once a pass, and the rows of incoming
+counts it was handed, kept or walked.
 """
+
+import dataclasses
 
 import numpy as np
 import pytest
@@ -22,8 +25,10 @@ from kubernetes_tpu.api.objects import Node, Pod
 from kubernetes_tpu.ops.oracle.profile import FullOracle, make_oracle_nodes
 from kubernetes_tpu.scheduler import Scheduler, SchedulerConfig
 from kubernetes_tpu.solver.exact import ExactSolver, ExactSolverConfig
+from kubernetes_tpu.state.cache import SchedulerCache
 from kubernetes_tpu.state.cluster import ClusterState
-from kubernetes_tpu.tensorize.interpod import build_interpod_tensors
+from kubernetes_tpu.state.interpod_owners import OwnerTerms
+from kubernetes_tpu.tensorize.interpod import INST_PAD, build_interpod_tensors
 from kubernetes_tpu.tensorize.plugins import (
     build_port_tensors,
     build_static_tensors,
@@ -103,6 +108,8 @@ def counters():
         metrics.interpod_terms_total.labels("incoming")._value.get(),
         metrics.interpod_terms_total.labels("existing")._value.get(),
         metrics.interpod_placed_visits_total._value.get(),
+        metrics.interpod_count_rows_total.labels("kept")._value.get(),
+        metrics.interpod_count_rows_total.labels("walk")._value.get(),
     )
 
 
@@ -195,7 +202,7 @@ def test_counters_move_by_the_terms_and_the_walk_of_one_batch(
     """A batch whose shapes the test sets: ``n_plain`` plain pods and two
     replicas of each of ``placed_labels`` rollouts already placed, then
     one batch of ``batch_labels`` rollouts, one of which (anti-0) is
-    also placed."""
+    also placed, then a second batch of the same labels."""
     cfg = small(nodes=32)
     cs, sched = mk_sched(cfg, split=1)
     plain = [gen.PodSpec(f"init-{i}", "plain", "init") for i in range(n_plain)]
@@ -219,19 +226,103 @@ def test_counters_move_by_the_terms_and_the_walk_of_one_batch(
     assert solves() - n0 == 1 and len(built) == 1
     (t,) = built
     # one required anti term per class (one class per label) in the batch;
-    # existing terms deduplicated over the placed and the batch pods
+    # the existing terms are those that select a pod of the batch: its own
+    # labels', whether a placed pod or a batch pod owns them
     assert t.num_in == batch_labels
-    assert t.num_ex == len({f"anti-{a}" for a in range(placed_labels)}
-                           | {f"anti-{a}" for a in range(batch_labels)})
+    assert t.num_ex == batch_labels
     assert c1[0] - c0[0] == t.num_in
     assert c1[1] - c0[1] == t.num_ex
-    # the owner-term pass and one pass per incoming term, over every placed pod
+    # one pass over the placed pods, for the labels the placed batch did
+    # not already ask about; none where every label was asked before
     placed = n_plain + len(standing)
-    assert c1[2] - c0[2] == (1 + t.num_in) * placed
+    new = batch_labels - placed_labels
+    assert c1[2] - c0[2] == (placed if new else 0)
+    assert (c1[3] - c0[3], c1[4] - c0[4]) == (placed_labels, new)
     # one tally: /metrics and dispatch_counts are the same increments
     d1 = sched.solver.dispatch_counts
     assert d1["interpod_incoming"] - d0["interpod_incoming"] == t.num_in
     assert d1["interpod_existing"] - d0["interpod_existing"] == t.num_ex
+
+    # a second batch of the same labels walks nothing: every row is kept
+    again = [
+        anti(f"again-{i:03d}", f"anti-{i % batch_labels}") for i in range(2 * batch_labels)
+    ]
+    assert len(drive(cs, sched, pods_of(cfg, again))) == len(again)
+    c2 = counters()
+    assert c2[2] == c1[2]
+    assert (c2[3] - c1[3], c2[4] - c1[4]) == (batch_labels, 0)
+
+
+def test_count_rows_kept_and_walked_by_source_and_no_spread_row():
+    """k new labels of n in a batch: k rows walked, n - k kept, and the
+    spread family's counter does not move."""
+    cfg = small(nodes=32)
+    cs, sched = mk_sched(cfg, split=1)
+
+    def spread_rows():
+        return tuple(
+            metrics.spread_count_rows_total.labels(s)._value.get() for s in ("kept", "walk")
+        )
+
+    s0, c0 = spread_rows(), counters()
+    labels = [f"anti-{a}" for a in range(4)]
+    drive(cs, sched, pods_of(cfg, [anti(f"a-{i}", labels[i % 4]) for i in range(8)]))
+    c1 = counters()
+    assert (c1[3] - c0[3], c1[4] - c0[4]) == (0, 4)
+    mixed = labels[:3] + ["anti-6", "anti-7"]  # k = 2 new of n = 5
+    drive(cs, sched, pods_of(cfg, [anti(f"b-{i}", mixed[i % 5]) for i in range(10)]))
+    c2 = counters()
+    assert (c2[3] - c1[3], c2[4] - c1[4]) == (3, 2)
+    assert spread_rows() == s0
+    assert len(sched.cache.spread_counts) == 6
+
+
+def test_the_existing_axis_stays_16_as_300_labels_are_placed():
+    """te_pad follows the batch's 13 labels, not every label placed: it
+    stays 16 (the padded axis of the shape key) while 300 rollouts each
+    place a replica."""
+    cfg = small(nodes=320)
+    nodes = nodes_of(cfg)
+    cache = SchedulerCache()
+    for n in nodes:
+        cache.add_node(n)
+    slot_of = {n.name: i for i, n in enumerate(nodes)}
+    batch = pods_of(cfg, [anti(f"new-{i:03d}", f"anti-{i % 13}") for i in range(64)])
+    vocab = ResourceVocab.build(batch, nodes)
+    nbatch = build_node_batch(nodes, {}, vocab=vocab)
+    pbatch = build_pod_batch(batch, vocab, pad=BATCH)
+    slot_nodes = list(nodes) + [None] * (nbatch.padded - len(nodes))
+    static = build_static_tensors(batch, pbatch, slot_nodes, nbatch.padded)
+    for placed in range(0, 301, 60):
+        for a in range(placed - 60 if placed else 0, placed):
+            pod = pods_of(cfg, [anti(f"old-{a}", f"anti-{a}")])[0]
+            cache.add_pod(dataclasses.replace(pod, node_name=nodes[a].name))
+        t = build_interpod_tensors(
+            batch, static.reps, pbatch, slot_nodes, {}, nbatch.padded, static.c_pad,
+            counts=cache.spread_counts, owners=cache.interpod_owners, slot_of=slot_of,
+        )
+        assert len(cache.interpod_owners) == placed
+        assert t.num_in == 13 and t.num_ex == 13
+        assert t.ex_cnt0.shape[0] == 16 > INST_PAD
+        # each label's one placed replica is counted on its node
+        assert t.ex_cnt0.sum() == min(placed, 13)
+
+
+@pytest.mark.parametrize(
+    "config",
+    ["sched-perf-basic-5000n", "sched-perf-spread-5000n", "sched-perf-spread-rollouts-5000n"],
+)
+def test_a_stream_with_no_inter_pod_term_leaves_the_owner_index_empty(config, monkeypatch):
+    def boom(*a, **k):
+        raise AssertionError("a pod with no inter-pod term reached the owner index")
+
+    monkeypatch.setattr(OwnerTerms, "pod_added", boom)
+    monkeypatch.setattr(OwnerTerms, "pod_removed", boom)
+    cfg = small(config, nodes=48, replicas=16)
+    specs = gen.RolloutStream(cfg, seed=3900000013).take(64)
+    cs, sched = mk_sched(cfg)
+    assert len(drive(cs, sched, pods_of(cfg, specs))) == len(specs)
+    assert len(sched.cache.interpod_owners) == 0
 
 
 @pytest.mark.parametrize(
