@@ -114,6 +114,7 @@ def cmd_config(args) -> int:
 
 
 def cmd_serve(args) -> int:
+    from .obs.waits import instrument_serve
     from .server.extender import run_server
     from .state.cluster import ClusterState
     from .utils import logging as structured_logging
@@ -128,6 +129,10 @@ def cmd_serve(args) -> int:
     sched_cfg = config_types.scheduler_config(cfg)
     sched_cfg.feature_gates = _feature_gates(args)
     telemetry_on = bool(args.telemetry or args.bundle_dir)
+    # what holds serve's threads, before any of them starts: the
+    # collector's pauses always; with telemetry the waits for
+    # cluster.lock and both as profiler annotations
+    instrument_serve(cluster, telemetry=telemetry_on)
     if telemetry_on:
         # a device trace of this process is read by scope name: do not
         # take executables built under other names from the cache

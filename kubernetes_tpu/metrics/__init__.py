@@ -1038,6 +1038,42 @@ extender_request_seconds = Histogram(
 )
 
 
+# -- what held a thread of serve (obs/waits.py) --
+
+gc_pause_seconds = Histogram(
+    "scheduler_gc_pause_seconds",
+    "Pause of one CPython garbage collection in serve, by generation "
+    "(0|1|2); on in every serve, as kube-scheduler exports "
+    "go_gc_duration_seconds.",
+    ["generation"],
+    buckets=(
+        0.001, 0.002, 0.005, 0.01, 0.02, 0.05, 0.1, 0.2, 0.5, 1.0, 2.0, 4.0,
+    ),
+    registry=REGISTRY,
+)
+cluster_lock_wait_seconds_total = Counter(
+    "scheduler_cluster_lock_wait_seconds_total",
+    "Seconds threads waited for cluster.lock in contended acquires, by "
+    "thread (loop = inside run_pipelined|ingest = the server's event "
+    "loop|other); serve --telemetry only.",
+    ["thread"],
+    registry=REGISTRY,
+)
+cluster_lock_contended_total = Counter(
+    "scheduler_cluster_lock_contended_total",
+    "Acquires of cluster.lock that had to wait, by thread (loop|ingest|"
+    "other); serve --telemetry only.",
+    ["thread"],
+    registry=REGISTRY,
+)
+
+# called by render() first: series whose observations were queued where
+# taking a metric's lock is not safe (the collector's callback)
+before_render = []
+
+
 def render() -> bytes:
     """Prometheus exposition text for the /metrics endpoint."""
+    for flush in before_render:
+        flush()
     return generate_latest(REGISTRY)

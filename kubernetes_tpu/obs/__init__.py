@@ -4,8 +4,10 @@ Three cooperating pieces, all zero-dep and virtual-time-clean (the
 flight telemetry further down adds the one piece that reaches a
 profiler: ``Telemetry.stage``, the seam that times the seven batch
 stages for ``scheduler_profile_stage_seconds`` AND writes each as a
-``jax.profiler.TraceAnnotation`` — there is no other device-trace
-hook in the package):
+``jax.profiler.TraceAnnotation``, with the thread's CPU time in it as
+the stat ``cpu_us``; beside it, ``waits.py`` annotates what cuts across
+the stages in ``serve`` — collections and waits for ``cluster.lock`` —
+and there is no other device-trace hook in the package):
 
 - **spans** (``span.py``): OTel-shaped host-side spans threaded through
   both scheduler loops (enqueue → snapshot → tensorize → fold/extender
@@ -30,6 +32,7 @@ host↔device syncs (TPU001 stays clean; verified by the analyzer gate).
 
 from __future__ import annotations
 
+import time
 from dataclasses import dataclass
 
 from .. import metrics
@@ -193,21 +196,34 @@ def build_obs(
     return tracer, journal, recorder
 
 
+def cpu_us_since(cpu0: float) -> int:
+    """Microseconds of this thread's CPU time since ``time.thread_time()``
+    read ``cpu0``: the ``cpu_us`` stat of a ``stage:*`` annotation."""
+    return int((time.thread_time() - cpu0) * 1e6)
+
+
 class _Stage:
     """An open stage interval of ``Telemetry.stage``."""
 
-    __slots__ = ("_tel", "_name", "_attrs", "_ann", "_t0")
+    __slots__ = ("_tel", "_name", "_attrs", "_ann", "_t0", "_cpu0")
 
     def __init__(self, tel: "Telemetry", name: str, attrs: dict) -> None:
         self._tel, self._name, self._attrs = tel, name, attrs
 
     def __enter__(self) -> "_Stage":
+        # read before the annotation starts: a switch of threads between
+        # the annotation's start and the clock's read would lengthen the
+        # annotation alone
+        self._cpu0 = time.thread_time()
         # the annotation starts when it is built, not when it is entered
         self._ann = self._tel.annotation("stage:" + self._name, **self._attrs)
         self._t0 = self._tel.clock.perf()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
+        # the annotation's wall time less cpu_us is what the thread spent
+        # runnable or blocked in the block
+        self._ann.set_metadata(cpu_us=cpu_us_since(self._cpu0))
         seconds = self._tel.clock.perf() - self._t0
         self._ann.__exit__(exc_type, exc, tb)
         if exc_type is None:

@@ -58,6 +58,7 @@ from ..ops.oracle import preemption as opr
 from ..ops.oracle.profile import FullOracle, make_oracle_nodes
 from ..state.cluster import ApiError, ClusterState
 from .. import metrics
+from ..obs import cpu_us_since
 
 MAX_EXTENDER_PRIORITY = 10
 # aiohttp's default body limit is 1 MiB: an ExtenderArgs carrying the
@@ -702,21 +703,25 @@ def make_app(
             if telemetry is not None
             else None
         )
+        cpu0 = time.thread_time() if ann is not None else 0.0
         t0 = time.perf_counter()
-        # a body is a batch: decoded with no lock held, one parse per
-        # distinct spec, then applied under one hold of cluster.lock
-        reused, parsed = pod_decoder.reused, pod_decoder.parsed
-        pods = [pod_decoder.decode(pd) for pd in _items(json.loads(body))]
-        with held_run():
-            core.cluster.create_pods(pods)
-        created = len(pods)
-        metrics.ingest_seconds_total.inc(time.perf_counter() - t0)
-        metrics.ingest_pods_total.inc(created)
-        ingest_specs_reused.inc(pod_decoder.reused - reused)
-        ingest_specs_parsed.inc(pod_decoder.parsed - parsed)
-        if ann is not None:
-            ann.set_metadata(pods=created)
-            ann.__exit__(None, None, None)
+        created = 0
+        try:
+            # a body is a batch: decoded with no lock held, one parse per
+            # distinct spec, then applied under one hold of cluster.lock
+            reused, parsed = pod_decoder.reused, pod_decoder.parsed
+            pods = [pod_decoder.decode(pd) for pd in _items(json.loads(body))]
+            with held_run():
+                core.cluster.create_pods(pods)
+            created = len(pods)
+            metrics.ingest_seconds_total.inc(time.perf_counter() - t0)
+            metrics.ingest_pods_total.inc(created)
+            ingest_specs_reused.inc(pod_decoder.reused - reused)
+            ingest_specs_parsed.inc(pod_decoder.parsed - parsed)
+        finally:
+            if ann is not None:
+                ann.set_metadata(pods=created, cpu_us=cpu_us_since(cpu0))
+                ann.__exit__(None, None, None)
         return web.json_response({"applied": created})
 
     async def delete_pod(request):

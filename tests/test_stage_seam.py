@@ -205,7 +205,10 @@ def test_annotations_of_a_profiler_session_agree_with_the_counters(
     summed = dict.fromkeys(STAGES, 0.0)
     for name, _, dur_ns, stats in loop[0]:
         summed[name[len("stage:"):]] += dur_ns / 1e9
-        assert set(stats) == {"step", "pods"}
+        # and the thread's CPU time in it, within one 10 ms tick of a
+        # host whose thread clock counts ticks
+        assert set(stats) == {"step", "pods", "cpu_us"}
+        assert 0 <= stats["cpu_us"] <= dur_ns / 1e3 + 10_000
     assert counters["fence_wait"] > 0 and summed["fence_wait"] == 0.0  # booked after the fact
     for stage in STAGES:
         if stage != "fence_wait":
