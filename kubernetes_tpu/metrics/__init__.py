@@ -383,6 +383,20 @@ spread_count_rows_total = Counter(
     ["source"],
     registry=REGISTRY,
 )
+static_rows_total = Counter(
+    "scheduler_tpu_static_rows_total",
+    "Class rows of the static plugin tables (StaticPluginTensors: the "
+    "NodeName, NodeUnschedulable, TaintToleration, NodeAffinity, "
+    "ImageLocality and volume mask and score rows) that the scheduler's "
+    "batches got, by source: evaluated = computed node by node for the "
+    "first class of a static identity, shared = copied from that class "
+    "for a class that differs only in what the spread or inter-pod "
+    "tensorizers read (its labels). Calls for the warm start, the relax "
+    "planner, the rebalancer, the simulator and solver/evaluate.py are "
+    "not counted.",
+    ["source"],
+    registry=REGISTRY,
+)
 spread_tracked_selectors = Gauge(
     "scheduler_tpu_spread_tracked_selectors",
     "(namespace, selector) pairs whose per-node match counts the "

@@ -2795,6 +2795,10 @@ class Scheduler:
                 added_affinity=solver.config.added_affinity,
                 class_key_extra=class_key_extra,
             )
+            metrics.static_rows_total.labels("evaluated").inc(static.rows_evaluated)
+            metrics.static_rows_total.labels("shared").inc(
+                static.num_classes - static.rows_evaluated
+            )
             if need_ports:
                 ports = _timed(
                     "NodePorts", build_port_tensors,

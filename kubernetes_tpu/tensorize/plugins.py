@@ -9,7 +9,9 @@ deployments/jobs, so C << P — the host evaluates each distinct spec once per
 node instead of once per pod (the reference evaluates every (pod, node) pair
 from scratch inside the goroutine parallel-for; the class dedup is the
 TPU-native restructuring that makes the host prep O(C·N) and the device work
-a gather).
+a gather). Classes that differ only in what the family tensorizers read (a
+spread or inter-pod pod's labels) share one static identity: its rows are
+evaluated once and copied into each of them.
 
 Static per-class tensors (filter mask + raw score inputs; normalization
 happens in-scan because DefaultNormalizeScore normalizes over the FEASIBLE
@@ -46,14 +48,31 @@ CLASS_PAD = 8  # pad the class axis to multiples of this (sublane-ish quantum)
 PORT_PAD = 8
 
 
-def _class_key(pod: Pod, with_images: bool):
-    """Everything the static plugins read from the pod spec. Image names only
-    matter when some node reports images (image_score is their sole
-    consumer); excluding them otherwise keeps C small for image-diverse
-    batches. PodTopologySpread reads the pod's own labels (selfMatch,
-    matchLabelKeys) and namespace, so those join the key only for pods that
-    carry spread constraints."""
-    na = pod.affinity.node_affinity if pod.affinity else None
+def _static_key(pod: Pod, with_images: bool):
+    """Everything the static plugins read from the pod spec: NodeName,
+    NodeUnschedulable and TaintToleration (node name, tolerations),
+    NodeAffinity (node selector, node affinity), ImageLocality and the
+    volume family. Classes with equal keys get equal static rows. Image
+    names only matter when some node reports images (image_score is their
+    sole consumer); excluding them otherwise keeps C small for
+    image-diverse batches."""
+    return (
+        pod.node_name,
+        tuple(sorted(pod.node_selector.items())),
+        pod.affinity.node_affinity if pod.affinity else None,
+        pod.tolerations,
+        tuple(tuple(c.images) for c in pod.containers) if with_images else (),
+        len(pod.containers) if with_images else 0,
+        # volume plugins resolve PVCs by (namespace, claim name)
+        (pod.namespace, pod.pvc_names) if pod.pvc_names else (),
+    )
+
+
+def _family_key(pod: Pod):
+    """What the per-class family tensorizers read beyond the static key.
+    PodTopologySpread reads the pod's own labels (selfMatch,
+    matchLabelKeys) and namespace, so those join the key only for pods
+    that carry spread constraints."""
     spread = (
         (
             pod.topology_spread_constraints,
@@ -73,18 +92,7 @@ def _class_key(pod: Pod, with_images: bool):
         if (pa is not None or paa is not None)
         else ()
     )
-    return (
-        pod.node_name,
-        tuple(sorted(pod.node_selector.items())),
-        na,
-        pod.tolerations,
-        tuple(tuple(c.images) for c in pod.containers) if with_images else (),
-        len(pod.containers) if with_images else 0,
-        spread,
-        interpod,
-        # volume plugins resolve PVCs by (namespace, claim name)
-        (pod.namespace, pod.pvc_names) if pod.pvc_names else (),
-    )
+    return spread, interpod
 
 
 @dataclass
@@ -101,6 +109,9 @@ class StaticPluginTensors:
     # out-of-tree ScorePlugin contributions, weight-premultiplied
     # (framework/runtime.py#fold_out_of_tree); None = no custom plugins
     extra_score: np.ndarray | None = None  # [Cp, Np] int32
+    # class rows evaluated node by node; the other num_classes - this
+    # were copied from an earlier class with the same static key
+    rows_evaluated: int = 0
 
     @property
     def c_pad(self) -> int:
@@ -130,6 +141,7 @@ def trivial_static_tensors(pbatch: PodBatch, padded_n: int, schedulable: np.ndar
         nodeaff_pref=z.copy(),
         image_score=z.copy(),
         reps=[],
+        rows_evaluated=1,
     )
 
 
@@ -171,8 +183,13 @@ def build_static_tensors(
     class_of = np.zeros(pbatch.padded, dtype=np.int32)
     reps: list[Pod] = []
     index: dict = {}
+    # per class, the first class with its static key: the rows depend on
+    # nothing else of the pod, so only that class is evaluated
+    row_src: list[int] = []
+    first_static: dict = {}
     for i, pod in enumerate(pods):
-        key = _class_key(pod, with_images=any_images)
+        skey = _static_key(pod, with_images=any_images)
+        key = (skey, _family_key(pod))
         if class_key_extra is not None:
             key = (key, class_key_extra(pod))
         c = index.get(key)
@@ -180,6 +197,7 @@ def build_static_tensors(
             c = len(reps)
             index[key] = c
             reps.append(pod)
+            row_src.append(first_static.setdefault(skey, c))
         class_of[i] = c
 
     c_pad = bucket_pow2(max(len(reps), 1), floor=CLASS_PAD)
@@ -189,6 +207,15 @@ def build_static_tensors(
     image_score = np.zeros((c_pad, padded_n), dtype=np.int32)
 
     for c, rep in enumerate(reps):
+        src = row_src[c]
+        if src != c:
+            # row assignment copies: _fold_group later folds plugins and
+            # extenders into mask[c] class by class
+            mask[c] = mask[src]
+            taint_cnt[c] = taint_cnt[src]
+            nodeaff_pref[c] = nodeaff_pref[src]
+            image_score[c] = image_score[src]
+            continue
         for j, node in enumerate(slot_nodes):
             if node is None or j >= padded_n:
                 continue
@@ -241,6 +268,7 @@ def build_static_tensors(
         nodeaff_pref=nodeaff_pref,
         image_score=image_score,
         reps=reps,
+        rows_evaluated=len(first_static),
     )
 
 
